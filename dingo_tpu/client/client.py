@@ -17,6 +17,7 @@ import grpc
 import numpy as np
 
 from dingo_tpu.client import retry as retry_mod
+from dingo_tpu.common.config import grpc_options
 from dingo_tpu.common.coord_channel import RotatingCoordinatorChannel
 from dingo_tpu.index import codec as vcodec
 from dingo_tpu.server import pb
@@ -79,7 +80,8 @@ class DingoClient:
     def _stub(self, store_id: str, service: str) -> ServiceStub:
         chan = self._channels.get(store_id)
         if chan is None:
-            chan = grpc.insecure_channel(self._store_addrs[store_id])
+            chan = grpc.insecure_channel(
+                self._store_addrs[store_id], options=grpc_options())
             self._channels[store_id] = chan
         return ServiceStub(chan, service)
 
@@ -591,6 +593,30 @@ class DingoClient:
             resp = self._call_leader(d, "IndexService", "VectorCount", req)
             total += resp.count
         return total
+
+    def vector_build(self, partition_id: int) -> None:
+        """VectorBuild on every region of the partition: full rebuild from
+        the engine (+ train for IVF types); returns when each is done."""
+        for d in self._regions_for_vector_ids(partition_id):
+            req = pb.VectorBuildRequest()
+            req.context.region_id = d.region_id
+            self._call_leader(d, "IndexService", "VectorBuild", req)
+
+    def vector_status(self, partition_id: int) -> List[Dict[str, Any]]:
+        """VectorStatus per region of the partition."""
+        out = []
+        for d in self._regions_for_vector_ids(partition_id):
+            req = pb.VectorStatusRequest()
+            req.context.region_id = d.region_id
+            resp = self._call_leader(d, "IndexService", "VectorStatus", req)
+            out.append({
+                "region_id": d.region_id,
+                "ready": resp.ready,
+                "trained": resp.trained,
+                "build_error": resp.build_error,
+                "count": resp.count,
+            })
+        return out
 
     # ---------------- kv ----------------
     def _region_for_key(self, key: bytes):

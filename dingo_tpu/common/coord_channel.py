@@ -28,6 +28,7 @@ from typing import Dict, Optional, Type
 
 import grpc
 
+from dingo_tpu.common.config import grpc_options
 from dingo_tpu.common.log import get_logger
 from dingo_tpu.server.rpc import ServiceStub
 
@@ -68,7 +69,8 @@ class RotatingCoordinatorChannel:
         if self._channel is not None:
             self._channel.close()
         self._active = idx % len(self._addrs)
-        self._channel = grpc.insecure_channel(self._addrs[self._active])
+        self._channel = grpc.insecure_channel(
+            self._addrs[self._active], options=grpc_options())
         self._stubs = {}
 
     def _stub_for(self, service: str):

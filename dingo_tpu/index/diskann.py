@@ -19,6 +19,7 @@ from typing import List, Optional
 import grpc
 import numpy as np
 
+from dingo_tpu.common.config import FLAGS, grpc_options
 from dingo_tpu.index.base import (
     FilterSpec,
     IndexParameter,
@@ -36,8 +37,6 @@ class TpuDiskann(VectorIndex):
                  server_addr: Optional[str] = None):
         super().__init__(index_id, parameter)
         if server_addr is None:
-            from dingo_tpu.common.config import FLAGS
-
             server_addr = FLAGS.get("diskann_server_addr")
         if not server_addr:
             raise VectorIndexError(
@@ -45,7 +44,8 @@ class TpuDiskann(VectorIndex):
                 "server endpoint)"
             )
         self.addr = server_addr
-        self._channel = grpc.insecure_channel(server_addr)
+        self._channel = grpc.insecure_channel(
+            server_addr, options=grpc_options())
         self.stub = ServiceStub(self._channel, "DiskAnnService")
         resp = self.stub.DiskAnnNew(pb.DiskAnnNewRequest(
             vector_index_id=index_id,

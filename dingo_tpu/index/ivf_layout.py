@@ -59,6 +59,13 @@ class BucketLayout:
         )
 
 
+def list_cap(n_rows: int, nlist: int) -> int:
+    """Bucket width for n_rows spread over nlist lists: the pow2 at or
+    above the MEAN list size, clamped to [MIN_CAP, MAX_CAP]."""
+    mean = max(1, -(-n_rows // max(1, nlist)))
+    return min(MAX_CAP, max(MIN_CAP, _next_pow2(mean)))
+
+
 def build_layout(
     assign_h: np.ndarray,
     valid_h: np.ndarray,
@@ -76,8 +83,7 @@ def build_layout(
     live, assign = live[keep], assign[keep]
 
     counts = np.bincount(assign, minlength=nlist).astype(np.int64)
-    mean = max(1, int(np.ceil(len(live) / max(1, nlist))))
-    cap_list = cap_hint or min(MAX_CAP, max(MIN_CAP, _next_pow2(mean)))
+    cap_list = cap_hint or list_cap(len(live), nlist)
 
     # buckets per list (every list gets >= 1 so probe_table[:, 0] is valid)
     nb = np.maximum(1, -(-counts // cap_list))           # ceil div

@@ -25,6 +25,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from dingo_tpu.common.metrics import METRICS
 from dingo_tpu.coprocessor.scalar_filter import ScalarFilter
 from dingo_tpu.engine.raw_engine import (
     CF_DEFAULT,
@@ -504,6 +505,9 @@ class VectorReader:
         param = self.ctx.parameter
         if param is None:
             raise VectorIndexError("host exact search needs index parameter")
+        METRICS.counter(
+            "fault.host_exact_searches", region_id=self.ctx.region_id
+        ).add(1)
         with TRACER.start_span("index.host_exact") as span:
             span.set_attr("region_id", self.ctx.region_id)
             lo, hi = self.ctx.id_window()
@@ -584,6 +588,9 @@ class VectorReader:
         (the reference builds a temp faiss flat per 2,048-vector batch and
         merges per-query top-k heaps; one TPU flat over the scan is the same
         result with fewer kernel launches)."""
+        METRICS.counter(
+            "fault.bruteforce_searches", region_id=self.ctx.region_id
+        ).add(1)
         with TRACER.start_span("index.bruteforce") as span:
             out = self._brute_force_search_impl(queries, topk, spec)
             span.set_attr("batch", len(queries))

@@ -19,7 +19,7 @@ per metrics tick.
 
 from __future__ import annotations
 
-from typing import Dict, Iterable
+from typing import Any, Dict, Iterable
 
 #: object types the walker must not enter (big host-side payload holders —
 #: the data CF is not device memory, and walking it costs O(keys))
@@ -30,7 +30,7 @@ _SKIP_TYPE_NAMES = frozenset({
 })
 
 
-def device_memory_stats() -> Dict[str, int]:
+def device_memory_stats() -> Dict[str, Any]:
     """Summed allocator stats over local devices ({} of zeros when the
     backend exposes none — e.g. CPU builds without allocator stats)."""
     out = {
@@ -38,6 +38,7 @@ def device_memory_stats() -> Dict[str, int]:
         "bytes_in_use": 0,
         "bytes_limit": 0,
         "peak_bytes_in_use": 0,
+        "per_device_bytes_in_use": {},
     }
     try:
         import jax
@@ -53,6 +54,7 @@ def device_memory_stats() -> Dict[str, int]:
         if not ms:
             continue
         out["devices"] += 1
+        out["per_device_bytes_in_use"][d.id] = int(ms.get("bytes_in_use", 0))
         out["bytes_in_use"] += int(ms.get("bytes_in_use", 0))
         out["bytes_limit"] += int(ms.get("bytes_limit", 0))
         out["peak_bytes_in_use"] += int(ms.get("peak_bytes_in_use", 0))

@@ -1,8 +1,10 @@
-"""ctypes bindings to the native C++ runtime pieces (built by native/Makefile).
+"""ctypes bindings to the native C++ runtime pieces (sources in native/).
 
-The shared libraries are built on demand at import time if missing — the
-environment guarantees g++ but no pip installs, so we ship sources and
-compile lazily (cached .so next to this file).
+The shared libraries are built on demand, on the machine that runs them:
+the environment guarantees g++ but no pip installs, so the repo ships
+sources and compiles lazily (cached .so next to this file, git-ignored).
+Only HNSW and the LSM raw engine need them; IVF/FLAT indexes and the WAL
+engine never load native code.
 """
 
 from __future__ import annotations
@@ -10,34 +12,48 @@ from __future__ import annotations
 import ctypes
 import hashlib
 import os
+import platform
 import subprocess
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _NATIVE_SRC = os.path.join(os.path.dirname(os.path.dirname(_HERE)), "native")
+_CXXFLAGS = ("-O3", "-std=c++17", "-fPIC", "-shared", "-march=native")
+
+
+def _cpu_identity() -> str:
+    """What -march=native resolved against: the first processor's model and
+    instruction-set flags. A library built under another identity may hold
+    instructions this CPU lacks (AVX-512 on a host without it is SIGILL)."""
+    ident = [platform.machine()]
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if not line.strip():
+                    break                       # first processor only
+                if line.startswith(("model name", "flags", "Features")):
+                    ident.append(line.strip())
+    except OSError:
+        pass
+    return "\n".join(ident)
 
 
 def _build(lib: str, src: str) -> str:
     """Compile (or reuse) a native helper library.
 
-    Staleness is decided by a content hash of the source recorded next to
-    the artifact — NOT mtimes (git checkouts don't preserve them) — so a
-    fresh clone never loads a stale or foreign-arch binary built with
-    -march=native on another machine (.so files are gitignored too).
+    A cached artifact is reused only when its stamp matches a hash of the
+    source, the compiler flags AND this CPU's identity — never mtimes (git
+    checkouts don't preserve them). The .so files are git-ignored but a
+    tree copied from another machine carries them along: under
+    -march=native such a copy is rebuilt here, never executed.
     """
     path = os.path.join(_HERE, lib)
     srcpath = os.path.join(_NATIVE_SRC, src)
     stamp = path + ".srchash"
-    if not os.path.exists(srcpath):
-        # installed without the native sources: a locally-built artifact is
-        # the only option (it was built on THIS machine, so arch is fine)
-        if os.path.exists(path):
-            return path
-        raise FileNotFoundError(
-            f"native source {srcpath} missing and no prebuilt {lib}; "
-            "install with the repo's native/ tree or prebuild the library"
-        )
     with open(srcpath, "rb") as f:
-        want = hashlib.sha256(f.read()).hexdigest()
+        h = hashlib.sha256(f.read())
+    h.update(" ".join(_CXXFLAGS).encode())
+    h.update(_cpu_identity().encode())
+    want = h.hexdigest()
     have = None
     if os.path.exists(stamp):
         with open(stamp) as f:
@@ -47,14 +63,17 @@ def _build(lib: str, src: str) -> str:
         # (pytest -n, two servers on one checkout) must never dlopen a
         # half-written .so
         tmp = f"{path}.build.{os.getpid()}"
-        subprocess.run(
-            [
-                "g++", "-O3", "-std=c++17", "-fPIC", "-shared",
-                "-march=native", srcpath, "-o", tmp,
-            ],
-            check=True,
-            capture_output=True,
-        )
+        try:
+            subprocess.run(
+                ["g++", *_CXXFLAGS, srcpath, "-o", tmp],
+                check=True,
+                capture_output=True,
+            )
+        except FileNotFoundError as e:
+            raise RuntimeError(
+                f"{lib} is built from native/{src} on first use and g++ is "
+                "missing; HNSW indexes and the LSM engine need it"
+            ) from e
         tmp_stamp = f"{stamp}.{os.getpid()}"
         with open(tmp_stamp, "w") as f:
             f.write(want)

@@ -145,7 +145,7 @@ class HbmLedger:
             self._region_peak.pop(region_id, None)
 
     # ---- process-level view ------------------------------------------------
-    def poll_process(self) -> Dict[str, int]:
+    def poll_process(self) -> Dict[str, Any]:
         """Refresh process allocator gauges (the hbm.watermark_interval_s
         crontab body; also runs with every metrics collection pass)."""
         from dingo_tpu.metrics.device import device_memory_stats
@@ -154,6 +154,8 @@ class HbmLedger:
         g = self.registry.gauge
         g("hbm.bytes_in_use").set(stats["bytes_in_use"])
         g("hbm.bytes_limit").set(stats["bytes_limit"])
+        for dev_id, nbytes in stats["per_device_bytes_in_use"].items():
+            g("device.bytes_in_use", labels={"device": dev_id}).set(nbytes)
         with self._lock:
             self._proc_peak = max(self._proc_peak,
                                   stats["peak_bytes_in_use"],

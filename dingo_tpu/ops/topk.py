@@ -20,7 +20,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-NEG_INF = jnp.float32(-jnp.inf)
+NEG_INF = float("-inf")
 
 
 def topk_scores(

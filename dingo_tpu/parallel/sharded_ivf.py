@@ -45,7 +45,7 @@ import jax.numpy as jnp
 
 from dingo_tpu.obs.sentinel import sentinel_jit
 import numpy as np
-from dingo_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dingo_tpu.index.base import (

@@ -41,7 +41,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from dingo_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dingo_tpu.common.config import FLAGS

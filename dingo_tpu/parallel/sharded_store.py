@@ -33,10 +33,10 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dingo_tpu.ops.distance import Metric, np_normalize
-from dingo_tpu.parallel.compat import shard_map
 from dingo_tpu.ops.topk import merge_sharded_topk, topk_scores
 from dingo_tpu.obs.sentinel import sentinel_jit
 

@@ -1,8 +1,7 @@
 """Shared tracing wrapper for the mesh-sharded search fan-outs.
 
 One context manager instead of three copies of the start/attr/error/end
-boilerplate in sharded_flat / sharded_ivf / sharded_pq. Kept free of any
-sharded-store import so it loads even where shard_map is unavailable.
+boilerplate in sharded_flat / sharded_ivf / sharded_pq.
 """
 
 from __future__ import annotations

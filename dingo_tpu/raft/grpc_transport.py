@@ -17,6 +17,7 @@ from typing import Callable, Dict, Optional
 
 import grpc
 
+from dingo_tpu.common.config import grpc_options
 from dingo_tpu.raft import wire
 from dingo_tpu.raft.transport import Transport, TransportFaults
 from dingo_tpu.server import pb
@@ -77,7 +78,7 @@ class GrpcRaftTransport(Transport):
             addr = self._peer_addrs.get(store_id)
             if addr is None:
                 return None
-            chan = grpc.insecure_channel(addr)
+            chan = grpc.insecure_channel(addr, options=grpc_options())
             self._channels[store_id] = chan
             stub = ServiceStub(chan, "RaftService")
             self._stubs[store_id] = stub

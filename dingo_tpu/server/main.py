@@ -23,8 +23,15 @@ import threading
 import sys
 import time
 
-from dingo_tpu.common.config import FLAGS, Config
+from dingo_tpu.common.config import (
+    FLAGS,
+    Config,
+    auto_arms,
+    enable_compile_cache,
+    require_device,
+)
 from dingo_tpu.common.crontab import CrontabManager
+from dingo_tpu.common.metrics import METRICS
 from dingo_tpu.common.stream import StreamManager
 from dingo_tpu.coordinator.balance import (
     BalanceLeaderScheduler,
@@ -154,7 +161,26 @@ def serve_coordinator(args) -> None:
             raft_coordinator.stop()
 
 
+def _claim_device() -> None:
+    """Start-up of a role that serves from the chip (store/index,
+    diskann): refuse any backend but a TPU before anything is built on
+    it, turn the persistent compile cache on, and publish what was found
+    — `device.count` and the backend-resolved `device.auto_arm`s — so a
+    client can tell from MetricsDump what the process really serves
+    from."""
+    cache_dir = enable_compile_cache()
+    dev = require_device()
+    METRICS.gauge("device.count", labels={
+        "platform": dev["platform"], "kind": dev["kind"],
+    }).set(dev["count"])
+    for flag, on in auto_arms().items():
+        METRICS.gauge("device.auto_arm", labels={"flag": flag}).set(int(on))
+    print(f"device: platform: {dev['platform']} kind: {dev['kind']} "
+          f"count: {dev['count']} compile cache: {cache_dir}", flush=True)
+
+
 def serve_store(args) -> None:
+    _claim_device()
     engine = _make_engine(args)
     if args.raft_peers:
         # multi-process replication: raft RPCs ride grpc between stores
@@ -395,6 +421,7 @@ def serve_diskann(args) -> None:
 
     from dingo_tpu.diskann.item import DiskAnnItemManager
 
+    _claim_device()
     root = args.data_dir or tempfile.mkdtemp(prefix="dingo-diskann-")
     manager = DiskAnnItemManager(root)
     server = DingoServer(args.port)

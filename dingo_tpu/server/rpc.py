@@ -12,7 +12,7 @@ from typing import Dict, Optional, Tuple
 
 import grpc
 
-from dingo_tpu.common.config import FLAGS
+from dingo_tpu.common.config import FLAGS, grpc_options
 from dingo_tpu.obs.pressure import (
     attach_budget,
     detach_budget,
@@ -336,7 +336,8 @@ def _register(server: grpc.Server, service_name: str, impl) -> None:
 class DingoServer:
     def __init__(self, port: int = 0, max_workers: int = 16):
         self._server = grpc.server(
-            futures.ThreadPoolExecutor(max_workers=max_workers)
+            futures.ThreadPoolExecutor(max_workers=max_workers),
+            options=grpc_options(),
         )
         self.port = self._server.add_insecure_port(f"127.0.0.1:{port}")
 

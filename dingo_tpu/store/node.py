@@ -304,12 +304,13 @@ class StoreNode:
 
         import grpc
 
+        from dingo_tpu.common.config import grpc_options
         from dingo_tpu.server import pb
         from dingo_tpu.server.rpc import ServiceStub
 
         if not self.index_manager.snapshot_root:
             return False
-        channel = grpc.insecure_channel(peer_addr)
+        channel = grpc.insecure_channel(peer_addr, options=grpc_options())
         try:
             meta = ServiceStub(channel, "NodeService").GetVectorIndexSnapshotMeta(
                 pb.VectorIndexSnapshotMetaRequest(region_id=region_id)

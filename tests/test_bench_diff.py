@@ -1,5 +1,5 @@
 """tools/bench_diff.py wired as a tier-1 gate (ISSUE 9 satellite): the
-BENCH_r0*.json trajectory becomes machine-checkable — a synthetic summary
+bench-summary trajectory becomes machine-checkable — a synthetic summary
 pair round-trips through the CLI with the right exit codes, regression
 classification, and thresholds."""
 

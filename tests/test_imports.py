@@ -35,10 +35,10 @@ def test_import_every_module():
 
 
 def test_sharded_modules_import():
-    """The four modules the shard_map break took down, pinned by name so
-    a future compat regression names the exact culprit."""
+    """The four modules a shard_map break takes down (they import
+    jax.shard_map directly), pinned by name so a regression names the
+    exact culprit."""
     for name in (
-        "dingo_tpu.parallel.compat",
         "dingo_tpu.parallel.sharded_store",
         "dingo_tpu.parallel.sharded_flat",
         "dingo_tpu.parallel.sharded_ivf",

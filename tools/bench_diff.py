@@ -1,7 +1,7 @@
 """Diff two bench.py JSON summaries and flag performance regressions.
 
-The BENCH_r0*.json trajectory has been eyeball-only since round 1; this
-makes it machine-checkable:
+Bench summaries were eyeball-only since round 1; this makes a pair of
+them machine-checkable:
 
     python tools/bench_diff.py OLD.json NEW.json \
         [--qps-drop 0.15] [--recall-drop 0.02] [--bytes-grow 0.25] [--json]

@@ -70,6 +70,13 @@ FAMILY_NAMES = {
         "hbm.region.total_peak_bytes",  # sum() can't double-count)
         "hbm.alloc_failures",
     },
+    "device": {
+        "device.count",          # devices of the serving process, by
+                                 # {platform, kind} as jax reports them
+        "device.auto_arm",       # 1/0 per tri-state 'auto' crossover as
+                                 # resolved at start, by {flag}
+        "device.bytes_in_use",   # allocator bytes per device, by {device}
+    },
     "flight": {
         "flight.bundles",        # captured bundles by reason
         "flight.suppressed",     # rate-limited triggers by reason
@@ -258,6 +265,11 @@ FAMILY_NAMES = {
                                     # drop_rerank / evict_mirrors /
                                     # retry / degrade
         "fault.degraded_regions",   # regions currently device-degraded
+        "fault.host_exact_searches",  # searches served by the numpy scan
+                                    # of the engine (degraded region)
+        "fault.bruteforce_searches",  # searches served by the temp-flat
+                                    # scan (index not ready / untrained /
+                                    # unsupported)
         "fault.rematerializations",  # degraded regions rebuilt (lower
                                     # precision) from the engine
         "fault.rebuilds",           # scrub-corruption rebuilds from the
