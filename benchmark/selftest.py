@@ -1,0 +1,297 @@
+"""The benchmark's own tests (no chip needed; about five minutes):
+
+    python3 benchmark/selftest.py            # everything
+    python3 benchmark/selftest.py quick      # all but the CPU rehearsals
+
+1. the trace reducer on the small recorded trace in `testdata/`;
+2. the work functions against bytes and FLOPs computed by hand at the cells'
+   shapes, and the roofline arithmetic;
+3. the percentile, rate, stall and open-loop timing arithmetic;
+4. the comparison: a perfect program passes, the bfloat16 control does not;
+5. CPU rehearsals (`JAX_PLATFORMS=cpu`, explicit small `--rows`): every line
+   says `platform: cpu`, the last line is never a pass, and with the timed
+   path broken underneath (`--wrap-client faults.py:<fault>`) `correct`
+   comes out false; a configuration whose metric or precision no arm of the
+   harness honours is refused.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+sys.path.insert(0, HERE)
+
+import numpy as np  # noqa: E402
+
+import readers  # noqa: E402
+import reference  # noqa: E402
+import trace_reduce  # noqa: E402
+
+
+def check(ok: bool, what: str) -> None:
+    print(("ok   " if ok else "FAIL ") + what, flush=True)
+    if not ok:
+        raise SystemExit(1)
+
+
+def load(*parts):
+    with open(os.path.join(HERE, *parts)) as f:
+        return json.load(f)
+
+
+# ------------------------------------------------------------ 1. the reducer
+def test_reducer() -> None:
+    check(trace_reduce.union_seconds([(0, 2), (1, 3), (5, 6)]) == 4,
+          "union of overlapping intervals")
+    check(trace_reduce.gaps_of([(0, 2), (1, 3), (5, 6)]) == [(3, 5)],
+          "gaps between merged intervals")
+    host = [("outer", 0.0, 10.0), ("inner", 3.0, 4.2), ("far", 8.0, 9.0)]
+    check(trace_reduce.attribute((3.1, 4.0), host) == "host: inner",
+          "a gap is named by the shortest host event covering half of it")
+    check(trace_reduce.attribute((20.0, 21.0), host) == trace_reduce.NOTHING,
+          "a gap no host event overlaps is named as such")
+    want = load("testdata", "recorded.expect.json")
+    got = trace_reduce.reduce_trace(
+        os.path.join(HERE, "testdata", "recorded.xplane.pb.gz"))
+    check(got["devices"] == want["devices"], "recorded trace: device planes")
+    check(abs(got["busy_s"] - want["busy_s"]) < 1e-9,
+          f"recorded trace: busy_s {got['busy_s']}")
+    check(got["device_ops"][0][0] == want["device_ops"][0][0]
+          and abs(got["device_ops"][0][1] - want["device_ops"][0][1]) < 1e-9,
+          f"recorded trace: top device op {got['device_ops'][0]}")
+    check(0 < got["busy_s"] <= got["device_span_s"],
+          "recorded trace: busy time lies inside the device's span")
+    # a witness that is not the reducer: the store's own device_wait_span
+    # read 19.6 ms per call in the run the trace is from
+    top = got["device_ops"][0][0]
+    per_call = got["op_seconds"][top] / got["op_calls"][top] * 1e3
+    check(18.5 < per_call < 20.5,
+          f"recorded trace: the scan kernel takes {per_call:.2f} ms a call, "
+          "as the store's device_wait_span read (19.6)")
+
+
+# ------------------------------------------------------ 2. work and roofline
+def test_work() -> None:
+    ivf, flat = load("configs", "ivf768.json"), load("configs", "flat768.json")
+    peaks = readers.load_peaks("TPU v5 lite")
+    w = readers.load_work("flat_scan")(flat, {"batch": 1, "search_args": {}})
+    check(w["bytes"] == 160_000 * 768 * 4 == 491_520_000,
+          "flat scan reads every row once: 491.5 MB")
+    check(w["flops"] == 2 * 768 * 160_000, "flat scan FLOPs at batch 1")
+    check(abs(readers.least_seconds(w, peaks) - 491.52e6 / 819e9) < 1e-12,
+          "flat scan is bound by bytes: 0.600 ms least")
+    w = readers.load_work("ivf_scan")(
+        ivf, {"batch": 1, "search_args": {"nprobe": 32}})
+    check(abs(w["bytes"] - 32 * (160_000 / 1024) * 768 * 4) < 1,
+          "ivf scan at batch 1: 32 lists of 156.25 rows = 15.4 MB")
+    w = readers.load_work("ivf_scan")(ivf, {"batch": 64, "search_args": {}})
+    lists = 1024 * (1 - (1 - 32 / 1024) ** 64)
+    check(abs(lists - 889.8) < 0.1 and abs(
+        w["bytes"] - lists * (160_000 / 1024) * 3072) < 1,
+        "ivf scan at batch 64: the union of probes, 889.8 lists = 427.1 MB")
+    check(w["flops"] == 2.0 * 768 * 64 * 32 * (160_000 / 1024),
+          "ivf scan FLOPs: one multiply-add per dimension per probed row")
+    try:
+        readers.load_peaks("TPU v9 imaginary")
+        check(False, "an unknown device kind is an error")
+    except KeyError:
+        check(True, "an unknown device kind is an error")
+    run = readers.Run(
+        trace={"op_seconds": {"jit_scan/a": 0.030, "jit_coarse_probes/b": 0.5},
+               "busy_s": 0.53, "window_s": 5.3},
+        records=np.array([[0.0, 0.0, 0.4, 1]] + [
+            [1.0, 1.0, 1.0 + 0.1 * i, 1] for i in range(4)] + [
+            [1.0, 1.0, 1.5, 0], [1.9, 1.9, 2.0, 1]]),
+        profile=(0.5, 2.0), config=flat, traffic={"batch": 1},
+        device_kind="TPU v5 lite")
+    got = readers.roofline(run, "flat_scan", exclude=["coarse_probes"])
+    check(abs(got - 100 * (491.52e6 / 819e9) * 4 / 0.030) < 1e-9,
+          "roofline share: least x requests answered in the profiled "
+          f"seconds / stage device time = {got:.3f} %")
+    check(abs(readers.idle_share(run) - 90.0) < 1e-9, "idle share")
+    check(readers.roofline(readers.Run(config=flat, traffic={}),
+                           "flat_scan") is None,
+          "a roofline with nothing to read is left out, not 0")
+
+
+# ------------------------------------------------------------- 3. arithmetic
+def test_arithmetic() -> None:
+    check(readers.percentile([5, 1, 3, 2, 4], 50) == 3, "median, nearest rank")
+    check(readers.percentile(range(1, 101), 95) == 95, "p95 of 1..100")
+    check(readers.percentile([], 50) is None, "percentile of nothing")
+    # open loop: timed from when it was due, failures count as infinite
+    rec = np.array([[10.0, 10.5, 10.7, 1], [11.0, 11.0, 11.1, 1],
+                    [12.0, 12.0, 12.2, 0], [13.0, 13.0, 15.5, 1]])
+    run = readers.Run(records=rec, t_open=10.0, t_close=15.0,
+                      traffic={"batch": 64})
+    lat = run.latencies_ms()
+    check(abs(lat[0] - 700) < 1e-6 and np.isinf(lat[2]),
+          "latency from the due time; a failed request is infinite")
+    check(abs(readers.request_stat(run, "p50") - 700) < 1e-6, "request p50")
+    check(readers.request_stat(run, "p99") is None,
+          "a tail that holds a failure has no finite reading")
+    check(abs(readers.request_stat(run, "p99", "late") - 500) < 1e-6,
+          "generator lateness")
+    check(readers.rate(run, "batch") == 2 * 64 / 5.0,
+          "rate: replies complete by the close, over all the window")
+    check(readers.stall_seconds(run) == 3 * 60 / 5,
+          "stall seconds per minute: seconds with no completion")
+    import run as harness
+
+    mix = load("traffic", "single_open_flat768.json")
+    a, b = harness.arrivals(mix, 1, 45), harness.arrivals(mix, 2**31 + 5, 45)
+    check(len(a) == len(b) == int(mix["rate_per_s"] * 45),
+          "every seed gets the same number of arrivals")
+    ga = np.sort(np.diff(np.concatenate([a, [45.0]])))
+    gb = np.sort(np.diff(np.concatenate([b, [45.0]])))
+    check(np.allclose(ga[1:-1], gb[1:-1], rtol=0.2),
+          "the same set of gaps, up to the two at the window's ends")
+    check(0 < a[0] and a[-1] < 45 and 0 < b[0] and b[-1] < 45,
+          "arrivals stay inside the window")
+    check(not np.array_equal(a, b), "another seed, another order")
+
+
+# ------------------------------------------------------------- 4. comparison
+def test_comparison() -> None:
+    seed, rows, k = 2**31 + 11, 20_000, 10
+    config = dict(load("configs", "ivf768.json"), rows=rows)
+    dist = reference.check_supported(config)
+    data = reference.Data(seed, config)
+    x = data.corpus()
+    check(len(x) == rows and np.array_equal(
+        x[:data.block_rows], reference.Data(seed, config).block(0)),
+        "the corpus is the same made whole or block by block")
+    check(data.n_clusters == 64 and reference.Data(
+        seed, load("configs", "ivf768.json")).n_clusters == 160,
+        "clusters by the configuration's assumed.corpus")
+    q = data.query_pool(256)
+    ids, d = reference.exact_topk(dist, x, q, k)
+    brute = np.argsort(((x[None, :, :].astype(np.float64)
+                         - q[:2, None, :]) ** 2).sum(-1), axis=1)[:, :k]
+    check(np.array_equal(ids[:2], brute),
+          "exact_topk agrees with a brute-force float64 sort")
+    truth = np.arange(len(q))
+    good = reference.compare_replies(
+        dist, x, q, ids, d.astype(np.float32), k, truth)
+    check(good["recall_at_10"] == 1.0 and good["dist_err"] < 1e-6
+          and good["malformed_items"] == 0,
+          f"a perfect program passes (dist_err {good['dist_err']:.2e})")
+    cid, cd = reference.control_topk(dist, x, q, k)
+    ctl = reference.compare_replies(dist, x, q, cid, cd, k, truth)
+    limit = load("configs", "ivf768.json")["limits"]["dist_err"]["max"]
+    check(ctl["dist_err"] > 3 * limit,
+          f"the bfloat16 control fails dist_err: {ctl['dist_err']:.2e} "
+          f"against the limit {limit:.0e}")
+    bad = ids.copy()
+    bad[0, 0] = (bad[0, 0] + 1) % rows
+    alt = reference.compare_replies(
+        dist, x, q, bad, d.astype(np.float32), k, truth)
+    check(alt["dist_err"] > limit, "an altered id fails dist_err")
+    short = ids.copy()
+    short[3, 5:] = -1
+    check(reference.compare_replies(
+        dist, x, q, short, d.astype(np.float32), k, truth
+    )["malformed_items"] == 5, "a short reply is malformed")
+    # a metric whose wire values descend (inner product), as a module would
+    # state it: the comparison's order follows the module, not L2
+    import types
+
+    ip = types.SimpleNamespace(
+        ASCENDING=False, norms=lambda x: np.zeros(len(x), np.float32),
+        rank32=lambda q_, xs, _n: -(q_ @ xs.T),
+        served64=lambda x_, q_, ids_: np.einsum(
+            "qd,qkd->qk", q_.astype(np.float64), x_[ids_].astype(np.float64)),
+        scale=lambda x_, q_, ids_: np.ones(ids_.shape))
+    iid, idd = reference.exact_topk(ip, x, q[:16], k)
+    brute = np.argsort(-(q[:16].astype(np.float64) @ x.T.astype(np.float64)),
+                       axis=1)[:, :k]
+    check(np.array_equal(iid, brute) and (np.diff(idd, axis=1) <= 0).all(),
+          "a descending metric: exact_topk is the largest, largest first")
+    got = reference.compare_replies(ip, x, q[:16], iid, idd, k, np.arange(16))
+    check(got["recall_at_10"] == 1.0 and got["unsorted_rows"] == 0,
+          "a descending metric: a perfect program passes")
+    check(reference.compare_replies(
+        ip, x, q[:16], iid[:, ::-1], idd[:, ::-1], k, np.arange(16)
+    )["unsorted_rows"] == 16, "a descending metric: ascending replies fail")
+    # what the harness cannot honour is refused, not run as fp32 L2
+    import run as harness
+
+    for key, value in (("metric", "IP"), ("precision", "sq8"),
+                       ("dimension", 512)):
+        try:
+            harness.check_config(dict(config, **{key: value}))
+            check(False, f"a configuration stating {key} {value!r} is refused")
+        except harness.RunFailure:
+            check(True, f"a configuration stating {key} {value!r} is refused")
+    recipe = dict(config["index_parameter"], metric_type="METRIC_TYPE_COSINE")
+    try:
+        harness.check_config(dict(config, index_parameter=recipe))
+        check(False, "a recipe that differs from the stated metric is refused")
+    except harness.RunFailure:
+        check(True, "a recipe that differs from the stated metric is refused")
+    harness.check_config(config)
+
+
+# ------------------------------------------------------------- 5. rehearsals
+def rehearse(workload: str, *extra):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    got = subprocess.run(
+        [sys.executable, os.path.join(HERE, "run.py"), "--workload", workload,
+         "--seed", str(2**31 + 77), "--seconds", "4", "--trace", "0",
+         "--rows", "8192", "--no-align", *extra],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
+    lines = got.stdout.strip().splitlines()
+    check(got.returncode == 3, f"{workload} {extra}: a rehearsal exits 3 "
+          f"(got {got.returncode}) {got.stderr[-500:] if got.returncode != 3 else ''}")
+    check(all("platform: cpu" in ln for ln in lines),
+          f"{workload} {extra}: every line says platform: cpu")
+    last = json.loads(lines[-1].split("  [platform")[0])
+    check(last["correct"] is False and last["rehearsal"] is True,
+          f"{workload} {extra}: the last line is not a pass")
+    return last
+
+
+def test_rehearsals() -> None:
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    got = subprocess.run(
+        [sys.executable, os.path.join(HERE, "run.py"), "--workload",
+         "ivf768.batch64", "--seed", "1", "--seconds", "4", "--trace", "0"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=120)
+    check(got.returncode != 0 and not got.stdout.strip(),
+          "without a chip and without --rows: no result, non-zero exit")
+    check(rehearse("ivf768.batch64")["correct_if_it_were_a_chip"] is True,
+          "ivf768.batch64: the sound path would be correct")
+    check(rehearse("ivf768.batch64", "--wrap-client", "faults.py:alter_answer")[
+        "correct_if_it_were_a_chip"] is False,
+        "an answer altered where it is produced: not correct")
+    check(rehearse("ivf768.batch64", "--wrap-client", "faults.py:half_batch")[
+        "correct_if_it_were_a_chip"] is False,
+        "half of the batch left out: not correct")
+    check(rehearse("ivf768.insert64")["correct_if_it_were_a_chip"] is True,
+          "ivf768.insert64: the sound path would be correct")
+    check(rehearse("ivf768.insert64", "--wrap-client", "faults.py:drop_row")[
+        "correct_if_it_were_a_chip"] is False,
+        "an acknowledged row that was never written: not correct")
+    check(rehearse("flat768.single", "--control", "bf16")["control"][
+        "correct"] is False,
+        "the bfloat16 control in the program's place: not correct")
+
+
+def main() -> int:
+    test_work()
+    test_arithmetic()
+    test_comparison()
+    test_reducer()
+    if "quick" not in sys.argv[1:]:
+        test_rehearsals()
+    print("selftest passed", flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
