@@ -516,6 +516,13 @@ def build_parser() -> argparse.ArgumentParser:
                     help="Chrome trace_event form (chrome://tracing / "
                          "Perfetto / tools/trace_report.py) instead of "
                          "the grouped-by-trace JSON")
+    prof = dbg.add_parser("profile")
+    prof.add_argument("--store", dest="target_store", required=True)
+    prof.add_argument("--seconds", type=float, default=5.0)
+    prof.add_argument("--dir", default="",
+                      help="directory on the STORE's machine for the "
+                           ".xplane.pb and spans.json (default: a fresh "
+                           "temporary one, named in the reply)")
     fp = dbg.add_parser("failpoint")
     fp.add_argument("--store", dest="target_store", required=True)
     fp.add_argument("name")
@@ -785,6 +792,11 @@ def run_command(client: DingoClient, args) -> int:
             print(stub.TraceChromeDump(pb.MetricsDumpRequest()).json)
         else:
             print(stub.TraceDump(pb.MetricsDumpRequest()).json)
+    elif g == "debug" and c == "profile":
+        stub = client._stub(args.target_store, "DebugService")
+        r = stub.DeviceProfile(pb.MetricsDumpRequest(format=json.dumps(
+            {"seconds": args.seconds, "dir": args.dir})))
+        print(r.json if r.error.errcode == 0 else r.error.errmsg)
     elif g == "debug" and c == "failpoint":
         stub = client._stub(args.target_store, "DebugService")
         r = stub.FailPoint(pb.FailPointRequest(

@@ -13,6 +13,7 @@ import time
 from typing import Callable, Dict, List, Optional
 
 from dingo_tpu.common.log import get_logger
+from dingo_tpu.trace import TRACER
 
 _log = get_logger("crontab")
 
@@ -96,7 +97,10 @@ class CrontabManager:
                     due.append(tab)
         for tab in due:
             try:
-                tab.func()
+                # cron.<name>: a background span (recorded at any
+                # trace_sampling_rate > 0, feeds background.busy_ms)
+                with TRACER.start_background("cron." + tab.name):
+                    tab.func()
                 tab.run_count += 1
             except Exception as e:  # noqa: BLE001
                 tab.error_count += 1

@@ -32,6 +32,7 @@ from dingo_tpu.index.vector_reader import serialize_scalar, serialize_vector
 from dingo_tpu.mvcc.codec import MAX_TS, Codec, ValueFlag
 from dingo_tpu.store.region import Region
 from dingo_tpu.raft import wire
+from dingo_tpu.trace import TRACER
 
 
 def apply_write(
@@ -234,10 +235,10 @@ def _apply_vector_add(
 
     wrapper = region.vector_index_wrapper
     if wrapper is not None and wrapper.is_ready():
-        if data.is_update:
-            wrapper.add(data.ids, data.vectors, log_id, is_upsert=True)
-        else:
-            wrapper.add(data.ids, data.vectors, log_id, is_upsert=False)
+        # index.upsert: assign, scatter, view maintenance
+        with TRACER.start_child("index.upsert"):
+            wrapper.add(data.ids, data.vectors, log_id,
+                        is_upsert=bool(data.is_update))
 
 
 def _apply_vector_delete(

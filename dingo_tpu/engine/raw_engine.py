@@ -348,10 +348,15 @@ class WalEngine(MemEngine):
             self._checkpoint_locked()
 
     def _checkpoint_locked(self) -> None:
-        super().checkpoint(self._ckpt_dir)
-        self._wal.close()
-        self._wal = open(self._wal_path, "wb")
-        self._wal_bytes = 0
+        # the whole-state rewrite, no write acknowledged meanwhile: a
+        # background span (recorded at any sampling rate > 0; inside a
+        # sampled writer's request it shows in that request's trace)
+        with TRACER.start_background("engine.wal_checkpoint") as span:
+            span.set_attr("wal_bytes", self._wal_bytes)
+            super().checkpoint(self._ckpt_dir)
+            self._wal.close()
+            self._wal = open(self._wal_path, "wb")
+            self._wal_bytes = 0
 
     def close(self) -> None:
         self._wal.close()

@@ -33,7 +33,7 @@ from dingo_tpu.index.rerank_cache import DeviceRerankCache
 from dingo_tpu.index.slot_store import SlotStore, SqSlotStore, _next_pow2
 from dingo_tpu.ops.distance import (
     Metric,
-    device_wait_span,
+    device_wait_begin,
     np_normalize,
     score_matrix,
     scores_to_distances,
@@ -41,6 +41,7 @@ from dingo_tpu.ops.distance import (
 from dingo_tpu.ops.topk import begin_host_fetch, topk_scores
 from dingo_tpu.obs.quality import QUALITY
 from dingo_tpu.obs.sentinel import sentinel_jit
+from dingo_tpu.trace import TRACER
 
 
 @sentinel_jit("index.flat.search", static_argnames=("k", "metric", "nbits"))
@@ -386,78 +387,90 @@ class _SlotStoreIndex(VectorIndex):
         ``jax.device_get`` on the whole fetch tuple (dists, slots, and
         the prune-stats block when present) — dingolint's resolve-sync
         checker enforces this across index families."""
-        queries = self._prep_queries(queries)
-        b = queries.shape[0]
-        qpad = staged.take(queries) if staged is not None else None
-        if qpad is None:
-            qpad = jnp.asarray(_pad_batch(queries))
-        store = self.store
-        # lease BEFORE dispatch: kernel-produced slots must stay limbo-
-        # parked (not reassigned) until resolve translates them
-        lease = store.begin_search()
-        self._count_search()
-        try:
-            with store.device_lock:
-                # mask capture AND dispatch under the device lock: a
-                # concurrent donated write or growth would invalidate the
-                # vecs reference / change the capacity mid-dispatch
-                if filter_spec is None or filter_spec.is_empty():
-                    mask = store.device_mask()
-                else:
-                    mask = jnp.asarray(
-                        filter_spec.slot_mask(store.ids_by_slot)
-                    )
-                kprime = self._rerank_shortlist(int(topk))
-                dists, slots, stats = self._run_search_kernel(
-                    qpad, mask, kprime or int(topk)
-                )
-                if kprime is not None:
-                    # exact rerank of the quantized shortlist, still under
-                    # the lock (cache arrays share it) and still async
-                    dists, slots = self._dispatch_rerank(
-                        qpad, dists, slots, int(topk)
-                    )
-        except Exception:
-            lease.release()
-            raise
-        if kprime is not None:
-            # sampled traces get a true ops.rerank kernel-time span
-            # (outside the lock; no-op when the request isn't sampled)
-            device_wait_span("rerank", (dists, slots))
-        # Start the D2H copy as soon as the kernel finishes — ONE group
-        # covering the whole reply (stats included): the fetch round
-        # trip then overlaps across in-flight searches instead of
-        # serializing at resolve time.
-        fetch = begin_host_fetch(dists, slots, stats)
-        # trace hook OUTSIDE the device lock: a sampled request blocks for
-        # a true kernel-time span without stalling concurrent searches
-        device_wait_span("flat_scan", (dists, slots))
         from dingo_tpu.obs.heat import HEAT, heat_enabled
 
-        heat_on = heat_enabled()
-        if heat_on:
-            HEAT.register_layout(self.id, "slot", self._heat_layout)
+        store = self.store
+        # index.dispatch: entry to kernels enqueued (prep, pad and H2D,
+        # mask capture, enqueue); NOOP for an unsampled request
+        with TRACER.start_child("index.dispatch"):
+            queries = self._prep_queries(queries)
+            b = queries.shape[0]
+            qpad = staged.take(queries) if staged is not None else None
+            if qpad is None:
+                qpad = jnp.asarray(_pad_batch(queries))
+            # lease BEFORE dispatch: kernel-produced slots must stay limbo-
+            # parked (not reassigned) until resolve translates them
+            lease = store.begin_search()
+            self._count_search()
+            try:
+                # asking for the lock to holding it (timed when sampled)
+                with TRACER.start_child("index.lock_wait"):
+                    store.device_lock.acquire()
+                try:
+                    # mask capture AND dispatch under the device lock: a
+                    # concurrent donated write or growth would invalidate
+                    # the vecs reference / change the capacity mid-dispatch
+                    if filter_spec is None or filter_spec.is_empty():
+                        mask = store.device_mask()
+                    else:
+                        mask = jnp.asarray(
+                            filter_spec.slot_mask(store.ids_by_slot)
+                        )
+                    kprime = self._rerank_shortlist(int(topk))
+                    dists, slots, stats = self._run_search_kernel(
+                        qpad, mask, kprime or int(topk)
+                    )
+                    if kprime is not None:
+                        # exact rerank of the quantized shortlist, still
+                        # under the lock (cache arrays share it) and still
+                        # async
+                        dists, slots = self._dispatch_rerank(
+                            qpad, dists, slots, int(topk)
+                        )
+                finally:
+                    store.device_lock.release()
+            except Exception:
+                lease.release()
+                raise
+            # Start the D2H copy as soon as the kernel finishes — ONE group
+            # covering the whole reply (stats included): the fetch round
+            # trip then overlaps across in-flight searches instead of
+            # serializing at resolve time.
+            fetch = begin_host_fetch(dists, slots, stats)
+            heat_on = heat_enabled()
+            if heat_on:
+                HEAT.register_layout(self.id, "slot", self._heat_layout)
+        # the device wait of a sampled request: from here (kernels
+        # enqueued, lock released) to the fetch's return in resolve();
+        # never a sync of its own (ops/distance.device_wait_begin)
+        wait = device_wait_begin(
+            "flat_scan" if kprime is None else "rerank")
+
         def resolve() -> List[SearchResult]:
             try:
                 fetched = jax.device_get(fetch)
-                dists_h, slots_h = fetched[0], fetched[1]
-                if stats is not None:
-                    self._note_prune_stats(fetched[2][:b])
-                if heat_on:
-                    # result slots -> slot-block heat units, from the
-                    # array this resolve ALREADY fetched (no new sync;
-                    # -1 padding filtered on the heat worker)
-                    HEAT.observe(self.id, "slot", slots_h[:b])
-                ids = store.ids_of_slots(slots_h[:b])
-                dists_h = self._convert_distances(dists_h)
-                # head-sampled shadow scoring (async lane; noop at rate 0);
-                # filtered searches carry their spec so the ground truth
-                # is restricted to the same candidate set
-                QUALITY.observe_search(
-                    self, queries, topk, ids, dists_h[:b], bucket="flat",
-                    filter_spec=filter_spec,
-                )
-                return [strip_invalid(i, d) for i, d in zip(ids, dists_h[:b])]
+                wait.end()
+                # index.resolve: the host work after the fetch
+                with TRACER.start_child("index.resolve"):
+                    dists_h, slots_h = fetched[0], fetched[1]
+                    if stats is not None:
+                        self._note_prune_stats(fetched[2][:b])
+                    if heat_on:
+                        # result slots -> slot-block heat units, from the
+                        # array this resolve ALREADY fetched (no new sync;
+                        # -1 padding filtered on the heat worker)
+                        HEAT.observe(self.id, "slot", slots_h[:b])
+                    ids = store.ids_of_slots(slots_h[:b])
+                    dists_h = self._convert_distances(dists_h)
+                    # head-sampled shadow scoring (async lane; noop at
+                    # rate 0); filtered searches carry their spec so the
+                    # ground truth is restricted to the same candidate set
+                    QUALITY.observe_search(
+                        self, queries, topk, ids, dists_h[:b],
+                        bucket="flat", filter_spec=filter_spec,
+                    )
+                    return [strip_invalid(i, d)
+                            for i, d in zip(ids, dists_h[:b])]
             finally:
                 lease.release()
 

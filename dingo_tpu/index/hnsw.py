@@ -575,9 +575,10 @@ class TpuHnsw(_SlotStoreIndex):
         from dingo_tpu.ops.topk import begin_host_fetch
 
         fetch = begin_host_fetch(dists, out_slots, hops, vcount, occ)
-        from dingo_tpu.ops.distance import device_wait_span
+        from dingo_tpu.ops.distance import device_wait_begin
 
-        device_wait_span("beam_search", (dists, out_slots))
+        # device wait of a sampled request, ended at resolve()'s one fetch
+        wait = device_wait_begin("beam_search")
         from dingo_tpu.obs.heat import HEAT, heat_enabled
 
         heat_on = heat_enabled()
@@ -589,6 +590,7 @@ class TpuHnsw(_SlotStoreIndex):
                 dists_h, slots_h, hops_h, vc_h, occ_h = jax.device_get(
                     fetch
                 )
+                wait.end()
                 self._note_walk_stats(
                     hops_h[:b], vc_h[:b], occ_h[:b], cap, beam
                 )

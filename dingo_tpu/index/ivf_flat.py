@@ -510,16 +510,10 @@ class IvfViewMaintenance:
         issued."""
         if not self.is_trained():
             return 0
-        n = 0
-        with TRACER.start_span("ivf.warmup") as span:
-            self._ensure_view()
-            for bsz in batches:
-                self.search(self._warmup_queries(int(bsz)), topk,
-                            nprobe=nprobe)
-                n += 1
-            if span.sampled:
-                span.set_attr("searches", n)
-        return n
+        self._ensure_view()
+        for bsz in batches:
+            self.search(self._warmup_queries(int(bsz)), topk, nprobe=nprobe)
+        return len(batches)
 
 
 class TpuIvfFlat(IvfViewMaintenance, _SlotStoreIndex):
@@ -836,167 +830,210 @@ class TpuIvfFlat(IvfViewMaintenance, _SlotStoreIndex):
     ):
         if not self.is_trained():
             raise NotTrained("IVF_FLAT not trained")  # reader falls back
-        queries = self._prep_queries(queries)
-        self._ensure_view()
-        self._count_search()
-        b = queries.shape[0]
-        topk = int(topk)
-        # request-pinned nprobe wins; else the SLO tuner's override; else
-        # the configured default (obs/tuner.py walks ladder values only)
-        nprobe = min(
-            nprobe or self.tuned("nprobe", self.parameter.default_nprobe),
-            self.nlist,
-        )
-        kprime = self._rerank_shortlist(topk)
-        k_eff, nprobe = self._shape_buckets(max(topk, kprime or 0), nprobe)
-        # staging-ring upload (serving pipeline): claimed only when the
-        # identity check proves it was built from THESE queries
-        qpad = staged.take(queries) if staged is not None else None
-        if qpad is None:
-            qpad = jnp.asarray(_pad_batch(queries))
-        # lease BEFORE dispatch: kernel slots must stay limbo-parked until
-        # resolve translates them (delete+reinsert would misattribute)
-        lease = self.store.begin_search()
-        try:
-            probes = _probe_lists(qpad, self.centroids, self._c_sqnorm, nprobe)
-            fprep = self._prep_filter_mask(filter_spec)
-            from dingo_tpu.common.config import pallas_ivf_enabled
-
-            # view snapshot + dispatch under the device lock: the
-            # incremental write path DONATES bucket arrays to its scatter
-            # programs, so a concurrent write must not invalidate a
-            # captured reference between here and dispatch (same contract
-            # as slot_store.put); reading self._view inside the same hold
-            # keeps view metadata and self._buckets consistent
-            stats = None
-            with self.store.device_lock:
-                view = self._view
-                vprobes = expand_probes(
-                    probes, view.probe_table, nprobe, view.max_spill
-                )
-                valid = self._bucket_valid_for_filter(filter_spec, fprep)
-                # kernel keeps top-k in a 128-lane output block; larger
-                # k (and its unrolled select rounds) stays on XLA
-                pallas_ok = (
-                    pallas_ivf_enabled(self.dimension)
-                    and self.metric in (
-                        Metric.L2, Metric.INNER_PRODUCT, Metric.COSINE
-                    )
-                    and k_eff <= 64
-                )
-                float_store = self.store.vecs.dtype in (
-                    jnp.float32, jnp.bfloat16
-                )
-                if pallas_ok and self._bucket_bsq is not None and (
-                    float_store or self._precision == "sq8"
-                ):
-                    # dimension-blocked early-pruning scan: partial
-                    # distances per block, candidates that cannot beat
-                    # the running k-th best stop scanning
-                    from dingo_tpu.ops.distance import metric_ascending
-                    from dingo_tpu.ops.pallas_ivf import ivf_pruned_search
-
-                    sq = self._precision == "sq8"
-                    dblk = self.dimension // self._bucket_bsq.shape[1]
-                    vals, slots, stats = ivf_pruned_search(
-                        vprobes, qpad, self._buckets, self._bucket_bsq,
-                        self._bucket_sqnorm, valid, view.bucket_slot,
-                        k=k_eff, dim_block=dblk,
-                        ascending=metric_ascending(self._scan_metric),
-                        sq_vmin=self.store.sq_vmin_d if sq else None,
-                        sq_scale=self.store.sq_scale_d if sq else None,
-                    )
-                    dists = scores_to_distances(vals, self._scan_metric)
-                elif pallas_ok and float_store:
-                    from dingo_tpu.ops.distance import metric_ascending
-                    from dingo_tpu.ops.pallas_ivf import ivf_list_search
-
-                    vals, slots = ivf_list_search(
-                        vprobes, qpad, self._buckets, self._bucket_sqnorm,
-                        valid, view.bucket_slot, k=k_eff,
-                        ascending=metric_ascending(self._scan_metric),
-                    )
-                    dists = scores_to_distances(vals, self._scan_metric)
-                elif self._precision == "sq8":
-                    dists, slots = _ivf_scan_kernel_sq(
-                        self._buckets,
-                        self._bucket_sqnorm,
-                        valid,
-                        view.bucket_slot,
-                        self.store.sq_vmin_d,
-                        self.store.sq_scale_d,
-                        vprobes,
-                        qpad,
-                        k=k_eff,
-                        metric=self._scan_metric,
-                    )
-                else:
-                    dists, slots = _ivf_scan_kernel(
-                        self._buckets,
-                        self._bucket_sqnorm,
-                        valid,
-                        view.bucket_slot,
-                        vprobes,
-                        qpad,
-                        k=k_eff,
-                        metric=self._scan_metric,
-                    )
-                if kprime is not None:
-                    # exact rerank of the quantized shortlist against the
-                    # device row cache, dispatched under the same lock
-                    # (cache arrays share it); still fully async
-                    dists, slots = self._dispatch_rerank(
-                        qpad, dists, slots, topk
-                    )
-        except Exception:
-            lease.release()
-            raise
-        if kprime is not None:
-            from dingo_tpu.ops.distance import device_wait_span
-
-            # sampled traces time the scan+rerank chain as ops.rerank
-            # (outside the lock; no-op for unsampled requests)
-            device_wait_span("rerank", (dists, slots))
-        store = self.store
-        # one-sync epilogue: the whole reply (prune stats included) joins
-        # a single D2H copy group; resolve device_gets it exactly once.
-        # The heat plane's probed-bucket ids ride the SAME group — the
-        # access sketch costs zero extra syncs (resolve-sync contract)
+        from dingo_tpu.common.config import pallas_ivf_enabled
         from dingo_tpu.obs.heat import HEAT, heat_enabled
+        from dingo_tpu.ops.distance import device_wait_begin, metric_ascending
 
-        heat_on = heat_enabled()
-        if heat_on:
-            HEAT.register_layout(self.id, "ivf", self._heat_layout)
-        fetch = begin_host_fetch(dists, slots, stats,
-                                 probes if heat_on else None)
+        store = self.store
+        # index.dispatch: entry to kernels enqueued (prep, pad and H2D,
+        # probe selection and expansion, enqueue); NOOP when unsampled
+        with TRACER.start_child("index.dispatch") as dspan:
+            queries = self._prep_queries(queries)
+            self._ensure_view()
+            self._count_search()
+            b = queries.shape[0]
+            topk = int(topk)
+            # request-pinned nprobe wins; else the SLO tuner's override;
+            # else the configured default (obs/tuner.py walks ladder
+            # values only)
+            nprobe = min(
+                nprobe
+                or self.tuned("nprobe", self.parameter.default_nprobe),
+                self.nlist,
+            )
+            kprime = self._rerank_shortlist(topk)
+            k_eff, nprobe = self._shape_buckets(
+                max(topk, kprime or 0), nprobe)
+            # staging-ring upload (serving pipeline): claimed only when
+            # the identity check proves it was built from THESE queries
+            qpad = staged.take(queries) if staged is not None else None
+            if qpad is None:
+                qpad = jnp.asarray(_pad_batch(queries))
+            # lease BEFORE dispatch: kernel slots must stay limbo-parked
+            # until resolve translates them (delete+reinsert would
+            # misattribute)
+            lease = store.begin_search()
+            try:
+                probes = _probe_lists(
+                    qpad, self.centroids, self._c_sqnorm, nprobe)
+                fprep = self._prep_filter_mask(filter_spec)
+                # view snapshot + dispatch under the device lock: the
+                # incremental write path DONATES bucket arrays to its
+                # scatter programs, so a concurrent write must not
+                # invalidate a captured reference between here and
+                # dispatch (same contract as slot_store.put); reading
+                # self._view inside the same hold keeps view metadata and
+                # self._buckets consistent
+                stats = None
+                # asking for the lock to holding it (timed when sampled)
+                with TRACER.start_child("index.lock_wait"):
+                    store.device_lock.acquire()
+                try:
+                    view = self._view
+                    vprobes = expand_probes(
+                        probes, view.probe_table, nprobe, view.max_spill
+                    )
+                    valid = self._bucket_valid_for_filter(filter_spec, fprep)
+                    # kernel keeps top-k in a 128-lane output block;
+                    # larger k (and its unrolled select rounds) stays on
+                    # XLA
+                    pallas_ok = (
+                        pallas_ivf_enabled(self.dimension)
+                        and self.metric in (
+                            Metric.L2, Metric.INNER_PRODUCT, Metric.COSINE
+                        )
+                        and k_eff <= 64
+                    )
+                    float_store = store.vecs.dtype in (
+                        jnp.float32, jnp.bfloat16
+                    )
+                    if pallas_ok and self._bucket_bsq is not None and (
+                        float_store or self._precision == "sq8"
+                    ):
+                        # dimension-blocked early-pruning scan: partial
+                        # distances per block, candidates that cannot beat
+                        # the running k-th best stop scanning
+                        from dingo_tpu.ops.pallas_ivf import (
+                            ivf_pruned_search,
+                        )
+
+                        stage = "pruned_scan"
+                        sq = self._precision == "sq8"
+                        dblk = self.dimension // self._bucket_bsq.shape[1]
+                        vals, slots, stats = ivf_pruned_search(
+                            vprobes, qpad, self._buckets, self._bucket_bsq,
+                            self._bucket_sqnorm, valid, view.bucket_slot,
+                            k=k_eff, dim_block=dblk,
+                            ascending=metric_ascending(self._scan_metric),
+                            sq_vmin=store.sq_vmin_d if sq else None,
+                            sq_scale=store.sq_scale_d if sq else None,
+                        )
+                        dists = scores_to_distances(vals, self._scan_metric)
+                    elif pallas_ok and float_store:
+                        from dingo_tpu.ops.pallas_ivf import ivf_list_search
+
+                        stage = "pallas_ivf_search"
+                        vals, slots = ivf_list_search(
+                            vprobes, qpad, self._buckets,
+                            self._bucket_sqnorm, valid, view.bucket_slot,
+                            k=k_eff,
+                            ascending=metric_ascending(self._scan_metric),
+                        )
+                        dists = scores_to_distances(vals, self._scan_metric)
+                    elif self._precision == "sq8":
+                        stage = "ivf_scan"
+                        dists, slots = _ivf_scan_kernel_sq(
+                            self._buckets,
+                            self._bucket_sqnorm,
+                            valid,
+                            view.bucket_slot,
+                            store.sq_vmin_d,
+                            store.sq_scale_d,
+                            vprobes,
+                            qpad,
+                            k=k_eff,
+                            metric=self._scan_metric,
+                        )
+                    else:
+                        stage = "ivf_scan"
+                        dists, slots = _ivf_scan_kernel(
+                            self._buckets,
+                            self._bucket_sqnorm,
+                            valid,
+                            view.bucket_slot,
+                            vprobes,
+                            qpad,
+                            k=k_eff,
+                            metric=self._scan_metric,
+                        )
+                    if kprime is not None:
+                        # exact rerank of the quantized shortlist against
+                        # the device row cache, dispatched under the same
+                        # lock (cache arrays share it); still fully async
+                        stage = "rerank"
+                        dists, slots = self._dispatch_rerank(
+                            qpad, dists, slots, topk
+                        )
+                finally:
+                    store.device_lock.release()
+            except Exception:
+                lease.release()
+                raise
+            # one-sync epilogue: the whole reply (prune stats included)
+            # joins a single D2H copy group; resolve device_gets it
+            # exactly once. The heat plane's probed-list ids and, for a
+            # sampled request, the probed bucket ids ride the SAME group:
+            # the access sketch and ivf.probed_rows_per_query cost zero
+            # extra syncs (resolve-sync contract)
+            heat_on = heat_enabled()
+            if heat_on:
+                HEAT.register_layout(self.id, "ivf", self._heat_layout)
+            probed = vprobes if dspan.sampled else None
+            fetch = begin_host_fetch(dists, slots, stats,
+                                     probes if heat_on else None, probed)
+        # the device wait of a sampled request: from here (kernels
+        # enqueued, lock released) to the fetch's return in resolve();
+        # never a sync of its own (ops/distance.device_wait_begin)
+        wait = device_wait_begin(stage)
+
         def resolve() -> List[SearchResult]:
             try:
                 fetched = jax.device_get(fetch)
-                dists_h, slots_h = fetched[0], fetched[1]
-                if stats is not None:
-                    # pruned-fraction observability rides the result
-                    # fetch — no extra sync on the dispatch path
-                    self._note_prune_stats(fetched[2][:b])
-                if heat_on:
-                    # probed bucket ids = which partitions this batch
-                    # actually read (bounded enqueue; folds async)
-                    HEAT.observe(self.id, "ivf", fetched[-1][:b])
-                # shape bucketing may have run a larger k; slice back
-                ids = store.ids_of_slots(slots_h[:b, :topk])
-                dists_h = self._convert_distances(dists_h[:b, :topk])
-                # head-sampled shadow scoring, attributed to the nprobe
-                # bucket actually scanned (async lane; noop at rate 0)
-                from dingo_tpu.obs.quality import QUALITY
+                wait.end()
+                # index.resolve: the host work after the fetch
+                with TRACER.start_child("index.resolve"):
+                    dists_h, slots_h = fetched[0], fetched[1]
+                    if stats is not None:
+                        # pruned-fraction observability rides the result
+                        # fetch — no extra sync on the dispatch path
+                        self._note_prune_stats(fetched[2][:b])
+                    if probed is not None:
+                        # joined LAST: [-1] whatever else is in the group
+                        self._note_probed_rows(view, fetched[-1][:b])
+                    if heat_on:
+                        # probed list ids = which partitions this batch
+                        # actually read (bounded enqueue; folds async)
+                        HEAT.observe(
+                            self.id, "ivf",
+                            fetched[-1 if probed is None else -2][:b])
+                    # shape bucketing may have run a larger k; slice back
+                    ids = store.ids_of_slots(slots_h[:b, :topk])
+                    dists_h = self._convert_distances(dists_h[:b, :topk])
+                    # head-sampled shadow scoring, attributed to the
+                    # nprobe bucket actually scanned (async lane; noop at
+                    # rate 0)
+                    from dingo_tpu.obs.quality import QUALITY
 
-                QUALITY.observe_search(
-                    self, queries, topk, ids, dists_h,
-                    bucket=f"nprobe={nprobe}", filter_spec=filter_spec,
-                )
-                return [strip_invalid(i, d) for i, d in zip(ids, dists_h)]
+                    QUALITY.observe_search(
+                        self, queries, topk, ids, dists_h,
+                        bucket=f"nprobe={nprobe}", filter_spec=filter_spec,
+                    )
+                    return [strip_invalid(i, d)
+                            for i, d in zip(ids, dists_h)]
             finally:
                 lease.release()
 
         return resolve
+
+    def _note_probed_rows(self, view, vprobes_h) -> None:
+        """Rows in the buckets a sampled batch probed, per query (mean),
+        from the bucket ids its one fetch brought and the view's host-side
+        fill counts: what the scan kernel walks, and what the benchmark's
+        work function assumes as nprobe * rows / nlist."""
+        vp = np.asarray(vprobes_h)
+        rows = np.where(vp >= 0, view.bucket_fill[np.maximum(vp, 0)], 0)
+        METRICS.gauge("ivf.probed_rows_per_query", region_id=self.id).set(
+            float(rows.sum(axis=1).mean()))
 
     # -- lifecycle -----------------------------------------------------------
     def save(self, path: str) -> None:

@@ -583,6 +583,7 @@ class TpuIvfPq(IvfViewMaintenance, _SlotStoreIndex):
         self._count_search()
         try:
             rerank = False
+            stage = "flat_scan"     # the untrained arm's exact scan
             # quality-estimator bucket: the untrained hybrid arm scans
             # EXACTLY regardless of any requested nprobe — labeling it
             # with the caller's nprobe would pool recall-1.0 evidence
@@ -662,6 +663,7 @@ class TpuIvfPq(IvfViewMaintenance, _SlotStoreIndex):
                     and precompute
                     and max(k_eff, kprime) <= 64
                 )
+                stage = "pallas_pq_adc" if use_fused_adc else "pq_scan"
                 with store.device_lock:
                     view = self._view
                     vprobes, coarse_pos = expand_probes_ranked(
@@ -721,10 +723,16 @@ class TpuIvfPq(IvfViewMaintenance, _SlotStoreIndex):
             HEAT.register_layout(self.id, "ivf", self._heat_layout)
         fetch = begin_host_fetch(dists, slots,
                                  probes if heat_on else None)
+        from dingo_tpu.ops.distance import device_wait_begin
+
+        # device wait of a sampled request, ended at resolve()'s first
+        # fetch (named for the fused ADC kernel when that arm ran)
+        wait = device_wait_begin(stage)
 
         def resolve() -> List[SearchResult]:
             try:
                 fetched = jax.device_get(fetch)
+                wait.end()
                 if heat_on:
                     # fetch tuple is positional over non-None members:
                     # probes joined LAST, so [-1] is safe in both arms

@@ -192,7 +192,7 @@ class VectorIndexManager:
             self.rebuild_running += 1
             self.rebuild_total += 1
         region_log(_log, region.id).info("index rebuild starting")
-        span = TRACER.start_span("index.rebuild")
+        span = TRACER.start_background("index.rebuild")
         span.set_attr("region_id", region.id)
         token = span.attach()
         try:
@@ -271,7 +271,7 @@ class VectorIndexManager:
         wrapper = region.vector_index_wrapper
         assert wrapper is not None and wrapper.own_index is not None
         path = self.snapshot_path(region.id)
-        with TRACER.start_span("index.save") as span, wrapper._lock:
+        with TRACER.start_background("index.save") as span, wrapper._lock:
             span.set_attr("region_id", region.id)
             wrapper.own_index.save(path)
             wrapper.snapshot_log_id = wrapper.apply_log_id

@@ -18,9 +18,10 @@ Per kernel the sentinel counts calls, cache hits, and traces; per trace it
 records the argument signature (dtype + shape bucket — the label that
 tells you WHICH shape broke the ladder), the compile wall time (gauge
 ``xla.compile_ms``, counters ``xla.recompiles`` / ``xla.compile_ms_total``)
-and an ``xla.compile`` span in the tracer — parented under the current
-request's trace when one is sampled, minted as a root otherwise: a compile
-stall is always evidence, never noise.
+and an ``xla.compile`` background span in the tracer (recorded at any
+``trace_sampling_rate`` above 0, whatever the head roll said) — parented
+under the current request's trace when one is sampled, minted as a root
+otherwise: a compile stall is evidence, never noise.
 
 Cost contract: a cache-hit call pays one thread-local push/pop, two clock
 reads, and one Counter.add. Signatures are only computed on a miss.
@@ -174,21 +175,15 @@ class RecompileSentinel:
 
     def _emit_compile_span(self, kernel: str, dur_ms: float,
                            sig: str) -> None:
-        """Record the compile stall as an `xla.compile` span. Parented
-        under the current sampled request span when there is one (the
-        stall shows up inside the victim's trace); otherwise minted as a
-        root regardless of the sampling rate — compiles are rare and
-        always worth the buffer slot."""
-        from dingo_tpu.trace.span import Span, TRACER, _gen_id, current_span
+        """Record the compile stall as an `xla.compile` background span
+        (the tracer's rule: under the current sampled request span when
+        there is one, so the stall shows up inside the victim's trace;
+        else a root of its own, at any sampling rate > 0). The compile is
+        only known once it is over, so the span is back-dated by it."""
+        from dingo_tpu.trace import TRACER
 
-        t1 = time.perf_counter_ns()
-        cur = current_span()
-        if cur is not None and cur.sampled:
-            span = Span(TRACER, "xla.compile", cur.trace_id,
-                        parent_id=cur.span_id)
-        else:
-            span = Span(TRACER, "xla.compile", _gen_id())
-        span.start_ns = t1 - int(dur_ms * 1e6)
+        span = TRACER.start_background(
+            "xla.compile", backdate_ns=int(dur_ms * 1e6))
         span.set_attr("kernel", kernel)
         if sig:
             span.set_attr("sig", sig)

@@ -200,20 +200,22 @@ def scores_to_distances(scores: jax.Array, metric: Metric) -> jax.Array:
     return scores
 
 
-def device_wait_span(name: str, value):
-    """Trace hook for device dispatch sites: when the current trace is
-    sampled, block until `value` (any jax pytree) is ready inside an
-    ``ops.<name>`` span, so the span measures real kernel time instead of
-    async-dispatch time. Otherwise value passes through untouched — one
-    sampled-check, no synchronization, no allocation (the span name is
-    only built once the check passes); a dispatch with no surrounding
-    request trace is never timed, so background kernels don't mint
-    single-span root traces."""
-    from dingo_tpu.trace import TRACER, current_span
+def device_wait_begin(name: str):
+    """Mark the point where a request's kernels have been enqueued. When
+    the current trace is sampled this starts the ``ops.<name>`` span (one
+    clock read) and returns it; the request's ``resolve()`` ends it right
+    after the ONE ``jax.device_get`` every reply already makes, so the
+    span reads "dispatch to result on the host". Nothing synchronises
+    here: a sampled request makes exactly the device calls an unsampled
+    one makes (an earlier version blocked until ready, under the store's
+    device lock on the IVF path, and a traced store measured another
+    regime). Otherwise NOOP_SPAN: one sampled-check, no clock, no
+    allocation. One span per request, named for the last kernel stage
+    enqueued; a dispatch with no surrounding request trace is never
+    timed, so background kernels don't mint single-span root traces."""
+    from dingo_tpu.trace import NOOP_SPAN, TRACER, current_span
 
     cur = current_span()
     if cur is None or not cur.sampled:
-        return value
-    with TRACER.start_span("ops." + name):
-        jax.block_until_ready(value)
-    return value
+        return NOOP_SPAN
+    return TRACER.start_child("ops." + name)

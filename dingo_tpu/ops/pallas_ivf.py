@@ -195,9 +195,6 @@ def ivf_list_search(
         vprobes, queries, buckets, bucket_sqnorm, bucket_valid, bucket_slot,
         k=k, ascending=ascending, interpret=pallas_interpret(), nq=b,
     )
-    from dingo_tpu.ops.distance import device_wait_span
-
-    vals, slots = device_wait_span("pallas_ivf_search", (vals, slots))
     return vals[:b], slots[:b]
 
 
@@ -520,10 +517,5 @@ def ivf_pruned_search(
         k=k, dim_block=dim_block, ascending=ascending, check_every=check,
         interpret=interpret, nq=b, sq=sq_vmin is not None,
         inbucket=bool(FLAGS.get("ivf_prune_inbucket_bound")),
-    )
-    from dingo_tpu.ops.distance import device_wait_span
-
-    vals, slots, stats = device_wait_span(
-        "pruned_scan", (vals, slots, stats)
     )
     return vals[:b], slots[:b], stats[:b]

@@ -207,7 +207,4 @@ def ivf_pq_adc_search(
         vprobes, coarse_pos, lut_all, code_buckets, bucket_valid,
         bucket_slot, k=k, interpret=pallas_interpret(), nq=b,
     )
-    from dingo_tpu.ops.distance import device_wait_span
-
-    vals, slots = device_wait_span("pallas_pq_adc", (vals, slots))
     return vals[:b], slots[:b]

@@ -42,7 +42,7 @@ from dingo_tpu.index.base import (
     resolve_precision,
     strip_invalid,
 )
-from dingo_tpu.ops.distance import Metric, np_normalize
+from dingo_tpu.ops.distance import Metric, device_wait_begin, np_normalize
 from dingo_tpu.parallel.sharded_store import (
     ShardedFlatStore,
     account_merge,
@@ -411,14 +411,14 @@ class TpuShardedFlat(VectorIndex):
                 METRICS.counter("mesh.fallback_searches").add(1)
             vals.copy_to_host_async()
             gslots.copy_to_host_async()
-            if span.sampled:
-                # sampled requests trade pipelining for a true kernel span
-                span.set_attr("batch", b)
-                jax.block_until_ready((vals, gslots))
+            span.set_attr("batch", b)
         ascending = self.metric is Metric.L2
+        # device wait of a sampled request, ended at the reply's one fetch
+        wait = device_wait_begin("mesh_search")
 
         def resolve() -> List[SearchResult]:
             vals_h, gslots_h = jax.device_get((vals, gslots))
+            wait.end()
             if not collective:
                 from dingo_tpu.parallel.sharded_store import merge_host_topk
 

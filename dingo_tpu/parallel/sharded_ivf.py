@@ -65,7 +65,12 @@ from dingo_tpu.index.ivf_layout import (
     expand_probes,
 )
 from dingo_tpu.index.slot_store import _next_pow2
-from dingo_tpu.ops.distance import Metric, scores_to_distances, squared_norms
+from dingo_tpu.ops.distance import (
+    Metric,
+    device_wait_begin,
+    scores_to_distances,
+    squared_norms,
+)
 from dingo_tpu.ops.kmeans import kmeans_assign
 from dingo_tpu.ops.topk import merge_sharded_topk
 from dingo_tpu.parallel.sharded_flat import TpuShardedFlat
@@ -394,9 +399,12 @@ class TpuShardedIvfFlat(TpuShardedFlat):
         vals.copy_to_host_async()
         gslots.copy_to_host_async()
         metric = self.metric
+        # device wait of a sampled request, ended at the reply's one fetch
+        wait = device_wait_begin("mesh_search")
 
         def resolve() -> List[SearchResult]:
             vals_h, gslots_h = jax.device_get((vals, gslots))
+            wait.end()
             vals_h, gslots_h = vals_h[:b], gslots_h[:b]
             safe = np.where(gslots_h >= 0, gslots_h, 0)
             ids = np.where(gslots_h >= 0, ids_by_gslot[safe], -1)
@@ -442,10 +450,8 @@ class TpuShardedIvfFlat(TpuShardedFlat):
                 ids_by_gslot = self.ids_by_gslot.copy()
             account_merge(self.mesh, int(qpad.shape[0]), int(topk),
                           region_id=self.id)
-            if span.sampled:
-                span.set_attr("batch", b)
-                span.set_attr("nprobe", int(nprobe))
-                jax.block_until_ready((vals, gslots))
+            span.set_attr("batch", b)
+            span.set_attr("nprobe", int(nprobe))
         return self._make_resolve(vals, gslots, b, ids_by_gslot)
 
     # -- lifecycle -----------------------------------------------------------

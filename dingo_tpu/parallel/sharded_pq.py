@@ -446,10 +446,8 @@ class TpuShardedIvfPq(TpuShardedIvfFlat):
                 ids_by_gslot = self.ids_by_gslot.copy()
             account_merge(self.mesh, int(qpad.shape[0]), k,
                           region_id=self.id)
-            if span.sampled:
-                span.set_attr("batch", b)
-                span.set_attr("nprobe", int(nprobe))
-                jax.block_until_ready((vals, gslots))
+            span.set_attr("batch", b)
+            span.set_attr("nprobe", int(nprobe))
         return self._make_resolve(vals, gslots, b, ids_by_gslot)
 
     # -- lifecycle -----------------------------------------------------------

@@ -12,8 +12,10 @@ import contextlib
 @contextlib.contextmanager
 def shard_search_span(name: str, mesh):
     """Span around a sharded search dispatch: records the mesh fan-out,
-    marks errors, and always ends — the body decides whether to pay
-    block_until_ready for a true kernel-time measurement (sampled only)."""
+    marks errors, and always ends. It times the dispatch only; the device
+    wait is the ``ops.mesh_search`` span the caller starts after it and
+    ends at resolve()'s one fetch (ops/distance.device_wait_begin) — a
+    sampled request never synchronises."""
     from dingo_tpu.trace import TRACER
 
     span = TRACER.start_span(name)

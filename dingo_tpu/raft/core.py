@@ -21,6 +21,7 @@ from typing import Callable, Dict, List, Optional
 from dingo_tpu.common.log import get_logger
 from dingo_tpu.raft.log import RaftLog
 from dingo_tpu.raft.transport import Transport
+from dingo_tpu.trace import NOOP_SPAN, TRACER, SpanContext
 
 _log = get_logger("raft.core")
 
@@ -90,6 +91,9 @@ class RaftNode:
         self._peer_last_ack: Dict[str, float] = {}
         self._stop = threading.Event()
         self._appliers_busy = False
+        #: log index -> SpanContext of the sampled raft.propose waiting
+        #: on it (leader side; empty unless requests are being traced)
+        self._propose_ctx: Dict[int, SpanContext] = {}
 
         transport.register(node_id, self._handle_rpc)
         self._ticker = threading.Thread(
@@ -153,24 +157,37 @@ class RaftNode:
         from dingo_tpu.common.failpoint import failpoint
 
         failpoint("before_raft_propose")
-        with self._lock:
-            if self.role != LEADER:
-                raise NotLeader(self.leader_id)
-            term = self.current_term
-            index = self.log.append(term, payload)
-            self.match_index[self.id] = index
-        self._broadcast_append()
-        deadline = time.monotonic() + timeout
-        with self._applied_cv:
-            while self.last_applied < index:
-                if self.log.term_at(index) != term:
-                    raise ProposalFailed(f"entry {index} overwritten")
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    raise ProposalFailed(f"timeout waiting for apply {index}")
-                self._applied_cv.wait(remaining)
-            if self.log.term_at(index) not in (term, None):
-                raise ProposalFailed(f"entry {index} overwritten")
+        # raft.propose: append to applied-locally, inside a sampled
+        # request; its context is kept per log index so that the entry's
+        # raft.apply (which may run on the ticker's thread) joins it
+        with TRACER.start_child("raft.propose") as span:
+            with self._lock:
+                if self.role != LEADER:
+                    raise NotLeader(self.leader_id)
+                term = self.current_term
+                index = self.log.append(term, payload)
+                self.match_index[self.id] = index
+                if span.sampled:
+                    span.set_attr("index", index)
+                    self._propose_ctx[index] = span.context
+            try:
+                self._broadcast_append()
+                deadline = time.monotonic() + timeout
+                with self._applied_cv:
+                    while self.last_applied < index:
+                        if self.log.term_at(index) != term:
+                            raise ProposalFailed(
+                                f"entry {index} overwritten")
+                        remaining = deadline - time.monotonic()
+                        if remaining <= 0:
+                            raise ProposalFailed(
+                                f"timeout waiting for apply {index}")
+                        self._applied_cv.wait(remaining)
+                    if self.log.term_at(index) not in (term, None):
+                        raise ProposalFailed(f"entry {index} overwritten")
+            finally:
+                if span.sampled:
+                    self._propose_ctx.pop(index, None)
         return index
 
     # ------------- ticker -------------
@@ -586,7 +603,12 @@ class RaftNode:
                     if entry is None:
                         break
                     payload = entry[1]
-                self.apply_fn(nxt, payload)
+                # leader side, sampled proposal: one entry's apply as a
+                # child of its raft.propose, whatever thread runs it
+                ctx = self._propose_ctx.get(nxt)
+                with (TRACER.start_span("raft.apply", parent=ctx)
+                      if ctx is not None else NOOP_SPAN):
+                    self.apply_fn(nxt, payload)
                 applied_any = True
                 with self._applied_cv:
                     self.last_applied = nxt

@@ -47,6 +47,7 @@ from dingo_tpu.raft import LocalTransport
 from dingo_tpu.server.rpc import DingoServer
 from dingo_tpu.store.checker import PreMergeChecker, PreSplitChecker
 from dingo_tpu.store.node import StoreNode
+from dingo_tpu.trace import TRACER
 
 _TRANSPORT = LocalTransport()   # in-process multi-role transport
 
@@ -149,6 +150,7 @@ def serve_coordinator(args) -> None:
         when_leader(ReplicaPlanScheduler(control).dispatch),
     )
     metrics_http = _maybe_metrics_http()
+    TRACER.watch_gc()     # full collections as gc.gen2 background spans
     crontab.start()
     print(f"coordinator {args.id} listening on 127.0.0.1:{port}"
           + (" (raft group)" if raft_coordinator else ""), flush=True)
@@ -377,6 +379,7 @@ def serve_store(args) -> None:
 
     FLIGHT.config_provider = _flight_node_config
     metrics_http = _maybe_metrics_http()
+    TRACER.watch_gc()     # full collections as gc.gen2 background spans
     crontab.start()
     print(f"store {args.id} listening on 127.0.0.1:{port}", flush=True)
     try:

@@ -161,6 +161,9 @@ SERVICE_SCHEMA: Dict[str, Dict[str, Tuple[type, type]]] = {
         # the method name alone routes — no proto regen needed
         "TraceDump": (pb.MetricsDumpRequest, pb.MetricsDumpResponse),
         "TraceChromeDump": (pb.MetricsDumpRequest, pb.MetricsDumpResponse),
+        # device profile + the interval's spans (trace/profile.py); the
+        # request's `format` carries {"seconds", "dir"} as JSON
+        "DeviceProfile": (pb.MetricsDumpRequest, pb.MetricsDumpResponse),
         "FailPoint": (pb.FailPointRequest, pb.FailPointResponse),
         "FlightDump": (pb.FlightDumpRequest, pb.FlightDumpResponse),
         # process-local control-plane event ring (obs/events.py)
@@ -356,7 +359,7 @@ class DingoServer:
         _register(self._server, "DocumentService", DocumentService(node))
         _register(self._server, "FileService", FileService(node))
         _register(self._server, "NodeService", NodeService(node))
-        _register(self._server, "DebugService", DebugService())
+        _register(self._server, "DebugService", DebugService(device=True))
         _register(self._server, "UtilService", UtilService())
         from dingo_tpu.server.services import RegionControlService
 
@@ -368,7 +371,7 @@ class DingoServer:
         from dingo_tpu.diskann.service import DiskAnnService
 
         _register(self._server, "DiskAnnService", DiskAnnService(manager))
-        _register(self._server, "DebugService", DebugService())
+        _register(self._server, "DebugService", DebugService(device=True))
 
     def host_coordinator_role(self, control, tso, kv_control,
                               meta=None, raft_transport=None) -> None:

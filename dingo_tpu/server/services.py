@@ -12,6 +12,7 @@ codegen plugin in this image); registration uses generic method handlers.
 from __future__ import annotations
 
 import json
+import tempfile
 import threading
 import time
 from concurrent.futures import TimeoutError as FuturesTimeoutError
@@ -37,6 +38,7 @@ from dingo_tpu.raft.core import NotLeader
 from dingo_tpu.server import convert, pb
 from dingo_tpu.store.node import StoreNode
 from dingo_tpu.store.region import Region, RegionType
+from dingo_tpu.trace import TRACER
 
 
 def _err(resp, code: int, msg: str):
@@ -218,11 +220,13 @@ class IndexService:
         lat = METRICS.latency("vector_search", region.id)
         t0 = time.perf_counter_ns()
         try:
-            binary = convert.is_binary_parameter(
-                region.definition.index_parameter
-            )
-            queries = convert.queries_from_pb(req.vectors, binary=binary)
-            kw = convert.search_kwargs_from_pb(req.parameter)
+            # service.decode: the request's boxed floats to arrays
+            with TRACER.start_child("service.decode"):
+                binary = convert.is_binary_parameter(
+                    region.definition.index_parameter
+                )
+                queries = convert.queries_from_pb(req.vectors, binary=binary)
+                kw = convert.search_kwargs_from_pb(req.parameter)
             if req.parameter.nprobe:
                 kw["nprobe"] = req.parameter.nprobe
             if req.parameter.ef_search:
@@ -331,16 +335,18 @@ class IndexService:
             black_box_error("rpc.IndexService.VectorSearch", e, ingress,
                             region_id=region.id)
             return _err(resp, 30001, str(e)), None
-        for row in results:
-            r = resp.batch_results.add()
-            for v in row:
-                item = r.results.add()
-                item.vector.id = v.id
-                item.distance = v.distance
-                if v.vector is not None:
-                    convert.fill_vector_pb(item.vector, v.vector)
-                if v.scalar:
-                    convert.scalar_to_pb(item.scalar_data, v.scalar)
+        # service.encode: the results into the reply message
+        with TRACER.start_child("service.encode"):
+            for row in results:
+                r = resp.batch_results.add()
+                for v in row:
+                    item = r.results.add()
+                    item.vector.id = v.id
+                    item.distance = v.distance
+                    if v.vector is not None:
+                        convert.fill_vector_pb(item.vector, v.vector)
+                    if v.scalar:
+                        convert.scalar_to_pb(item.scalar_data, v.scalar)
         lat.observe_us((time.perf_counter_ns() - t0) / 1000.0)
         if qos.qos_enabled():
             # throughput vs goodput: every reply counts served; only the
@@ -395,8 +401,10 @@ class IndexService:
         if region is None:
             return resp
         try:
-            ids, vectors, scalars, table_values = self._vector_batch_from_pb(
-                region, req.vectors)
+            # service.decode: the rows' boxed floats to arrays
+            with TRACER.start_child("service.decode"):
+                ids, vectors, scalars, table_values = \
+                    self._vector_batch_from_pb(region, req.vectors)
             ts = self.node.storage.vector_add(
                 region, ids, vectors, scalars,
                 is_update=req.is_update, ttl_ms=req.ttl_ms,
@@ -406,8 +414,9 @@ class IndexService:
             return _err(resp, 20001, f"not leader: {e.leader_hint}")
         except (VectorIndexError, ValueError) as e:
             return _err(resp, 30001, str(e))
-        resp.ts = ts
-        resp.key_states.extend([True] * len(req.vectors))
+        with TRACER.start_child("service.encode"):
+            resp.ts = ts
+            resp.key_states.extend([True] * len(req.vectors))
         METRICS.counter("vector_add", region.id).add(len(req.vectors))
         return resp
 
@@ -1401,6 +1410,11 @@ class FileService:
 
 
 class DebugService:
+    def __init__(self, device: bool = False):
+        #: whether this role holds the device (store, diskann): only such
+        #: a process may profile it (a coordinator must never touch one)
+        self._device = device
+
     def MetricsDump(self, req: pb.MetricsDumpRequest) -> pb.MetricsDumpResponse:
         resp = pb.MetricsDumpResponse()
         fmt = req.format or "json"
@@ -1431,6 +1445,30 @@ class DebugService:
 
         resp = pb.MetricsDumpResponse()
         resp.json = json.dumps(to_chrome_trace())
+        return resp
+
+    def DeviceProfile(self, req: pb.MetricsDumpRequest):
+        """Profile the device for a few seconds of whatever this store is
+        serving (trace/profile.py): `format` carries the parameters as
+        JSON, {"seconds": 5, "dir": "<where>"}; both optional (5 s, a
+        fresh temporary directory). Blocks for the interval; the reply's
+        JSON names the `.xplane.pb`, the spans file written beside it and
+        both clock pairs."""
+        resp = pb.MetricsDumpResponse()
+        if not self._device:
+            return _err(resp, 50004, "this role holds no device to profile")
+        from dingo_tpu.trace import profile
+
+        try:
+            params = json.loads(req.format) if req.format else {}
+            if not isinstance(params, dict):
+                raise ValueError("format must be a JSON object")
+            out_dir = params.get("dir") or tempfile.mkdtemp(
+                prefix="dingo_profile_")
+            resp.json = json.dumps(
+                profile.capture(out_dir, params.get("seconds", 5.0)))
+        except (ValueError, RuntimeError, OSError) as e:
+            return _err(resp, 50004, f"{type(e).__name__}: {e}")
         return resp
 
     def FailPoint(self, req: pb.FailPointRequest) -> pb.FailPointResponse:

@@ -10,7 +10,17 @@ and a Chrome ``trace_event`` file (chrome://tracing / Perfetto).
 
 Overhead contract: with ``trace_sampling_rate = 0`` every instrumented
 site costs ONE sampled-check (a contextvar read + flag read) and returns
-the shared no-op span — no allocations on the hot path.
+the shared no-op span — no allocations, no clock reads on the hot path.
+A traced request makes exactly the device calls an untraced one makes:
+no span ever synchronises with the device (ops/distance.device_wait_begin
+stamps the wait at the reply's one fetch).
+
+Layer-boundary spans (``Tracer.start_child``) exist only inside a sampled
+request; background work (crontab jobs, checkpoints, saves, full GCs,
+compiles: ``Tracer.start_background``) is recorded at any rate above 0.
+All timestamps are ``time.monotonic_ns()``. ``trace/profile.py`` captures
+a device profile with the spans mirrored into it (DebugService
+DeviceProfile).
 """
 
 from dingo_tpu.trace.buffer import TRACE_BUFFER, TraceBuffer

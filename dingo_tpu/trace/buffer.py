@@ -15,6 +15,8 @@ import threading
 from collections import deque
 from typing import Dict, List, Optional
 
+from dingo_tpu.common.metrics import METRICS
+
 
 class TraceBuffer:
     def __init__(self, capacity: int = 2048, slow_capacity: int = 256):
@@ -31,10 +33,12 @@ class TraceBuffer:
         with self._lock:
             if len(self._ring) < self.capacity:
                 self._ring.append(record)
-            else:
-                self._ring[self._pos] = record
-                self._pos = (self._pos + 1) % self.capacity
-                self._dropped += 1
+                return
+            self._ring[self._pos] = record
+            self._pos = (self._pos + 1) % self.capacity
+            self._dropped += 1
+        # the ring's overwrite count, where an operator's scrape sees it
+        METRICS.counter("trace.spans_dropped").add(1)
 
     def add_slow(self, record: Dict) -> None:
         with self._lock:

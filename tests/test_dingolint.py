@@ -170,7 +170,7 @@ _HOT_SYNC = """
             d = self._kernel(queries)
             vals = jax.device_get(d)        # BAD: sync at dispatch
             if self.span.sampled:
-                jax.block_until_ready(d)    # ok: sampled-trace guard
+                jax.block_until_ready(d)    # BAD: a sampled request syncs
 
             def resolve():
                 return jax.device_get(d)    # ok: designated sync point
@@ -182,15 +182,20 @@ _HOT_SYNC = """
 def test_host_sync_flags_dispatch_sync(tmp_path):
     findings = _lint(tmp_path, "dingo_tpu/index/bad.py", _HOT_SYNC,
                      HostSyncChecker())
-    assert len(findings) == 1
-    assert findings[0].lineno == 8
+    # a sampled-trace guard sanctions nothing: a traced request has to
+    # make the device calls an untraced one makes
+    assert [f.lineno for f in findings] == [8, 10]
     assert "device_get" in findings[0].message
+    assert "block_until_ready" in findings[1].message
 
 
 def test_host_sync_resolve_and_guard_clean(tmp_path):
     good = _HOT_SYNC.replace(
         "vals = jax.device_get(d)        # BAD: sync at dispatch",
         "vals = d",
+    ).replace(
+        "jax.block_until_ready(d)    # BAD: a sampled request syncs",
+        "self.span.set_attr('k', 1)",
     )
     assert _lint(tmp_path, "dingo_tpu/index/good.py", good,
                  HostSyncChecker()) == []

@@ -335,6 +335,344 @@ def test_conf_templates_carry_trace_keys():
         assert "slow_query_ms" in text
 
 
+# ---------------- layer-boundary and background spans ----------------
+
+def _busy_ms(job):
+    return METRICS.counter("background.busy_ms", labels={"job": job}).get()
+
+
+def _lose_the_roll(monkeypatch):
+    """Every head-sampling roll loses from here on (rate < 1)."""
+    import random
+
+    monkeypatch.setattr(random, "random", lambda: 0.999999)
+
+
+def test_rate_zero_new_sites_are_noop_and_buffer_stays_empty(tmp_path):
+    """trace_sampling_rate 0: every site this round added returns the
+    shared NOOP_SPAN (no allocation, no clock) and records nothing."""
+    import gc
+
+    from dingo_tpu.common.crontab import CrontabManager
+    from dingo_tpu.engine.raw_engine import CF_DEFAULT, WalEngine, WriteBatch
+    from dingo_tpu.ops.distance import device_wait_begin
+
+    FLAGS.set("trace_sampling_rate", 0.0)
+    TRACE_BUFFER.clear()
+    assert TRACER.start_child("index.dispatch") is NOOP_SPAN
+    assert TRACER.start_background("cron.x") is NOOP_SPAN
+    assert device_wait_begin("flat_scan") is NOOP_SPAN
+    # ... also inside an (unsampled) request context
+    token = NOOP_SPAN.attach()
+    try:
+        assert TRACER.start_child("raft.propose") is NOOP_SPAN
+        assert device_wait_begin("pruned_scan") is NOOP_SPAN
+    finally:
+        NOOP_SPAN.detach(token)
+    # the background sites themselves: a crontab job, a WAL checkpoint,
+    # a full collection
+    before = _busy_ms("cron.rate0_job")
+    cron = CrontabManager()
+    ran = []
+    cron.add("rate0_job", 0.0, lambda: ran.append(1), immediately=True)
+    assert cron.run_pending() == 1 and ran == [1]
+    eng = WalEngine(str(tmp_path), checkpoint_threshold_bytes=1 << 30)
+    eng.write(WriteBatch().put(CF_DEFAULT, b"k", b"v"))
+    eng.checkpoint()
+    eng.close()
+    TRACER.watch_gc()
+    try:
+        gc.collect()
+    finally:
+        gc.callbacks.remove(TRACER._on_gc)
+    assert TRACE_BUFFER.snapshot() == []
+    assert _busy_ms("cron.rate0_job") == before
+
+
+def test_start_child_never_mints_a_root(sampled):
+    # rate 1.0 but no request around: a boundary site records nothing
+    assert TRACER.start_child("index.dispatch") is NOOP_SPAN
+    with TRACER.start_span("rpc.X") as root:
+        with TRACER.start_child("index.dispatch") as child:
+            assert child.sampled and child.parent_id == root.span_id
+    assert [r["name"] for r in TRACE_BUFFER.snapshot()] == \
+        ["index.dispatch", "rpc.X"]
+
+
+def test_background_recorded_whatever_the_head_roll(sampled, monkeypatch):
+    """Rate 0.05 and a losing roll: a request is not traced, a
+    background job still is (the one save of a minute must not be lost
+    to the request sampler)."""
+    FLAGS.set("trace_sampling_rate", 0.05)
+    _lose_the_roll(monkeypatch)
+    assert TRACER.start_span("rpc.Lost") is NOOP_SPAN
+    with TRACER.start_background("cron.kept") as bg:
+        assert bg.sampled
+    recs = TRACE_BUFFER.snapshot()
+    assert [r["name"] for r in recs] == ["cron.kept"]
+    assert recs[0]["parent_id"] == ""           # a root of its own
+
+
+def test_background_child_of_sampled_request_else_root(sampled, monkeypatch):
+    with TRACER.start_span("rpc.Writer") as req:
+        with TRACER.start_background("engine.wal_checkpoint") as ck:
+            assert ck.trace_id == req.trace_id
+            assert ck.parent_id == req.span_id
+    # inside an UNSAMPLED request: a root of its own
+    FLAGS.set("trace_sampling_rate", 0.05)
+    _lose_the_roll(monkeypatch)
+    token = NOOP_SPAN.attach()
+    try:
+        with TRACER.start_background("engine.wal_checkpoint") as ck2:
+            assert ck2.sampled and ck2.parent_id == 0
+            assert ck2.trace_id != req.trace_id
+    finally:
+        NOOP_SPAN.detach(token)
+
+
+def test_background_busy_ms_counts_the_outermost_job_once(sampled):
+    outer0, inner0 = _busy_ms("cron.outer_job"), _busy_ms("index.save_t")
+    with TRACER.start_background("cron.outer_job"):
+        with TRACER.start_span("some.step"):          # ordinary child
+            with TRACER.start_background("index.save_t"):
+                time.sleep(0.02)
+    grew = _busy_ms("cron.outer_job") - outer0
+    assert 20.0 <= grew < 2000.0
+    # the nested job's time is inside the outer's: not counted twice
+    assert _busy_ms("index.save_t") == inner0
+    # alone, the same job counts under its own name
+    with TRACER.start_background("index.save_t"):
+        time.sleep(0.005)
+    assert _busy_ms("index.save_t") - inner0 >= 5.0
+
+
+def test_spans_recorded_and_dropped_reach_metrics(sampled):
+    rec0 = METRICS.counter("trace.spans_recorded").get()
+    for i in range(3):
+        TRACER.start_span(f"n{i}").end()
+    assert METRICS.counter("trace.spans_recorded").get() - rec0 == 3
+    drop0 = METRICS.counter("trace.spans_dropped").get()
+    small = TraceBuffer(capacity=2)
+    for i in range(5):
+        small.add({"name": f"s{i}", "trace_id": "t"})
+    assert small.stats()["dropped"] == 3
+    assert METRICS.counter("trace.spans_dropped").get() - drop0 == 3
+
+
+def test_span_clock_is_monotonic_ns_and_dur_never_zero(sampled):
+    t0 = time.monotonic_ns()
+    span = TRACER.start_span("tiny")
+    span.end()
+    t1 = time.monotonic_ns()
+    assert t0 <= span.start_ns <= span.end_ns <= t1
+    rec = TRACE_BUFFER.snapshot()[-1]
+    assert rec["dur_us"] >= 1                 # readers divide by it
+    assert rec["start_us"] == span.start_ns // 1000
+    # an unfinished span still reads 0 (nothing to divide yet)
+    assert TRACER.start_span("open").record()["dur_us"] == 0
+
+
+def test_crontab_job_is_a_background_span(sampled, monkeypatch):
+    from dingo_tpu.common.crontab import CrontabManager
+
+    FLAGS.set("trace_sampling_rate", 0.05)
+    _lose_the_roll(monkeypatch)
+    seen = {}
+    cron = CrontabManager()
+    cron.add("probe_job", 0.0,
+             lambda: seen.setdefault("cur", current_span()),
+             immediately=True)
+    before = _busy_ms("cron.probe_job")
+    assert cron.run_pending() == 1
+    recs = [r for r in TRACE_BUFFER.snapshot()
+            if r["name"] == "cron.probe_job"]
+    assert len(recs) == 1 and recs[0]["parent_id"] == ""
+    # the job ran INSIDE its span (a save it starts is its child)
+    assert seen["cur"].name == "cron.probe_job"
+    assert _busy_ms("cron.probe_job") > before
+    # a failing job still ends its span, marked as an error
+    cron.add("bad_job", 0.0, lambda: 1 / 0, immediately=True)
+    cron.run_pending()
+    bad = [r for r in TRACE_BUFFER.snapshot() if r["name"] == "cron.bad_job"]
+    assert bad and bad[0]["status"].startswith("error")
+
+
+def test_wal_checkpoint_span_inside_a_sampled_write(sampled, tmp_path):
+    from dingo_tpu.engine.raw_engine import CF_DEFAULT, WalEngine, WriteBatch
+
+    eng = WalEngine(str(tmp_path), checkpoint_threshold_bytes=256)
+    try:
+        with TRACER.start_span("rpc.Write") as req:
+            for i in range(8):
+                eng.write(WriteBatch().put(
+                    CF_DEFAULT, f"k{i}".encode(), b"x" * 128))
+            trace_id = f"{req.trace_id:016x}"
+    finally:
+        eng.close()
+    spans = TRACE_BUFFER.snapshot(trace_id=trace_id)
+    by_id = {s["span_id"]: s for s in spans}
+    cks = [s for s in spans if s["name"] == "engine.wal_checkpoint"]
+    assert cks, {s["name"] for s in spans}
+    for ck in cks:
+        # the rewrite happens under the write that tripped the threshold
+        assert by_id[ck["parent_id"]]["name"] == "engine.wal_write"
+        assert ck["attrs"]["wal_bytes"] >= 256
+
+
+def test_gc_gen2_is_a_background_span_with_a_pause_counter(sampled):
+    import gc
+
+    pause = METRICS.counter("gc.pause_ms", labels={"gen": "2"})
+    TRACER.watch_gc()
+    TRACER.watch_gc()                       # idempotent
+    assert gc.callbacks.count(TRACER._on_gc) == 1
+    try:
+        before = pause.get()
+        gc.collect(0)                       # young generations: ignored
+        TRACER.start_span("flush0").end()
+        assert all(r["name"] != "gc.gen2" for r in TRACE_BUFFER.snapshot())
+        with TRACER.start_span("rpc.Victim") as req:
+            gc.collect()                    # a full collection
+        # the callback only parks the pause (it may run under any lock);
+        # the next span to finish records it
+        recs = [r for r in TRACE_BUFFER.snapshot() if r["name"] == "gc.gen2"]
+        assert len(recs) == 1
+        assert recs[0]["parent_id"] == f"{req.span_id:016x}"
+        assert recs[0]["dur_us"] >= 1
+        assert pause.get() > before
+    finally:
+        gc.callbacks.remove(TRACER._on_gc)
+
+
+def test_compile_span_follows_the_background_rule():
+    from dingo_tpu.obs.sentinel import SENTINEL
+
+    TRACE_BUFFER.clear()
+    try:
+        FLAGS.set("trace_sampling_rate", 0.0)
+        SENTINEL._emit_compile_span("k.rate0", 12.0, "f32[8]")
+        assert TRACE_BUFFER.snapshot() == []
+        FLAGS.set("trace_sampling_rate", 1e-9)
+        t0 = time.monotonic_ns()
+        SENTINEL._emit_compile_span("k.on", 12.0, "f32[8]")
+        rec = TRACE_BUFFER.snapshot()[-1]
+        assert rec["name"] == "xla.compile" and rec["parent_id"] == ""
+        # back-dated by the compile's length through the tracer itself
+        assert 12_000 <= rec["dur_us"] < 12_000 + 50_000
+        assert rec["start_us"] <= t0 // 1000 - 11_000
+    finally:
+        FLAGS.set("trace_sampling_rate", 0.0)
+        TRACE_BUFFER.clear()
+
+
+def test_propose_apply_parentage_across_threads(sampled):
+    """raft.apply joins its entry's raft.propose through the context kept
+    per log index, also when the ticker's thread applies the entry."""
+    from dingo_tpu.raft import LocalTransport, RaftNode
+
+    applied_on = []
+    node = RaftNode(
+        "n0", ["n0"], LocalTransport(), seed=0,
+        apply_fn=lambda i, p: applied_on.append(
+            (threading.get_ident(), current_span())),
+    )
+    node.start()
+    try:
+        deadline = time.monotonic() + 5.0
+        while not node.is_leader() and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert node.is_leader()
+        # the proposer's own broadcast does nothing: the entry commits and
+        # applies on the ticker's next heartbeat, on the ticker's thread
+        proposer = threading.get_ident()
+        real = node._broadcast_append
+        node._broadcast_append = lambda: (
+            None if threading.get_ident() == proposer else real())
+        with TRACER.start_span("rpc.Add") as req:
+            index = node.propose(b"payload")
+            trace_id = f"{req.trace_id:016x}"
+        # an unsampled proposal leaves no context behind and no span
+        node.propose(b"untraced")
+    finally:
+        node.stop()
+    thread_id, cur = applied_on[0]
+    assert thread_id != proposer
+    assert cur is not None and cur.name == "raft.apply"
+    assert applied_on[1][1] is None
+    assert node._propose_ctx == {}
+    spans = {s["name"]: s for s in TRACE_BUFFER.snapshot(trace_id=trace_id)}
+    assert set(spans) == {"rpc.Add", "raft.propose", "raft.apply"}
+    assert spans["raft.propose"]["attrs"]["index"] == index
+    assert spans["raft.apply"]["parent_id"] == spans["raft.propose"]["span_id"]
+    assert spans["raft.apply"]["thread"] == thread_id
+    assert spans["raft.propose"]["thread"] == proposer
+    # propose waits for the apply: the child's interval is inside it
+    p, a = spans["raft.propose"], spans["raft.apply"]
+    assert p["start_us"] <= a["start_us"]
+    assert a["start_us"] + a["dur_us"] <= p["start_us"] + p["dur_us"]
+
+
+def test_capture_writes_spans_and_both_clock_pairs(sampled, tmp_path):
+    """trace/profile.capture on the CPU backend: the profiler's trace,
+    the interval's spans beside it, a clock pair at each end; spans are
+    mirrored into the profile only while it is live."""
+    from dingo_tpu.trace import profile
+
+    out = {}
+    t = threading.Thread(
+        target=lambda: out.update(profile.capture(str(tmp_path), 0.6)))
+    assert TRACER.annotate is None
+    t.start()
+    deadline = time.monotonic() + 30.0
+    while TRACER.annotate is None and t.is_alive() \
+            and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert TRACER.annotate is not None
+    with TRACER.start_span("rpc.Profiled"):
+        with TRACER.start_child("index.dispatch"):
+            time.sleep(0.01)
+    t.join(timeout=60.0)
+    assert not t.is_alive()
+    assert TRACER.annotate is None and TRACER.buffer is TRACE_BUFFER
+    assert out["xplane"].endswith(".xplane.pb")
+    assert out["spans_file"].startswith(out["xplane"].rsplit("/", 1)[0])
+    with open(out["spans_file"]) as f:
+        saved = json.load(f)
+    names = [r["name"] for r in saved["spans"]]
+    assert "rpc.Profiled" in names and "index.dispatch" in names
+    clock = saved["clock"]
+    for end in ("start", "stop"):
+        mono, wall = clock[end]
+        assert mono > 0 and wall > 1_600_000_000 * 10**9
+    assert clock["stop"][0] - clock["start"][0] >= 0.6e9
+    # every kept span lies on the same monotonic clock, inside the pairs
+    for r in saved["spans"]:
+        assert clock["start"][0] // 1000 <= r["start_us"] \
+            <= clock["stop"][0] // 1000
+    # the ring got them too (the tee forwards)
+    assert any(r["name"] == "rpc.Profiled" for r in TRACE_BUFFER.snapshot())
+    # one capture at a time, and bad intervals are refused
+    with pytest.raises(ValueError):
+        profile.capture(str(tmp_path), 0)
+
+
+def test_device_profile_rpc_needs_a_role_that_holds_the_device(tmp_path):
+    from dingo_tpu.server import pb
+    from dingo_tpu.server.services import DebugService
+
+    no = DebugService().DeviceProfile(pb.MetricsDumpRequest())
+    assert no.error.errcode == 50004 and "no device" in no.error.errmsg
+    ok = DebugService(device=True).DeviceProfile(pb.MetricsDumpRequest(
+        format=json.dumps({"seconds": 0.2, "dir": str(tmp_path)})))
+    assert ok.error.errcode == 0, ok.error.errmsg
+    reply = json.loads(ok.json)
+    assert reply["xplane"].endswith(".xplane.pb")
+    assert set(reply["clock"]) == {"start", "stop"}
+    bad = DebugService(device=True).DeviceProfile(
+        pb.MetricsDumpRequest(format=json.dumps({"seconds": -1})))
+    assert bad.error.errcode == 50004
+
+
 # ---------------- overhead contract ----------------
 
 @pytest.mark.slow

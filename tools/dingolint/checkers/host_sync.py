@@ -24,12 +24,15 @@ Sanctioned sync points are excluded by construction, not baselined:
 
 - nested defs named ``resolve`` (the contract's sync point) and
   anything only they call;
-- syncs lexically under an ``if ... sampled ...`` guard, and the
-  ``device_wait_span`` helper itself (trace-sampled kernel timing: the
-  head-sampling rate, not the workload, bounds how often it fires);
 - the obs plane (``dingo_tpu/obs``) — its lanes are async/head-sampled
   by their own tested discipline (quality scoring, integrity scrub);
 - ``copy_to_host_async`` is the opposite of a sync and never flagged.
+
+A sampled-trace guard is NOT a sanction: a traced request that blocks
+until ready (it once did, under the store's device lock on the IVF
+path) measures another regime than the one it samples. Device waits are
+timed by ``ops/distance.device_wait_begin``, whose span ends at
+resolve()'s one fetch and never synchronises.
 
 What's left is either a genuine stall (fix it) or a deliberate
 synchronous design (the mesh tier's collective merge) that belongs in
@@ -66,22 +69,11 @@ _BUILD_MODULE_PREFIXES = ("dingo_tpu.ops.graph_build",)
 #: traversal never descends into these (their own discipline applies)
 _SKIP_MODULE_PREFIXES = ("dingo_tpu.obs.", "dingo_tpu.trace.",
                          "dingo_tpu.metrics.")
-_SKIP_BASENAMES = {"resolve", "device_wait_span"}
+_SKIP_BASENAMES = {"resolve"}
 
 #: taint producers: a local assigned from one of these roots holds a
 #: device value; float()/np.asarray() on it is a hidden sync
 _DEVICE_ROOTS = {"jnp", "jax"}
-
-
-def _under_sampled_guard(module: Module, node: ast.AST) -> bool:
-    cur = module.parent(node)
-    while cur is not None:
-        if isinstance(cur, ast.If):
-            test_src = ast.unparse(cur.test)
-            if "sampled" in test_src or "sampling" in test_src:
-                return True
-        cur = module.parent(cur)
-    return False
 
 
 def _tainted_names(module: Module, fn: ast.AST, qual: str) -> Set[str]:
@@ -112,7 +104,7 @@ def _tainted_names(module: Module, fn: ast.AST, qual: str) -> Set[str]:
 class HostSyncChecker(Checker):
     name = "host-sync"
     description = ("no device->host sync on the search dispatch path "
-                   "outside resolve()/sampled-trace guards")
+                   "outside resolve()")
 
     def _hot_set(self, repo: Repo) -> Set[str]:
         cg = repo.callgraph()
@@ -148,8 +140,6 @@ class HostSyncChecker(Checker):
                 msg = self._sync_kind(node, tainted)
                 if msg is None:
                     continue
-                if _under_sampled_guard(module, node):
-                    continue
                 f = module.finding(self.name, node, msg)
                 if f:
                     out.append(f)
@@ -169,8 +159,8 @@ class HostSyncChecker(Checker):
                         "a sampled-trace guard")
             if tail == "block_until_ready":
                 return ("block_until_ready on the search dispatch path — "
-                        "use device_wait_span (sampled-only timing) or "
-                        "move the wait into resolve()")
+                        "time the wait with device_wait_begin (its span "
+                        "ends at resolve()'s fetch) and never block here")
             if tail == "asarray" and parts[0] in ("np", "numpy") \
                     and node.args:
                 arg = node.args[0]

@@ -37,7 +37,7 @@ from tools.dingolint.core import Checker, Finding, Module, Repo
 #: the registration methods on MetricsRegistry
 _METHODS = {"counter", "gauge", "latency"}
 #: span-minting methods on Tracer (names bridge to `span.<name>` series)
-_SPAN_METHODS = {"start_span"}
+_SPAN_METHODS = {"start_span", "start_child", "start_background"}
 
 #: full-name rule (common/metrics.py METRIC_NAME_RE)
 NAME_RE = re.compile(r"^[a-z][a-z0-9_.]*$")
@@ -117,6 +117,20 @@ FAMILY_NAMES = {
                                     # (candidate, dim-block) work skipped
         "ivf.pruned_candidates",    # candidates dropped before their
                                     # last dimension block
+        "ivf.probed_rows_per_query",  # rows in the probed buckets, mean
+                                    # per query of a sampled batch (gauge)
+    },
+    "background": {
+        "background.busy_ms",       # ms inside background spans (crontab
+                                    # jobs, checkpoints, saves, rebuilds,
+                                    # full GCs, compiles), by {job}
+    },
+    "gc": {
+        "gc.pause_ms",              # ms inside full collections, by {gen}
+    },
+    "trace": {
+        "trace.spans_recorded",     # spans handed to the trace buffer
+        "trace.spans_dropped",      # ring overwrites (oldest span lost)
     },
     "qos": {
         # serving-pressure plane (obs/pressure.py + common/coalescer.py):

@@ -22,8 +22,8 @@ Two rules:
      calls that diverge at the same ``if`` into different arms are
      branch-exclusive — only one runs per reply — and stay clean
      (the quantized families' rerank/no-rerank arms).
-   - reachable helpers (minus the obs/trace/metrics planes and
-     ``device_wait_span``) are flagged on ANY explicit sync: resolve
+   - reachable helpers (minus the obs/trace/metrics planes) are
+     flagged on ANY explicit sync: resolve
      already fetched, so a helper sync is by construction a second one.
 
 2. **the coalescer flush thread**: methods of ``SearchCoalescer``
@@ -58,7 +58,6 @@ _ADMISSION_MODULE_PREFIXES = ("dingo_tpu.cache.",)
 #: traversal never descends into these (their own discipline applies)
 _SKIP_MODULE_PREFIXES = ("dingo_tpu.obs.", "dingo_tpu.trace.",
                          "dingo_tpu.metrics.")
-_SKIP_BASENAMES = {"device_wait_span"}
 
 #: the flush-thread class; the completion lane's handoff class is
 #: intentionally NOT here — its resolve() runs on the lane thread
@@ -133,9 +132,6 @@ class ResolveSyncChecker(Checker):
         ]
 
         def skip(qual: str) -> bool:
-            base = qual.rsplit(".", 1)[-1]
-            if base in _SKIP_BASENAMES:
-                return True
             return qual.startswith(_SKIP_MODULE_PREFIXES)
 
         hot = cg.reachable(roots, fuzzy=True, skip=skip)
