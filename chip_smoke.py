@@ -379,8 +379,20 @@ def served_phase(args, out_dir: str, child_env: dict, summary: dict):
     summary["serving_kernels"] = served
     say(f"kernels that served the searches (sentinel name: calls): "
         f"{served}")
-    if arms.get("use_pallas_ivf_search") and args.chips == 1 and not any(
-            k.startswith("ops.pallas.ivf_") for k in served):
+    # the scan kernels are traced INSIDE the request's one program
+    # (index.ivf.search), so the sentinel names that program; which loop
+    # order scanned is the store's ivf.scan_arm counter
+    scan_arms = {}
+    for labels, v in _series(after, "ivf.scan_arm"):
+        was = sum(w for lb, w in _series(before, "ivf.scan_arm")
+                  if lb == labels)
+        if v - was > 0:
+            scan_arms[labels["arm"]] = int(v - was)
+    summary["scan_arms"] = scan_arms
+    say(f"IVF scan arms that served the searches (arm: searches): "
+        f"{scan_arms}")
+    if arms.get("use_pallas_ivf_search") and args.chips == 1 and not (
+            scan_arms.get("batch") or scan_arms.get("query")):
         raise SmokeFailure(
             "use_pallas_ivf_search resolved on, yet no Pallas IVF kernel "
             "served a search")
@@ -488,16 +500,17 @@ def kernel_phase_child() -> int:
     q = x[rng.choice(len(x), BATCH, replace=False)] + 0.05 * (
         rng.standard_normal((BATCH, DIM), dtype=np.float32))
 
-    def search_with(flags, idx, refresh=None, **kw):
-        """One search with tri-state `flags` forced (then back to 'auto');
-        `refresh` runs under the flags first. -> (ids, distances, secs)"""
+    def search_with(flags, idx, refresh=None, nq=BATCH, **kw):
+        """One search of the first `nq` queries with tri-state `flags`
+        forced (then back to 'auto'); `refresh` runs under the flags
+        first. -> (ids, distances, secs)"""
         for f, v in flags.items():
             FLAGS.set(f, v)
         try:
             if refresh:
                 refresh()
             t0 = time.monotonic()
-            res = idx.search(q, TOPK, **kw)
+            res = idx.search(q[:nq], TOPK, **kw)
             secs = time.monotonic() - t0
         finally:
             for f in flags:
@@ -565,17 +578,27 @@ def kernel_phase_child() -> int:
         ivf.upsert(np.arange(n, dtype=np.int64), x[:n])
         ivf.train()
         ref = search_with({ivf_on: False}, ivf, nprobe=nlist)
-        # compact() rebuilds the bucket view, which is where the pruning
-        # metadata is built or dropped for the flags then in force
-        compare(f"ivf_pruned_topk[{precision}]",
-                "ops.pallas.ivf_pruned_topk", ivf,
+        # the scan's loop order comes from the batch (ops/pallas_ivf
+        # scan_arm): all BATCH queries take the batch-major kernel, four
+        # take the query-major ones. The kernels are traced inside the
+        # request's program, so the sentinel counts them when a compare's
+        # shapes are new, as every one's here are. compact() rebuilds the
+        # bucket view, which is where the pruning metadata is built or
+        # dropped for the flags then in force
+        compare(f"ivf_batch_topk[{precision}]",
+                "ops.pallas.ivf_batch_topk", ivf,
                 {ivf_on: True, "ivf_prune_scan": True}, ref, tol=tol,
                 refresh=ivf.compact, nprobe=nlist)
+        ref4 = [r[:4] for r in ref[:2]]
+        compare(f"ivf_pruned_topk[{precision}]",
+                "ops.pallas.ivf_pruned_topk", ivf,
+                {ivf_on: True, "ivf_prune_scan": True}, ref4, tol=tol,
+                nq=4, nprobe=nlist)
         out["bucket_shape"] = [int(v) for v in ivf._buckets.shape]
         if precision == "fp32":
             compare("ivf_list_topk", "ops.pallas.ivf_list_topk", ivf,
-                    {ivf_on: True, "ivf_prune_scan": False}, ref,
-                    refresh=ivf.compact, nprobe=nlist)
+                    {ivf_on: True, "ivf_prune_scan": False}, ref4,
+                    refresh=ivf.compact, nq=4, nprobe=nlist)
         del ivf
 
     # no exact rerank behind the ADC scan: compare the kernel's own output

@@ -131,8 +131,8 @@ def ivf_scan_scores(
                  accumulation; bucket_sqnorm then caches DECODED norms
     Returns raw SCORES (descending-better) + slots — shard_map-safe (no
     jit, no distance conversion) so the mesh-sharded IVF can merge scores
-    across shards before converting; `_ivf_scan_kernel` is the single-
-    device jitted wrapper.
+    across shards before converting; `ivf_search_program` is the single-
+    device jitted program around it.
     """
     b = queries.shape[0]
     nprobe = probes.shape[1]
@@ -189,28 +189,62 @@ def ivf_scan_scores(
     return vals, slots
 
 
-@sentinel_jit("index.ivf.scan", static_argnames=("k", "metric"))
-def _ivf_scan_kernel(
-    buckets, bucket_sqnorm, bucket_valid, bucket_slot, probes, queries, k, metric
+@sentinel_jit("index.ivf.search",
+              static_argnames=("k", "nprobe", "metric", "pallas", "interpret",
+                               "check_every", "inbucket"))
+def ivf_search_program(
+    qpad,            # [b, d] f32 padded queries
+    centroids,       # [nlist, d]
+    c_sqnorm,        # [nlist]
+    probe_table,     # [nlist, max_spill] int32 bucket ids per list (-1 pad)
+    valid,           # [B, cap] bool: resident bucket_valid, or a filter's
+    bucket_slot,     # [B, cap] int32
+    buckets,         # [B, cap, d] rows or sq8 codes
+    bucket_sqnorm,   # [B, cap] f32
+    bucket_bsq,      # [B, nblk, cap] f32 pruning norms, or None
+    sq_vmin,         # [d] sq8 codec params, or None
+    sq_scale,
+    k: int,
+    nprobe: int,
+    metric: Metric,
+    pallas: bool,
+    interpret: bool = False,
+    check_every: int = 1,
+    inbucket: bool = True,
 ):
-    vals, slots = ivf_scan_scores(
-        buckets, bucket_sqnorm, bucket_valid, bucket_slot, probes, queries,
-        k, metric,
-    )
-    return scores_to_distances(vals, metric), slots
+    """A request's whole device side as ONE program: coarse probe
+    selection, probe expansion through the view's probe table, the scan
+    and the wire-convention distances -> (distances[b, k], slots[b, k],
+    probes[b, nprobe], vprobes[b, budget], aux).
 
+    The host launches nothing else between the H2D of the queries and the
+    reply's one fetch: the op-by-op `jnp` glue that used to stand here
+    cost a b = 64 request more host time, under store.device_lock, than
+    its kernels cost the device (PERF.md, PR 28). Statics are the request
+    shape (k, nprobe), the metric and the scan family; `max_spill` is the
+    probe table's width. `pallas` takes ops/pallas_ivf.ivf_probe_scan,
+    which picks its loop order from the batch (aux: touched-bucket count,
+    pruning stats or None); otherwise the XLA rank scan (aux None)."""
+    from dingo_tpu.ops.distance import metric_ascending
 
-@sentinel_jit("index.ivf.scan_sq", static_argnames=("k", "metric"))
-def _ivf_scan_kernel_sq(
-    buckets, bucket_sqnorm, bucket_valid, bucket_slot, sq_vmin, sq_scale,
-    probes, queries, k, metric
-):
-    """SQ8 variant: buckets hold uint8 codes, decoded on the fly."""
-    vals, slots = ivf_scan_scores(
-        buckets, bucket_sqnorm, bucket_valid, bucket_slot, probes, queries,
-        k, metric, sq_vmin=sq_vmin, sq_scale=sq_scale,
-    )
-    return scores_to_distances(vals, metric), slots
+    probes = coarse_probes(qpad, centroids, c_sqnorm, nprobe)
+    vprobes = expand_probes(probes, probe_table, nprobe, probe_table.shape[1])
+    if pallas:
+        from dingo_tpu.ops.pallas_ivf import ivf_probe_scan
+
+        vals, slots, aux = ivf_probe_scan(
+            vprobes, qpad, buckets, bucket_bsq, bucket_sqnorm, valid,
+            bucket_slot, sq_vmin, sq_scale, k=k,
+            ascending=metric_ascending(metric), interpret=interpret,
+            check_every=check_every, inbucket=inbucket,
+        )
+    else:
+        vals, slots = ivf_scan_scores(
+            buckets, bucket_sqnorm, valid, bucket_slot, vprobes, qpad, k,
+            metric, sq_vmin=sq_vmin, sq_scale=sq_scale,
+        )
+        aux = None
+    return scores_to_distances(vals, metric), slots, probes, vprobes, aux
 
 
 @sentinel_jit("index.ivf.filter_mask")
@@ -830,13 +864,17 @@ class TpuIvfFlat(IvfViewMaintenance, _SlotStoreIndex):
     ):
         if not self.is_trained():
             raise NotTrained("IVF_FLAT not trained")  # reader falls back
-        from dingo_tpu.common.config import pallas_ivf_enabled
+        from dingo_tpu.common.config import (
+            pallas_interpret,
+            pallas_ivf_enabled,
+        )
         from dingo_tpu.obs.heat import HEAT, heat_enabled
-        from dingo_tpu.ops.distance import device_wait_begin, metric_ascending
+        from dingo_tpu.ops.distance import device_wait_begin
+        from dingo_tpu.ops.pallas_ivf import scan_arm
 
         store = self.store
-        # index.dispatch: entry to kernels enqueued (prep, pad and H2D,
-        # probe selection and expansion, enqueue); NOOP when unsampled
+        # index.dispatch: entry to the program enqueued (prep, pad and
+        # H2D, the view snapshot, one launch); NOOP when unsampled
         with TRACER.start_child("index.dispatch") as dspan:
             queries = self._prep_queries(queries)
             self._ensure_view()
@@ -859,108 +897,66 @@ class TpuIvfFlat(IvfViewMaintenance, _SlotStoreIndex):
             qpad = staged.take(queries) if staged is not None else None
             if qpad is None:
                 qpad = jnp.asarray(_pad_batch(queries))
+            # what the program is specialised on, read once per request
+            # and outside the lock. The Pallas kernels keep top-k in a
+            # 128-lane output block; larger k (and its unrolled select
+            # rounds), the binary family's int8 rows and sq8 without the
+            # blocked norms stay on the XLA rank scan
+            sq = self._precision == "sq8"
+            pallas = (
+                pallas_ivf_enabled(self.dimension)
+                and self.metric in (
+                    Metric.L2, Metric.INNER_PRODUCT, Metric.COSINE
+                )
+                and k_eff <= 64
+                and (sq or store.vecs.dtype in (jnp.float32, jnp.bfloat16))
+            )
+            interpret = pallas_interpret() if pallas else False
+            check = max(1, int(FLAGS.get("ivf_prune_check_interval")))
+            inbucket = bool(FLAGS.get("ivf_prune_inbucket_bound"))
+            fprep = self._prep_filter_mask(filter_spec)
             # lease BEFORE dispatch: kernel slots must stay limbo-parked
             # until resolve translates them (delete+reinsert would
             # misattribute)
             lease = store.begin_search()
             try:
-                probes = _probe_lists(
-                    qpad, self.centroids, self._c_sqnorm, nprobe)
-                fprep = self._prep_filter_mask(filter_spec)
-                # view snapshot + dispatch under the device lock: the
+                # view snapshot + ONE launch under the device lock: the
                 # incremental write path DONATES bucket arrays to its
                 # scatter programs, so a concurrent write must not
-                # invalidate a captured reference between here and
-                # dispatch (same contract as slot_store.put); reading
+                # invalidate a captured reference between here and the
+                # enqueue (same contract as slot_store.put); reading
                 # self._view inside the same hold keeps view metadata and
-                # self._buckets consistent
-                stats = None
-                # asking for the lock to holding it (timed when sampled)
+                # self._buckets consistent. Nothing eager runs in the hold.
+                # index.lock_wait: asking for the lock to holding it
                 with TRACER.start_child("index.lock_wait"):
                     store.device_lock.acquire()
                 try:
                     view = self._view
-                    vprobes = expand_probes(
-                        probes, view.probe_table, nprobe, view.max_spill
+                    bsq = self._bucket_bsq
+                    if sq and bsq is None:
+                        pallas = False
+                    # loop order of the Pallas scan, as the program will
+                    # pick it from the same shapes (ops/pallas_ivf)
+                    arm = scan_arm(
+                        qpad.shape[0], view.cap_list, self.dimension,
+                        self._buckets.dtype.itemsize,
+                    ) if pallas else "xla"
+                    dists, slots, probes, vprobes, aux = ivf_search_program(
+                        qpad, self.centroids, self._c_sqnorm,
+                        view.probe_table,
+                        self._bucket_valid_for_filter(filter_spec, fprep),
+                        view.bucket_slot, self._buckets,
+                        self._bucket_sqnorm, bsq if pallas else None,
+                        store.sq_vmin_d if sq else None,
+                        store.sq_scale_d if sq else None,
+                        k=k_eff, nprobe=nprobe, metric=self._scan_metric,
+                        pallas=pallas, interpret=interpret,
+                        check_every=check, inbucket=inbucket,
                     )
-                    valid = self._bucket_valid_for_filter(filter_spec, fprep)
-                    # kernel keeps top-k in a 128-lane output block;
-                    # larger k (and its unrolled select rounds) stays on
-                    # XLA
-                    pallas_ok = (
-                        pallas_ivf_enabled(self.dimension)
-                        and self.metric in (
-                            Metric.L2, Metric.INNER_PRODUCT, Metric.COSINE
-                        )
-                        and k_eff <= 64
-                    )
-                    float_store = store.vecs.dtype in (
-                        jnp.float32, jnp.bfloat16
-                    )
-                    if pallas_ok and self._bucket_bsq is not None and (
-                        float_store or self._precision == "sq8"
-                    ):
-                        # dimension-blocked early-pruning scan: partial
-                        # distances per block, candidates that cannot beat
-                        # the running k-th best stop scanning
-                        from dingo_tpu.ops.pallas_ivf import (
-                            ivf_pruned_search,
-                        )
-
-                        stage = "pruned_scan"
-                        sq = self._precision == "sq8"
-                        dblk = self.dimension // self._bucket_bsq.shape[1]
-                        vals, slots, stats = ivf_pruned_search(
-                            vprobes, qpad, self._buckets, self._bucket_bsq,
-                            self._bucket_sqnorm, valid, view.bucket_slot,
-                            k=k_eff, dim_block=dblk,
-                            ascending=metric_ascending(self._scan_metric),
-                            sq_vmin=store.sq_vmin_d if sq else None,
-                            sq_scale=store.sq_scale_d if sq else None,
-                        )
-                        dists = scores_to_distances(vals, self._scan_metric)
-                    elif pallas_ok and float_store:
-                        from dingo_tpu.ops.pallas_ivf import ivf_list_search
-
-                        stage = "pallas_ivf_search"
-                        vals, slots = ivf_list_search(
-                            vprobes, qpad, self._buckets,
-                            self._bucket_sqnorm, valid, view.bucket_slot,
-                            k=k_eff,
-                            ascending=metric_ascending(self._scan_metric),
-                        )
-                        dists = scores_to_distances(vals, self._scan_metric)
-                    elif self._precision == "sq8":
-                        stage = "ivf_scan"
-                        dists, slots = _ivf_scan_kernel_sq(
-                            self._buckets,
-                            self._bucket_sqnorm,
-                            valid,
-                            view.bucket_slot,
-                            store.sq_vmin_d,
-                            store.sq_scale_d,
-                            vprobes,
-                            qpad,
-                            k=k_eff,
-                            metric=self._scan_metric,
-                        )
-                    else:
-                        stage = "ivf_scan"
-                        dists, slots = _ivf_scan_kernel(
-                            self._buckets,
-                            self._bucket_sqnorm,
-                            valid,
-                            view.bucket_slot,
-                            vprobes,
-                            qpad,
-                            k=k_eff,
-                            metric=self._scan_metric,
-                        )
                     if kprime is not None:
                         # exact rerank of the quantized shortlist against
                         # the device row cache, dispatched under the same
                         # lock (cache arrays share it); still fully async
-                        stage = "rerank"
                         dists, slots = self._dispatch_rerank(
                             qpad, dists, slots, topk
                         )
@@ -969,9 +965,17 @@ class TpuIvfFlat(IvfViewMaintenance, _SlotStoreIndex):
             except Exception:
                 lease.release()
                 raise
-            # one-sync epilogue: the whole reply (prune stats included)
-            # joins a single D2H copy group; resolve device_gets it
-            # exactly once. The heat plane's probed-list ids and, for a
+            METRICS.counter("ivf.scan_arm", region_id=self.id,
+                            labels={"arm": arm}).add(1)
+            # the ops.<stage> span's name: what the request waits for
+            stage = "rerank" if kprime is not None else {
+                "batch": "batch_scan", "xla": "ivf_scan",
+                "query": "pruned_scan" if bsq is not None
+                else "pallas_ivf_search",
+            }[arm]
+            # one-sync epilogue: the whole reply (the scan's aux block
+            # included) joins a single D2H copy group; resolve device_gets
+            # it exactly once. The heat plane's probed-list ids and, for a
             # sampled request, the probed bucket ids ride the SAME group:
             # the access sketch and ivf.probed_rows_per_query cost zero
             # extra syncs (resolve-sync contract)
@@ -979,9 +983,9 @@ class TpuIvfFlat(IvfViewMaintenance, _SlotStoreIndex):
             if heat_on:
                 HEAT.register_layout(self.id, "ivf", self._heat_layout)
             probed = vprobes if dspan.sampled else None
-            fetch = begin_host_fetch(dists, slots, stats,
+            fetch = begin_host_fetch(dists, slots, aux,
                                      probes if heat_on else None, probed)
-        # the device wait of a sampled request: from here (kernels
+        # the device wait of a sampled request: from here (program
         # enqueued, lock released) to the fetch's return in resolve();
         # never a sync of its own (ops/distance.device_wait_begin)
         wait = device_wait_begin(stage)
@@ -993,10 +997,10 @@ class TpuIvfFlat(IvfViewMaintenance, _SlotStoreIndex):
                 # index.resolve: the host work after the fetch
                 with TRACER.start_child("index.resolve"):
                     dists_h, slots_h = fetched[0], fetched[1]
-                    if stats is not None:
-                        # pruned-fraction observability rides the result
-                        # fetch — no extra sync on the dispatch path
-                        self._note_prune_stats(fetched[2][:b])
+                    if aux is not None:
+                        # scan observability rides the result fetch — no
+                        # extra sync on the dispatch path
+                        self._note_scan_aux(arm, fetched[2], b)
                     if probed is not None:
                         # joined LAST: [-1] whatever else is in the group
                         self._note_probed_rows(view, fetched[-1][:b])
@@ -1024,6 +1028,19 @@ class TpuIvfFlat(IvfViewMaintenance, _SlotStoreIndex):
                 lease.release()
 
         return resolve
+
+    def _note_scan_aux(self, arm: str, aux_h, b: int) -> None:
+        """Fold the scan's aux block, fetched with the reply: the
+        query-major pruned arm's stats, or the batch-major arm's count of
+        touched buckets (each read once: buckets x cap x d x itemsize
+        bytes of HBM). A batch-major scan skipped nothing, and says so:
+        the last pruned request's fraction would be a false reading."""
+        if arm != "batch":
+            self._note_prune_stats(aux_h[:b])
+            return
+        METRICS.gauge("ivf.batch_scan_buckets", region_id=self.id).set(
+            float(aux_h))
+        METRICS.gauge("ivf.pruned_dim_fraction", region_id=self.id).set(0.0)
 
     def _note_probed_rows(self, view, vprobes_h) -> None:
         """Rows in the buckets a sampled batch probed, per query (mean),

@@ -4,7 +4,7 @@ PDX (PAPERS.md) stores vectors *vertically* — all candidates' values for
 one block of dimensions contiguously — so a scan can accumulate partial
 distances one dimension-block at a time and drop candidates whose partial
 distance already cannot beat the running k-th best. The TPU translation
-(ops/pallas_ivf.ivf_pruned_search / ops/pallas_topk.pruned_fused_search):
+(ops/pallas_ivf.ivf_probe_scan / ops/pallas_topk.pruned_fused_search):
 
   * data      [n_blocks, n, block_d]  (FLAT store mirror; the IVF bucket
               arrays stay [B, cap, d] — a BlockSpec (1, cap, block_d) tile

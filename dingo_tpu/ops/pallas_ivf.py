@@ -1,10 +1,10 @@
-"""Pallas IVF list-DMA kernel: stream ONLY probed buckets through VMEM.
+"""Pallas IVF list-DMA kernels: stream ONLY probed buckets through VMEM.
 
-The XLA IVF path (`ivf_flat._ivf_scan_kernel`) gathers each probed bucket
+The XLA IVF path (`ivf_flat.ivf_scan_scores`) gathers each probed bucket
 into a fresh [b, cap_list, d] HBM array per probe rank and then reads it
 again for the distance einsum — 3x the necessary HBM traffic, plus it
-cannot skip padded ranks. This kernel uses scalar-prefetched probe ids as
-the BlockSpec index_map, so the Pallas pipeline DMAs exactly one probed
+cannot skip padded ranks. These kernels use scalar-prefetched bucket ids
+as the BlockSpec index_map, so the Pallas pipeline DMAs exactly one probed
 bucket [cap_list, d] from HBM to VMEM per grid step (double-buffered), and
 the distance + running top-k merge happen in VMEM with nothing written
 back but the final [b, k].
@@ -12,8 +12,24 @@ back but the final [b, k].
 Replaces the hot loop the reference runs through faiss's IVF scanners over
 src/simd/hook.cc kernels (vector_index_ivf_flat.cc search path).
 
-Grid: (b, budget) — query-major, so the output block for query q stays
-resident in VMEM across its inner rank loop (accumulate-in-output pattern).
+Two loop orders over the same (query, probed bucket) pairs, same answers:
+
+  query-major  `ivf_list_topk` / `ivf_pruned_topk`: grid (b, budget[, nblk]),
+               the output block of query q resident across its own probes.
+               One step multiplies ONE query row with a bucket tile, so the
+               dimension-blocked variant can stop a candidate early (PDX);
+               a bucket is read once per query that probes it.
+  batch-major  `ivf_batch_topk`: grid (touched buckets,), the whole [b, k]
+               top-k resident. One step reads a bucket ONCE and multiplies
+               it with all b queries on the MXU; pairs whose query did not
+               probe the bucket are masked. No pruning.
+
+`ivf_probe_scan` picks one from the request's own shape at trace time
+(`scan_arm`): with fewer than ROW_BLOCK queries a bucket is hardly ever
+shared and an M = 1 step that prunes wins; from ROW_BLOCK queries on, the
+query-major grid pays b x budget x nblk steps of ~1 us each for mat-vecs
+(19 ms at b = 64 on a v5e) where the batch-major grid pays one ~2-us step
+a touched bucket (PERF.md, PR 28).
 """
 
 from __future__ import annotations
@@ -179,25 +195,6 @@ def ivf_list_topk(
     return out_v[:, :k], out_i[:, :k]
 
 
-def ivf_list_search(
-    vprobes, queries, buckets, bucket_sqnorm, bucket_valid, bucket_slot,
-    k: int, ascending: bool = True,
-):
-    """Backend-aware wrapper: compiled on the TPU, interpreted on the CPU
-    (config.pallas_interpret); pads the ARRAYS to ROW_BLOCK rows but
-    clamps the grid to the real batch, so a b<8 request doesn't run (or
-    DMA for) dead grid steps."""
-    from dingo_tpu.common.config import pallas_interpret
-
-    b = queries.shape[0]
-    queries, vprobes = _pad_rows(queries, vprobes)
-    vals, slots = ivf_list_topk(
-        vprobes, queries, buckets, bucket_sqnorm, bucket_valid, bucket_slot,
-        k=k, ascending=ascending, interpret=pallas_interpret(), nq=b,
-    )
-    return vals[:b], slots[:b]
-
-
 def _pad_rows(queries, vprobes):
     """Pad the per-query arrays to the ROW_BLOCK sublane multiple (padded
     queries probe nothing: vprobes -1)."""
@@ -210,6 +207,200 @@ def _pad_rows(queries, vprobes):
             [vprobes, jnp.full((pad, vprobes.shape[1]), -1, vprobes.dtype)]
         )
     return queries, vprobes
+
+
+def touched_buckets(vprobes: jax.Array, nbuckets: int):
+    """The batch's probe SCHEDULE: every bucket some query probes, once.
+
+    vprobes [b, budget] (-1 pad) -> (sched [S] int32, count int32) with
+    S = min(nbuckets, b * budget) static; sched[:count] holds the touched
+    bucket ids in ascending order, entries past count repeat the last id
+    (the grid's block index then does not change, so the pipeline fetches
+    nothing for a padded step)."""
+    b, budget = vprobes.shape
+    steps = min(nbuckets, b * budget)
+    flat = vprobes.reshape(-1)
+    hit = jnp.zeros((nbuckets + 1,), jnp.bool_).at[
+        jnp.where(flat >= 0, flat, nbuckets)
+    ].set(True)[:nbuckets]
+    count = jnp.sum(hit, dtype=jnp.int32)
+    ids = jnp.nonzero(hit, size=steps, fill_value=0)[0].astype(jnp.int32)
+    last = ids[jnp.maximum(count - 1, 0)]
+    sched = jnp.where(jnp.arange(steps, dtype=jnp.int32) < count, ids, last)
+    return sched, count
+
+
+def _ivf_batch_kernel(sched_ref, cnt_ref, vp_ref, q_ref, qsq_ref, x_ref,
+                      xsq_ref, val_ref, slot_ref, *rest, k, ascending, sq):
+    """Batch-major list scan: grid step s streams bucket sched[s] through
+    VMEM ONCE and scores the whole batch against it with one MXU matmul
+    [b, d] x [d, cap]. A (query, candidate) pair counts only if the
+    query's own probes hold this bucket (vector compare of the resident
+    [b, budget] probes with the step's bucket id) and the row is valid, so
+    each query sees exactly the rows the query-major kernels show it. The
+    [b, k] running top-k stays resident in the output block for the whole
+    grid. The k rounds of select run per ROW_BLOCK of queries and only
+    where some candidate beats a row's running k-th best: of ~1,100 steps
+    a batch of 64 takes, a query's shortlist changes in a handful."""
+    if sq:
+        vmin_ref, scale_ref, outv_ref, outi_ref, sc_ref = rest
+    else:
+        outv_ref, outi_ref, sc_ref = rest
+    s = pl.program_id(0)
+    b = q_ref.shape[0]
+    pad = outv_ref.shape[1] - k
+
+    @pl.when(s == 0)
+    def _init():
+        outv_ref[:] = jnp.full(outv_ref.shape, NEG_INF, jnp.float32)
+        outi_ref[:] = jnp.full(outi_ref.shape, -1, jnp.int32)
+
+    @pl.when(s < cnt_ref[0])
+    def _scan_bucket():
+        q = q_ref[:]                                     # [b, d]
+        x = x_ref[0]                                     # [cap, d]
+        if sq:
+            # the sq8 tier's compute contract (ops/sq.py), as the pruned
+            # kernel: decode in f32, multiply in bf16, accumulate in f32
+            x = (
+                x.astype(jnp.int32).astype(jnp.float32) * scale_ref[:]
+                + vmin_ref[:]
+            ).astype(jnp.bfloat16)
+            q = q.astype(jnp.bfloat16)
+        else:
+            x = x.astype(jnp.float32)
+        dots = jax.lax.dot_general(
+            q, x, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=(None if sq else jax.lax.Precision.HIGHEST),
+        )                                                # [b, cap]
+        if ascending:   # L2 score = -(||q||^2 - 2qx + ||x||^2)
+            scores = -(qsq_ref[:] - 2.0 * dots + xsq_ref[0])
+        else:           # IP
+            scores = dots
+        member = jnp.max(
+            jnp.where(vp_ref[:] == sched_ref[s], 1.0, 0.0),
+            axis=1, keepdims=True,
+        )                                                # [b, 1]
+        live = (member > 0.5) & (val_ref[0] > 0.5)       # [b, cap]
+        scores = jnp.where(live, scores, NEG_INF)
+        beats = jnp.where(scores > outv_ref[:, k - 1:k], 1.0, 0.0)
+
+        @pl.when(jnp.sum(beats) > 0.5)
+        def _merge():
+            sc_ref[:] = scores
+            slot = slot_ref[0].astype(jnp.int32)         # [1, cap]
+
+            def group(g, carry):
+                rows = pl.ds(pl.multiple_of(g * ROW_BLOCK, ROW_BLOCK),
+                             ROW_BLOCK)
+                sc = sc_ref[rows, :]                     # [8, cap]
+                cur_v = outv_ref[rows, :]
+                better = jnp.where(sc > cur_v[:, k - 1:k], 1.0, 0.0)
+
+                @pl.when(jnp.sum(better) > 0.5)
+                def _select():
+                    blk_v, blk_i = _select_topk(
+                        sc, jnp.broadcast_to(slot, sc.shape), k)
+                    cur_i = outi_ref[rows, :]
+                    cat_v = jnp.concatenate([cur_v[:, :k], blk_v], axis=1)
+                    cat_i = jnp.concatenate([cur_i[:, :k], blk_i], axis=1)
+                    new_v, new_i = _select_topk(cat_v, cat_i, k)
+                    outv_ref[rows, :] = jnp.concatenate(
+                        [new_v,
+                         jnp.full((ROW_BLOCK, pad), NEG_INF, jnp.float32)],
+                        axis=1,
+                    )
+                    outi_ref[rows, :] = jnp.concatenate(
+                        [new_i, jnp.full((ROW_BLOCK, pad), -1, jnp.int32)],
+                        axis=1,
+                    )
+
+                return carry
+
+            jax.lax.fori_loop(0, b // ROW_BLOCK, group, 0)
+
+    @pl.when(s == pl.num_programs(0) - 1)
+    def _finish():
+        # -inf picks carry arbitrary slots; normalize to -1 like the XLA path
+        outi_ref[:] = jnp.where(jnp.isneginf(outv_ref[:]), -1, outi_ref[:])
+
+
+@sentinel_jit("ops.pallas.ivf_batch_topk",
+              static_argnames=("k", "ascending", "interpret"))
+def ivf_batch_topk(
+    vprobes: jax.Array,        # [b, budget] int32 virtual bucket ids (-1 pad)
+    queries: jax.Array,        # [b, d] f32, b a multiple of ROW_BLOCK
+    buckets: jax.Array,        # [B, cap, d] rows (f32/bf16) or codes (uint8)
+    bucket_sqnorm: jax.Array,  # [B, cap] f32 (decoded) norms
+    bucket_valid: jax.Array,   # [B, cap] bool/float
+    bucket_slot: jax.Array,    # [B, cap] int32
+    sq_vmin,                   # [d] f32 codec params (None for float rows)
+    sq_scale,
+    k: int,
+    ascending: bool = True,
+    interpret: bool = False,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Batch-major probed-bucket scan -> (scores[b, k], slots[b, k],
+    touched-bucket count). Same answers as ivf_list_topk (no dimension
+    pruning: on the MXU a dead candidate's lanes cost nothing to carry),
+    but each bucket the batch probes is read from HBM once and multiplied
+    with all b queries, where the query-major grids read it once per
+    probing query for an M = 1 mat-vec."""
+    b, d = queries.shape
+    nb, cap, _ = buckets.shape
+    assert b % ROW_BLOCK == 0, f"batch {b} not a multiple of {ROW_BLOCK}"
+    sq = sq_vmin is not None
+    sched, count = touched_buckets(vprobes, nb)
+    q32 = queries.astype(jnp.float32)
+    qsq = jnp.einsum(
+        "bd,bd->b", q32, q32, precision=jax.lax.Precision.HIGHEST
+    )[:, None]
+    # membership compares ride full lane tiles (-1 never equals a bucket)
+    lanes = (-vprobes.shape[1]) % OUT_PAD
+    vp = jnp.pad(vprobes, ((0, 0), (0, lanes)), constant_values=-1)
+
+    def whole(s, sched, cnt):
+        return (0, 0)
+
+    def bucket_map(s, sched, cnt):
+        return (sched[s], 0, 0)
+
+    in_specs = [
+        pl.BlockSpec(vp.shape, whole),                        # probes
+        pl.BlockSpec((b, d), whole),                          # queries
+        pl.BlockSpec((b, 1), whole),                          # qsq
+        pl.BlockSpec((1, cap, d), bucket_map),                # bucket rows
+        pl.BlockSpec((1, 1, cap), bucket_map),                # sqnorm
+        pl.BlockSpec((1, 1, cap), bucket_map),                # valid
+        pl.BlockSpec((1, 1, cap), bucket_map),                # slots
+    ]
+    args = [
+        vp, q32, qsq, buckets,
+        bucket_sqnorm[:, None, :],
+        bucket_valid.astype(jnp.float32)[:, None, :],
+        bucket_slot[:, None, :],
+    ]
+    if sq:
+        in_specs += [pl.BlockSpec((1, d), whole)] * 2
+        args += [sq_vmin[None, :], sq_scale[None, :]]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(sched.shape[0],),
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec((b, OUT_PAD), whole)] * 2,
+        scratch_shapes=[pltpu.VMEM((b, cap), jnp.float32)],   # step scores
+    )
+    out_v, out_i = pl.pallas_call(
+        functools.partial(_ivf_batch_kernel, k=k, ascending=ascending, sq=sq),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, OUT_PAD), jnp.float32),
+            jax.ShapeDtypeStruct((b, OUT_PAD), jnp.int32),
+        ],
+        interpret=interpret,
+    )(sched, count[None], *args)
+    return out_v[:, :k], out_i[:, :k], count
 
 
 def _ivf_pruned_kernel(vp_ref, q_ref, qsq_ref, qpsq_ref, x_ref, bsq_ref,
@@ -495,27 +686,59 @@ def ivf_pruned_topk(
     return out_v[:, :k], out_i[:, :k], out_s[:, :4]
 
 
-def ivf_pruned_search(
+#: VMEM the batch-major kernel may plan for (v5e's scoped default is
+#: 16 MiB; Mosaic's own temporaries take the rest)
+BATCH_VMEM_BUDGET = 12 << 20
+
+
+def scan_arm(b: int, cap: int, d: int, itemsize: int) -> str:
+    """Loop order for a scan of b (padded) queries over [cap, d] buckets:
+    "batch" from ROW_BLOCK queries on, where its blocks fit VMEM (bucket
+    tile double-buffered and widened once if the rows are not f32, the
+    resident queries, the step's [b, cap] scores); else "query"."""
+    if b < ROW_BLOCK or b % ROW_BLOCK:
+        return "query"
+    tile = cap * d
+    need = (2 * tile * itemsize + (0 if itemsize == 4 else 2 * tile * 4)
+            + 2 * b * d * 4 + 4 * b * cap * 4 + 6 * b * OUT_PAD * 4)
+    return "batch" if need <= BATCH_VMEM_BUDGET else "query"
+
+
+def ivf_probe_scan(
     vprobes, queries, buckets, bucket_bsq, bucket_sqnorm, bucket_valid,
-    bucket_slot, k: int, dim_block: int, ascending: bool = True,
-    sq_vmin=None, sq_scale=None,
+    bucket_slot, sq_vmin, sq_scale, *, k: int, ascending: bool,
+    interpret: bool, check_every: int = 1, inbucket: bool = True,
 ):
-    """Backend-aware wrapper for the pruning scan: pads per-query arrays
-    to ROW_BLOCK, clamps the grid to the real batch, computes the query
-    prefix norms, and returns (scores[b,k], slots[b,k], stats[b,4])."""
-    from dingo_tpu.common.config import FLAGS, pallas_interpret
+    """The scan stage of one request, traced inside its program: virtual
+    probes + queries -> (scores[b, k], slots[b, k], aux). The loop order
+    comes from the shapes (`scan_arm`); aux is the touched-bucket count
+    (batch-major), the [b, 4] pruning stats (query-major with the blocked
+    norms `bucket_bsq`) or None (query-major without). The query-major
+    arrays are padded to ROW_BLOCK rows with the grid clamped to the real
+    batch, so a b < 8 request neither runs nor DMAs for dead steps."""
+    b, d = queries.shape
+    _, cap, _ = buckets.shape
+    if scan_arm(b, cap, d, buckets.dtype.itemsize) == "batch":
+        return ivf_batch_topk(
+            vprobes, queries, buckets, bucket_sqnorm, bucket_valid,
+            bucket_slot, sq_vmin, sq_scale,
+            k=k, ascending=ascending, interpret=interpret,
+        )
+    queries, vprobes = _pad_rows(queries, vprobes)
+    if bucket_bsq is None:
+        vals, slots = ivf_list_topk(
+            vprobes, queries, buckets, bucket_sqnorm, bucket_valid,
+            bucket_slot, k=k, ascending=ascending, interpret=interpret, nq=b,
+        )
+        return vals[:b], slots[:b], None
     from dingo_tpu.ops.blocked import query_prefix_sqnorms
 
-    b = queries.shape[0]
-    queries, vprobes = _pad_rows(queries, vprobes)
-    qpsq = query_prefix_sqnorms(queries, dim_block)
-    interpret = pallas_interpret()
-    check = max(1, int(FLAGS.get("ivf_prune_check_interval")))
+    dim_block = d // bucket_bsq.shape[1]
     vals, slots, stats = ivf_pruned_topk(
-        vprobes, queries, qpsq, buckets, bucket_bsq, bucket_sqnorm,
-        bucket_valid, bucket_slot, sq_vmin, sq_scale,
-        k=k, dim_block=dim_block, ascending=ascending, check_every=check,
-        interpret=interpret, nq=b, sq=sq_vmin is not None,
-        inbucket=bool(FLAGS.get("ivf_prune_inbucket_bound")),
+        vprobes, queries, query_prefix_sqnorms(queries, dim_block), buckets,
+        bucket_bsq, bucket_sqnorm, bucket_valid, bucket_slot, sq_vmin,
+        sq_scale, k=k, dim_block=dim_block, ascending=ascending,
+        check_every=check_every, interpret=interpret, nq=b,
+        sq=sq_vmin is not None, inbucket=inbucket,
     )
     return vals[:b], slots[:b], stats[:b]

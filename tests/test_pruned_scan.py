@@ -56,8 +56,11 @@ def test_ivf_pruned_parity_vs_xla(corpus, small_dim_block, precision,
                                   metric):
     """Exact tiers must return identical ids; sq8 recall@10 within 0.995
     relative of the XLA arm (blocked partial sums reorder bf16-multiply
-    rounding near ties)."""
+    rounding near ties). Four queries: under ROW_BLOCK the request takes
+    the query-major pruned kernel (tests/test_pallas_ivf.py holds the
+    batch-major arm to the same answers)."""
     x, ids, q = corpus
+    q = q[:4]
     idx = TpuIvfFlat(1, IndexParameter(
         index_type=IndexType.IVF_FLAT, dimension=D, ncentroids=NLIST,
         metric=metric, precision=precision,
@@ -82,10 +85,13 @@ def test_ivf_pruned_parity_vs_xla(corpus, small_dim_block, precision,
     assert 0.0 < frac < 1.0   # pruning demonstrably engaged
 
 
-def test_ivf_pruned_incremental_append_parity(corpus, small_dim_block):
+@pytest.mark.parametrize("nq", [4, 8])
+def test_ivf_pruned_incremental_append_parity(corpus, small_dim_block, nq):
     """In-place appends must keep the blocked norm metadata in sync (the
-    scatter arm, not just the dense materialize)."""
+    scatter arm, not just the dense materialize) — for the query-major
+    pruned kernel (4 queries) and the batch-major one (8)."""
     x, ids, q = corpus
+    q = q[:nq]
     idx = TpuIvfFlat(1, IndexParameter(
         index_type=IndexType.IVF_FLAT, dimension=D, ncentroids=NLIST,
     ))
@@ -132,22 +138,34 @@ def test_pruned_counters_and_span_names(corpus, small_dim_block):
     idx.upsert(ids, x)
     idx.train()
     c = METRICS.counter("ivf.pruned_candidates", region_id=7)
+    arms = {a: METRICS.counter("ivf.scan_arm", region_id=7,
+                               labels={"arm": a}) for a in ("query", "batch")}
+    frac = METRICS.gauge("ivf.pruned_dim_fraction", region_id=7)
     before = c.get()
     FLAGS.set("use_pallas_ivf_search", True)
     try:
+        idx.search(q[:4], K, nprobe=8)
+        assert c.get() > before
+        assert 0.0 < frac.get() < 1.0
+        assert (arms["query"].get(), arms["batch"].get()) == (1, 0)
+        # a batch-major scan prunes nothing and says so: the b = 4 value
+        # left standing would be a false reading
         idx.search(q, K, nprobe=8)
+        assert frac.get() == 0.0
+        assert (arms["query"].get(), arms["batch"].get()) == (1, 1)
+        touched = METRICS.gauge("ivf.batch_scan_buckets", region_id=7).get()
+        assert 1 <= touched <= idx.view_stats()["nbuckets"]
     finally:
         FLAGS.set("use_pallas_ivf_search", False)
-    assert c.get() > before
-    assert 0.0 < METRICS.gauge(
-        "ivf.pruned_dim_fraction", region_id=7
-    ).get() < 1.0
 
 
-def test_pruned_steady_state_no_recompiles(corpus, small_dim_block):
-    """PR 5 sentinel invariant: repeated same-shape pruned searches hit
-    the jit cache (grid clamp + shape bucketing keep shapes stable)."""
+@pytest.mark.parametrize("nq", [4, 8])
+def test_pruned_steady_state_no_recompiles(corpus, small_dim_block, nq):
+    """PR 5 sentinel invariant: repeated same-shape searches hit the jit
+    cache (grid clamp + shape bucketing keep shapes stable), on both loop
+    orders of the Pallas scan."""
     x, ids, q = corpus
+    q = q[:nq]
     idx = TpuIvfFlat(1, IndexParameter(
         index_type=IndexType.IVF_FLAT, dimension=D, ncentroids=NLIST,
     ))

@@ -74,6 +74,14 @@ def _ivf_pruned_topk(xdt):
             interpret=False, nq=B, sq=sq, inbucket=True)
 
 
+def _ivf_batch_topk(xdt):
+    return pallas_ivf.ivf_batch_topk, (
+        _sds((B, BUDGET), i32), _sds((B, D), f32), _sds((NB, CAP, D), xdt),
+        _sds((NB, CAP), f32), _sds((NB, CAP), jnp.bool_),
+        _sds((NB, CAP), i32), *_codec(xdt == u8),
+    ), dict(k=K, ascending=True, interpret=False)
+
+
 def _ivf_pq_adc_topk():
     return pallas_pq.ivf_pq_adc_topk, (
         _sds((B, BUDGET), i32), _sds((B, BUDGET), i32),
@@ -91,6 +99,9 @@ CASES = {
     "ivf_pruned_topk[f32]": lambda: _ivf_pruned_topk(f32),
     "ivf_pruned_topk[bf16]": lambda: _ivf_pruned_topk(bf16),
     "ivf_pruned_topk[sq8]": lambda: _ivf_pruned_topk(u8),
+    "ivf_batch_topk[f32]": lambda: _ivf_batch_topk(f32),
+    "ivf_batch_topk[bf16]": lambda: _ivf_batch_topk(bf16),
+    "ivf_batch_topk[sq8]": lambda: _ivf_batch_topk(u8),
     "ivf_pq_adc_topk[m=96]": _ivf_pq_adc_topk,
 }
 

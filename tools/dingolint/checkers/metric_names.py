@@ -119,6 +119,10 @@ FAMILY_NAMES = {
                                     # last dimension block
         "ivf.probed_rows_per_query",  # rows in the probed buckets, mean
                                     # per query of a sampled batch (gauge)
+        "ivf.scan_arm",             # searches by the scan's loop order,
+                                    # {arm}: batch | query | xla
+        "ivf.batch_scan_buckets",   # buckets the last batch-major scan
+                                    # read, each once (gauge)
     },
     "background": {
         "background.busy_ms",       # ms inside background spans (crontab
