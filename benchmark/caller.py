@@ -164,8 +164,9 @@ class Caller:
         after the close. Open loop (`due` offsets from t_open): each sent
         when due from a pool of threads, timed from when it was due."""
         self.done.clear()
+        time.sleep(max(0.0, t_open - time.monotonic()))
+        cpu0 = time.process_time()
         if due is None:
-            time.sleep(max(0.0, t_open - time.monotonic()))
             while time.monotonic() < t_close:
                 self.one()
         else:
@@ -179,7 +180,10 @@ class Caller:
                     futures.append(ex.submit(self.search, t_due, int(off)))
                 for f in futures:
                     f.result()
-        return self._save("window")
+        # this process's CPU seconds (all its threads) from the open to its
+        # last reply: `generator_busy_share` takes the busiest caller's
+        return dict(self._save("window"),
+                    cpu_s=time.process_time() - cpu0)
 
     def readback(self, requests) -> dict:
         """Search for the rows of acknowledged write requests, 64 at a

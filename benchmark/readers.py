@@ -55,6 +55,7 @@ class Run:
         self.config = kw.get("config")
         self.traffic = kw.get("traffic")
         self.device_kind = kw.get("device_kind", "")
+        self.readings = kw.get("readings") or {}  # the harness's own
 
     def latencies_ms(self, field: str = "latency"):
         """All requests of the window; a failed one counts as over any
@@ -160,6 +161,13 @@ def compared(run: Run, key: str):
     return None if v is None else float(v)
 
 
+def harness_reading(run: Run, key: str):
+    """What the harness read of itself and its machine (`host_probe.py`,
+    the callers' CPU seconds)."""
+    v = run.readings.get(key)
+    return None if v is None else float(v)
+
+
 def setup_phase(run: Run, phase: str):
     v = run.setup.get(phase)
     return None if v is None else float(v)
@@ -254,7 +262,8 @@ def idle_share(run: Run):
 
 READERS = {f.__name__: f for f in (
     request_stat, rate, span_stat, difference, stall_seconds, counter_delta, gauge,
-    compared, setup_phase, memory_peak, trace_op_time, roofline, idle_share)}
+    compared, harness_reading, setup_phase, memory_peak, trace_op_time, roofline,
+    idle_share)}
 
 
 def read(run: Run, spec: dict):

@@ -35,6 +35,7 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 sys.path.insert(0, ROOT)
 PARTITION = 1
+PROBE_LEAD_S = 1.5      # host_probe.py runs this long before the window opens
 FALLBACKS = ("fault.host_exact_searches", "fault.bruteforce_searches",
              "fault.oom_recoveries", "fault.degraded_regions")
 
@@ -222,6 +223,16 @@ class Cluster:
         self.callers.append(c)
         return c
 
+    def spawn_probe(self) -> Child:
+        """`host_probe.py` on the store's cores: asked once, just before the
+        window opens, how long its fixed piece of host work takes today."""
+        c = Child("host_probe", [sys.executable,
+                                 os.path.join(HERE, "host_probe.py")],
+                  self.off_jax, os.path.join(self.out, "host_probe.log"),
+                  cores=self.store_cores)
+        self.callers.append(c)
+        return c
+
     def create_region(self, config: dict) -> None:
         """The region by the configuration's own recipe: `index_parameter`
         holds the fields of pb.VectorIndexParameter, enums by their names."""
@@ -324,6 +335,16 @@ def arrivals(mix: dict, seed: int, seconds: float):
     gaps = np.random.default_rng([11, seed]).permutation(gaps)
     due = np.cumsum(gaps)[:n] * (seconds / gaps.sum())
     return due
+
+
+# ---------------------------------------------------------- the generator
+def generator_busy_share(files, seconds: float) -> float:
+    """The busiest caller's own CPU seconds over the window's: a caller is
+    one process under one GIL, so it can use one core and no more (the
+    generator's cores outnumber the callers or equal them). Near 1 that
+    caller, not the store, sets its pace, and the run says nothing about
+    the store."""
+    return max(float(f["cpu_s"]) for f in files) / seconds
 
 
 # ---------------------------------------------------------------- alignment
@@ -516,6 +537,7 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
         wrap = [os.path.join(HERE, name), function]
     traffic_callers = [cluster.spawn_caller(i, config, mix, args.seed, wrap)
                        for i in range(n_callers)]
+    probe = cluster.spawn_probe()
     device = cluster.start(config, conf, bool(args.trace))
     store, client = cluster.store, cluster.client
     setup["store_up"] = time.monotonic() - T_START
@@ -531,7 +553,7 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
     # ---- region, load, build ----------------------------------------------
     cluster.create_region(config)
     for c in cluster.callers:
-        c.reply()                       # {"ready": true}
+        c.reply()                       # {"ready": true}; the probe's too
     t0 = time.monotonic()
     setup["region"] = t0 - T_START - setup["store_up"]
     traffic_callers[0].ask(cmd="load")
@@ -562,7 +584,7 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
 
     # ---- open the window at a fixed offset from the store's schedule ------
     ev = store.ask(cmd="events")
-    t_open = time.monotonic() + 1.0
+    t_open = time.monotonic() + PROBE_LEAD_S + 0.5
     tick = None
     if not args.no_align:
         t_open, tick = aligned_open(ev, harness, t_open, seconds)
@@ -588,6 +610,9 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
     else:
         for c in traffic_callers:
             c.send(cmd="go", t_open=t_open, t_close=t_close, due=None)
+    # the store is idle, between two of its 5-s jobs: how fast is the host?
+    time.sleep(max(0.0, t_open - PROBE_LEAD_S - time.monotonic()))
+    readings = {"host_probe_ms": probe.ask(cmd="probe")["least_ms"]}
     profile = None
     if args.trace:
         trace_dir = os.path.join(out, "profile")
@@ -619,6 +644,7 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
     attempted = int(len(records))
     failed = int((records[:, 3] <= 0).sum()) if attempted else 0
     errors = [e for f in files for e in f["errors"]]
+    readings["generator_busy_share"] = generator_busy_share(files, seconds)
     job = {"seed": args.seed, "config": config,
            "k": mix["search_args"]["topk"], "pool": mix["query_pool"],
            "replies": os.path.join(out, "replies.npz")}
@@ -739,7 +765,8 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
         records=records, t_open=t_open, t_close=t_close, spans=spans,
         metrics_before=before, metrics_after=after, trace=trace,
         profile=profile, setup=setup, memory=memory, compared=numbers,
-        config=config, traffic=mix, device_kind=device["kind"])
+        config=config, traffic=mix, device_kind=device["kind"],
+        readings=readings)
     wanted = bench["per_layer"] if args.trace else bench["end_to_end"]
     metrics = {}
     for m in wanted:
@@ -763,13 +790,14 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
             f"{tl['unprofiled_requests_per_s']:.2f} in the rest of the "
             f"window; spans kept for {len({s[3] for s in spans})} requests")
     tl.update(workload=cell["name"], seed=args.seed, t_open=t_open,
-              setup=setup, drain_s=t_drained - t_close, exit_codes=codes,
-              crontab=ev["crontab"])
+              setup=setup, harness=readings, drain_s=t_drained - t_close,
+              exit_codes=codes, crontab=ev["crontab"])
     with open(os.path.join(out, "timeline.json"), "w") as f:
         json.dump(tl, f)
     say("timeline " + json.dumps({k: tl[k] for k in (
         "completions", "longest_ms", "longest_region_map_ms", "store")}))
     say("setup " + json.dumps({k: round(v, 2) for k, v in setup.items()}))
+    say("harness " + json.dumps(readings))
     if memory.get("disk_write_bytes"):
         say(f"the store wrote {memory['disk_write_bytes'] / 1e9:.2f} GB to "
             "disk in this run (WAL, checkpoints, raft log, index snapshots)")

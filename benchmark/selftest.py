@@ -8,7 +8,11 @@
    shapes, and the roofline arithmetic;
 3. the percentile, rate, stall and open-loop timing arithmetic;
 4. the comparison: a perfect program passes, the bfloat16 control does not;
-5. CPU rehearsals (`JAX_PLATFORMS=cpu`, explicit small `--rows`): every line
+5. the contract's data: every cell's traffic file states its load (the
+   caller count of a closed loop, rate and threads of an open one) and why,
+   every metric has its file, and the bounds in BENCHMARK.json are the ones
+   PERF.md section 2 gives its reasons for;
+6. CPU rehearsals (`JAX_PLATFORMS=cpu`, explicit small `--rows`): every line
    says `platform: cpu`, the last line is never a pass, and with the timed
    path broken underneath (`--wrap-client faults.py:<fault>`) `correct`
    comes out false; a configuration whose metric or precision no arm of the
@@ -141,8 +145,19 @@ def test_arithmetic() -> None:
           "rate: replies complete by the close, over all the window")
     check(readers.stall_seconds(run) == 3 * 60 / 5,
           "stall seconds per minute: seconds with no completion")
+    import host_probe
     import run as harness
 
+    check(harness.generator_busy_share(
+        [{"cpu_s": 9.0}, {"cpu_s": 4.5}], 45.0) == 0.2,
+          "generator busy share: the busiest caller's CPU seconds over the "
+          "window's, one core a caller")
+    spec = {"reader": "harness_reading", "args": {"key": "host_probe_ms"}}
+    check(readers.read(readers.Run(readings={"host_probe_ms": 61.5}), spec)
+          == 61.5 and readers.read(readers.Run(), spec) is None,
+          "a harness reading is read by its key, and left out where absent")
+    check(host_probe.one_pass([1.0] * 64 * 768) > 0,
+          "the host probe times a pass of its fixed work")
     mix = load("traffic", "single_open_flat768.json")
     a, b = harness.arrivals(mix, 1, 45), harness.arrivals(mix, 2**31 + 5, 45)
     check(len(a) == len(b) == int(mix["rate_per_s"] * 45),
@@ -237,7 +252,54 @@ def test_comparison() -> None:
     harness.check_config(config)
 
 
-# ------------------------------------------------------------- 5. rehearsals
+# ------------------------------------------------------------------ 5. data
+def test_data() -> None:
+    import re
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for cell in bench["workloads"]:
+        mix = load("traffic", cell["traffic"] + ".json")
+        if mix["loop"] == "closed":
+            n = mix.get("callers")
+            stated = isinstance(n, int) and n >= 1
+            said = stated and all(re.search(rf"\b{n} (closed-loop )?"
+                                            r"(callers?|writers?)\b", why)
+                                  for why in (mix.get("why", ""), cell["why"]))
+        else:
+            n = (mix.get("processes"), mix.get("threads_per_process"))
+            stated = all(isinstance(v, int) and v >= 1 for v in n) \
+                and mix.get("rate_per_s", 0) > 0
+            said = stated and f"{mix['rate_per_s']:g}" in cell["why"]
+        check(stated, f"{cell['name']}: traffic {cell['traffic']} states its "
+              f"load ({n})")
+        check(said and len(mix["why"]) > 40,
+              f"{cell['name']}: the traffic's and the cell's why name that load")
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        check(os.path.exists(os.path.join(HERE, "metrics", m["name"] + ".json")),
+              f"metric {m['name']} has its file")
+    on_file = {os.path.splitext(f)[0] for f in os.listdir(
+        os.path.join(HERE, "metrics"))}
+    listed = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]}
+    check(on_file == listed, "no metric file without an entry in "
+          f"BENCHMARK.json ({sorted(on_file - listed)})")
+    # PERF.md section 2's table: | `metric` | cells | what | bound | set from |
+    with open(os.path.join(ROOT, "PERF.md")) as f:
+        section = f.read().split("\n## 2.")[1].split("\n## 3.")[0]
+    table = {}
+    for line in section.splitlines():
+        cols = [c.strip() for c in line.strip().strip("|").split("|")]
+        if len(cols) >= 4 and re.fullmatch(r"`[\w.]+`", cols[0]):
+            table[cols[0].strip("`")] = float(cols[3])
+    bounds = {m["name"]: m["bound"] for m in bench["end_to_end"]}
+    check(table == bounds, f"BENCHMARK.json's bounds {bounds} are PERF.md "
+          f"section 2's {table}")
+    run_seconds = re.search(r"`run_seconds` (\d+)", section)
+    check(run_seconds and int(run_seconds.group(1)) == bench["run_seconds"],
+          "PERF.md section 2 states BENCHMARK.json's run_seconds")
+
+
+# ------------------------------------------------------------- 6. rehearsals
 def rehearse(workload: str, *extra):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     got = subprocess.run(
@@ -253,6 +315,8 @@ def rehearse(workload: str, *extra):
     last = json.loads(lines[-1].split("  [platform")[0])
     check(last["correct"] is False and last["rehearsal"] is True,
           f"{workload} {extra}: the last line is not a pass")
+    last["harness"] = next(json.loads(ln[8:].split("  [platform")[0])
+                           for ln in lines if ln.startswith("harness {"))
     return last
 
 
@@ -264,8 +328,13 @@ def test_rehearsals() -> None:
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=120)
     check(got.returncode != 0 and not got.stdout.strip(),
           "without a chip and without --rows: no result, non-zero exit")
-    check(rehearse("ivf768.batch64")["correct_if_it_were_a_chip"] is True,
+    sound = rehearse("ivf768.batch64")
+    check(sound["correct_if_it_were_a_chip"] is True,
           "ivf768.batch64: the sound path would be correct")
+    check(0 < sound["harness"]["generator_busy_share"] <= 1.05
+          and sound["harness"]["host_probe_ms"] > 0,
+          "ivf768.batch64: the harness reads its busiest caller's share of "
+          "one core and the host probe")
     check(rehearse("ivf768.batch64", "--wrap-client", "faults.py:alter_answer")[
         "correct_if_it_were_a_chip"] is False,
         "an answer altered where it is produced: not correct")
@@ -287,6 +356,7 @@ def main() -> int:
     test_arithmetic()
     test_comparison()
     test_reducer()
+    test_data()
     if "quick" not in sys.argv[1:]:
         test_rehearsals()
     print("selftest passed", flush=True)

@@ -9,7 +9,8 @@ separated]`. Prints every run's result line, then per workload and metric the
 values, the median and the spread: the distance between the first and third
 quartile (`statistics.quantiles(values, n=4)`) as a share of the median.
 `--keep DIR` copies each run's timeline, reduced trace and logs to
-DIR/<tag>/ (the chip tool brings `chiprun_out/` back).
+DIR/<tag>/ and appends each result line to DIR/<tag>.jsonl (the chip tool
+brings `chiprun_out/` back).
 """
 
 from __future__ import annotations
@@ -73,8 +74,17 @@ def main() -> int:
             result = json.loads(last)
         except ValueError:
             result = None
-        rows.append((workload, int(trace), seed, got.returncode, took, result))
+        # what the harness read of itself (`harness {...}` in the run's log)
+        readings = next((json.loads(ln[8:]) for ln in lines[::-1]
+                         if ln.startswith("harness {")), {})
+        rows.append((workload, int(trace), seed, got.returncode, took, result,
+                     readings))
         if keep:
+            with open(keep + ".jsonl", "a") as f:
+                f.write(json.dumps({"tag": args.tag, "spec": spec,
+                                    "rc": got.returncode, "took": took,
+                                    "result": result,
+                                    "harness": readings}) + "\n")
             dest = os.path.join(keep, f"{i}-{workload}-s{seed}-t{trace}")
             os.makedirs(dest, exist_ok=True)
             for name in KEPT:
@@ -90,7 +100,7 @@ def main() -> int:
         shutil.rmtree(out, ignore_errors=True)
     print("=== summary", flush=True)
     groups = {}
-    for workload, trace, seed, rc, took, result in rows:
+    for workload, trace, seed, rc, took, result, readings in rows:
         if result is None:
             continue
         g = groups.setdefault((workload, trace), {})
@@ -102,6 +112,8 @@ def main() -> int:
             if key in result.get("device", {}):
                 g.setdefault("device." + key, []).append(
                     result["device"][key])
+        for name, value in readings.items():
+            g.setdefault("harness." + name, []).append(value)
         for name, shown in result.get("compared", {}).items():
             g.setdefault("compared." + name, []).append(shown[0])
     for (workload, trace), g in groups.items():
