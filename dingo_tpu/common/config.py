@@ -287,13 +287,16 @@ FLAGS.define("hnsw_max_iters", 48, mutable=True,
                    "beam has converged; the cap bounds worst-case latency "
                    "on adversarial graphs")
 FLAGS.define("hnsw_device_build", "auto", mutable=True,
-             help_="build bulk HNSW graphs on the device "
-                   "(ops/graph_build.py): pow2 insert batches walk the "
-                   "partially-built adjacency with the lockstep beam "
+             help_="build HNSW graphs on the device "
+                   "(ops/graph_build.py), bulk rebuilds and, with "
+                   "hnsw_device_search also on, every upsert into the "
+                   "live graph (the device adjacency is then the one "
+                   "graph): pow2 insert batches walk the adjacency as it "
+                   "stands with the lockstep beam "
                    "kernel, occlusion-prune neighbors as masked top-k "
                    "over the candidate score matrix, and install reverse "
                    "edges with degree-clamped re-pruning; the native "
-                   "graph back-fills lazily on first host-path use. "
+                   "graph back-fills only on a CPU-arm use. "
                    "'auto' (default) = TPU-only — MXU batch throughput "
                    "is the whole point; the host insert loop stays the "
                    "CPU arm and the parity oracle. True/False force")

@@ -85,6 +85,8 @@ class IndexParameter:
     nbits_per_idx: int = 8        # PQ nbits (ksub = 2**nbits)
     default_nprobe: int = 80
     # HNSW (vector_index_hnsw.cc:154-181)
+    # rows the region is created for: the slot store and the device
+    # adjacency are sized for them at creation (0 = grow by pow2 steps)
     max_elements: int = 0
     efconstruction: int = 200
     nlinks: int = 32              # M

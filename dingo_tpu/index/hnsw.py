@@ -1,33 +1,43 @@
-"""TpuHnsw: dual-representation graph index — host graph for writes,
-device graph for reads.
+"""TpuHnsw: a graph index whose TPU arm keeps ONE graph, on the device.
 
 Reference: VectorIndexHnsw (src/vector/vector_index_hnsw.{h,cc} — wraps
 hnswlib::HierarchicalNSW with L2Space/InnerProductSpace,
 vector_index_hnsw.cc:154-181; NeedToRebuild when deleted count exceeds half
 the TOTAL element count :577-589; hnswlib-file Save/Load :310).
 
-Two serving paths share one SlotStore + one exact device rerank:
+Two arms share one SlotStore + one exact device rerank; which one a
+process walks follows from the backend it observes (``hnsw.device_search``
+and ``hnsw.device_build``, both ``auto`` = TPU-only):
 
-  host path (fallback + parity oracle) — graph construction and beam
+  TPU arm (both on) — the level-0 adjacency in slot space
+  (``SlotStore.adj``, dense ``[capacity, deg]`` int32, deg = nlinks*2) IS
+  the graph. ``upsert`` puts the rows and inserts them into the live
+  adjacency in pow2 batches (ops/graph_build.insert_batch: candidate
+  discovery by the lockstep beam walk, occlusion pruning, reverse edges;
+  the adjacency is donated under ``store.device_lock``), so an
+  acknowledged row is found by the next search with no O(N) step;
+  ``delete`` tombstones the slot and the walk routes around it; searches
+  run as one jitted lockstep beam search (ops/beam.py: frontier gather on
+  the adjacency, candidate distances via one einsum against the SlotStore,
+  a per-query packed visited bitmask, masked top-k beam updates, a fixed
+  iteration cap with early exit); ``save`` persists rows + adjacency +
+  entry and ``load`` serves from them. No native graph is fed, exported or
+  written: ``hnsw.native_adds``, ``hnsw.adjacency_rebuilds`` and
+  ``hnsw.host_searches`` stay 0.
+
+  CPU arm (either off) and parity oracle — graph construction and beam
   search run in our own C++ NSW implementation (native/hnsw/hnsw.cc, an
   original implementation, not a copy of hnswlib). The graph returns an
-  over-fetched candidate set (ef per query), and the device re-ranks the
-  candidates with exact batched distances against the authoritative
-  SlotStore copy.
-
-  device path (``hnsw.device_search``, ISSUE 8 tentpole) — the native
-  level-0 adjacency exports into a dense slot-space ``[capacity, deg]``
-  int32 mirror (SlotStore.adj, deg = nlinks*2) and the whole walk runs as
-  one jitted lockstep beam search (ops/beam.py): frontier gather on the
-  adjacency, candidate distances via one ``[b, beam*deg] x d`` einsum
-  against the SlotStore (bf16/sq8 precision tiers included), a per-query
-  packed visited bitmask over capacity, masked top-k beam updates, and a
-  fixed iteration cap with early exit once every query's beam converges.
-  The mirror stays in sync with upsert/delete/load by keying on
-  (native graph version, store mutation version) and lazily re-exporting
+  over-fetched candidate set (ef per query) and the device re-ranks it.
+  With ``hnsw.device_search`` forced on over a native-built graph, the
+  native level-0 adjacency exports into the device mirror, keyed on
+  (native graph version, store mutation version) and lazily re-exported
   on the first search after a write — the IVF `_ensure_view` discipline.
+  A device-owned graph met by this arm (a flag flipped, a host search
+  asked for) is replayed into the native graph first
+  (``_ensure_native_graph``, ``build.backfills``).
 
-Both paths end in the SAME exact device rerank (ops/rerank.py), so the
+Both arms end in the SAME exact device rerank (ops/rerank.py), so the
 final ordering is byte-identical whenever the candidate sets agree.
 Filter pushdown applies the PR 3 filter-mask cache device-side inside
 the beam kernel (masked candidates never enter the result beam); the
@@ -61,6 +71,7 @@ from dingo_tpu.index.flat import (
     _pad_batch,
     integrity_mutation,
 )
+from dingo_tpu.obs.sentinel import sentinel_jit
 from dingo_tpu.ops.distance import Metric, np_normalize
 
 _LIB = None
@@ -83,6 +94,32 @@ def _lib():
     return _LIB
 
 
+@sentinel_jit("index.hnsw.search",
+              static_argnames=("beam", "max_iters", "metric", "k"))
+def hnsw_search_program(adj, vecs, sqnorm, valid, fmask, queries, entry,
+                        beam, max_iters, metric, k):
+    """A float-tier search request as ONE device program: the lockstep
+    walk (ops/beam.py) and the exact rerank of its candidate set
+    (ops/rerank.py), one launch inside one ``store.device_lock`` hold and
+    three arrays to fetch — as IVF_FLAT's ``ivf_search_program``. The sq8
+    tier keeps the two launches (its rerank may chain the row cache).
+
+    Returns (wire distances [b, k], slots [b, k], walk diagnostics
+    [b, 3] int32: rounds, visited rows, live result entries)."""
+    from dingo_tpu.ops.beam import beam_search
+    from dingo_tpu.ops.rerank import exact_rerank_device
+
+    unit = jnp.zeros((vecs.shape[1],), jnp.float32)   # sq codec, unused
+    rslots, hops, vcount, occ = beam_search.__wrapped__(
+        adj, vecs, sqnorm, valid, fmask, queries, entry, unit, unit,
+        beam, max_iters, metric, False,
+    )
+    dists, slots = exact_rerank_device.__wrapped__(
+        vecs, sqnorm, queries, rslots, k, metric
+    )
+    return dists, slots, jnp.stack([hops, vcount, occ], axis=1)
+
+
 class TpuHnsw(_SlotStoreIndex):
     def __init__(self, index_id: int, parameter: IndexParameter):
         VectorIndex.__init__(self, index_id, parameter)
@@ -92,13 +129,16 @@ class TpuHnsw(_SlotStoreIndex):
         if p.metric is Metric.HAMMING:
             raise InvalidParameter("hamming not valid for HNSW")
         precision = resolve_precision(parameter)
-        self.store = _new_tier_store(precision, p.dimension, parameter)
+        # `max_elements` (the upstream's hnsw parameter): the slot store,
+        # and with it the device adjacency, is sized for the region's rows
+        # at creation, so a load never re-shapes them (each pow2 step of a
+        # growing store re-allocates both and recompiles the insert and
+        # search programs for the new shape). 0 = grow.
+        self.store = _new_tier_store(precision, p.dimension, parameter,
+                                     capacity=max(0, int(p.max_elements)))
         self._init_precision(parameter, tier=precision)
         self.ef_search_default = max(64, p.efconstruction // 2)
-        metric_code = 0 if p.metric is Metric.L2 else 1
-        self._graph = _lib().hnsw_new(
-            p.dimension, metric_code, p.nlinks, p.efconstruction, index_id
-        )
+        self._graph = self._new_native_graph()
         self._kernel_metric = p.metric
         self._kernel_nbits = 0
         #: level-0 degree cap of the exported adjacency (hnsw M0 = 2*M)
@@ -107,12 +147,38 @@ class TpuHnsw(_SlotStoreIndex):
         #: adjacency mirror was built against; None = never built
         self._graph_key = None
         self._entry_slot = -1
-        #: device bulk build installed an adjacency the native graph does
-        #: not hold yet — the first host-path use (write, host search,
-        #: save) back-fills it (ISSUE 18 tentpole a)
+        #: rows tombstoned while the device adjacency was the graph
+        self._deleted_slots = 0
+        #: the device adjacency is THE graph and the native graph does not
+        #: hold it (TPU-arm writes, a device bulk build, a loaded device
+        #: snapshot) — the first CPU-arm use (write, host search, save)
+        #: back-fills the native graph from the store's rows
         self._native_pending = False
+        #: TPU-arm writes since the adjacency ledger was last seeded: the
+        #: scrub and the snapshot skip the artifact until save() re-seeds
+        #: it from the host copy it takes anyway
+        self._adj_ledger_stale = False
+        #: reverse edges dropped by TPU-arm inserts (device scalar, folded
+        #: into ``build.reverse_dropped`` at save: no sync on a write)
+        self._dropped_d = None
+        self._identity_codec = None
+        #: (entry slot, its device scalar): uploaded when the entry moves,
+        #: not with every search
+        self._entry_cached = (None, None)
+        # the counters that say which arm served exist from the start: an
+        # arm that never ran reads 0, not "no such series"
+        for name in ("native_adds", "adjacency_rebuilds", "host_searches",
+                     "device_searches"):
+            METRICS.counter("hnsw." + name, region_id=index_id).add(0)
         #: fingerprint -> (store version, numpy mask, device mask or None)
         self._filter_cache: dict = {}
+
+    def _new_native_graph(self):
+        p = self.parameter
+        return _lib().hnsw_new(
+            p.dimension, 0 if p.metric is Metric.L2 else 1, p.nlinks,
+            p.efconstruction, self.id,
+        )
 
     def __del__(self):  # noqa: D105
         try:
@@ -152,9 +218,22 @@ class TpuHnsw(_SlotStoreIndex):
         if self._precision == "sq8" and vectors is not None:
             self.store.maybe_train(self._prep_vectors(vectors))
 
-    @integrity_mutation
-    def upsert(self, ids: np.ndarray, vectors: np.ndarray) -> None:
-        self._ensure_native_graph()
+    def _tpu_arm(self) -> bool:
+        """True where the device adjacency is the one graph: searches walk
+        it (``hnsw.device_search``) and writes insert into it
+        (``hnsw.device_build``); both ``auto`` = TPU-only, so the arm
+        follows the backend the process observes."""
+        from dingo_tpu.common.config import (
+            hnsw_device_build_enabled,
+            hnsw_device_enabled,
+        )
+
+        return hnsw_device_build_enabled() and hnsw_device_enabled()
+
+    def _put_rows(self, ids: np.ndarray, vectors: np.ndarray):
+        """Store put + rerank offer + quality/integrity ledgers: what every
+        write does whichever graph takes the edges. -> (ids, vectors,
+        slots)"""
         vectors = self._prep_vectors(vectors)
         ids = np.ascontiguousarray(ids, np.int64)
         if len(ids) != len(vectors):
@@ -167,17 +246,34 @@ class TpuHnsw(_SlotStoreIndex):
         # for shadow ground truth (no-op while sampling is off)
         QUALITY.observe_write(self, ids, vectors)
         self._integrity_write(ids, vectors)
+        self.write_count_since_save += len(ids)
+        return ids, vectors, slots
+
+    def _native_add(self, ids: np.ndarray, vectors: np.ndarray) -> None:
         _lib().hnsw_add(
             self._graph,
             len(ids),
             ids.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
             vectors.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
         )
-        self.write_count_since_save += len(ids)
+        METRICS.counter("hnsw.native_adds", region_id=self.id).add(len(ids))
+
+    @integrity_mutation
+    def upsert(self, ids: np.ndarray, vectors: np.ndarray) -> None:
+        if self._tpu_arm():
+            self._own_device_graph()
+            _, _, slots = self._put_rows(ids, vectors)
+            self._device_insert(np.asarray(slots, np.int32))
+            return
+        self._ensure_native_graph()
+        ids, vectors, _ = self._put_rows(ids, vectors)
+        self._native_add(ids, vectors)
 
     @integrity_mutation
     def delete(self, ids: np.ndarray) -> None:
-        self._ensure_native_graph()
+        tpu = self._tpu_arm() and self._native_pending
+        if not tpu:
+            self._ensure_native_graph()
         ids = np.ascontiguousarray(ids, np.int64)
         slots = self.store.remove_slots(ids)
         removed = int((slots >= 0).sum())
@@ -186,11 +282,99 @@ class TpuHnsw(_SlotStoreIndex):
 
         QUALITY.observe_delete(self, ids)
         self._integrity_delete(ids)
-        _lib().hnsw_delete(
-            self._graph, len(ids),
-            ids.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-        )
+        if tpu:
+            # a tombstone: the slot leaves the validity mask, the walk
+            # routes around it, and neighbours' edges to it now translate
+            # to no id — the adjacency ledger waits for the next save
+            self._deleted_slots += removed
+            self._adj_ledger_stale = True
+        else:
+            _lib().hnsw_delete(
+                self._graph, len(ids),
+                ids.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+            )
         self.write_count_since_save += removed
+
+    # -- TPU arm: the device adjacency as the one graph ----------------------
+    def _own_device_graph(self) -> None:
+        """Make the device adjacency the graph writes go into. A fresh
+        index gets an empty adjacency; a native-built graph (a loaded
+        CPU-arm snapshot, a flag flipped under a live index) hands its
+        level-0 export over once. From here the native graph is stale
+        (`_native_pending`) until a CPU-arm use replays the rows."""
+        store = self.store
+        if self._native_pending and store.adj is not None:
+            return
+        with store.device_lock:
+            if int(_lib().hnsw_total_count(self._graph)):
+                self._ensure_device_graph()
+            elif store.adj is None:
+                store.set_graph(
+                    np.full((store.capacity, self._graph_deg), -1, np.int32),
+                    self._graph_deg,
+                )
+            self._native_pending = True
+
+    def _entry_device(self):
+        if self._entry_cached[0] != self._entry_slot:
+            self._entry_cached = (
+                self._entry_slot, jnp.asarray(self._entry_slot, jnp.int32)
+            )
+        return self._entry_cached[1]
+
+    def _codec(self):
+        """(sq_on, vmin, scale) for the beam and build kernels; float tiers
+        pass one cached identity codec (no per-call device allocation)."""
+        store = self.store
+        if self._precision == "sq8" and store.sq_params is not None:
+            return True, store.sq_vmin_d, store.sq_scale_d
+        if self._identity_codec is None:
+            self._identity_codec = (
+                jnp.zeros((self.dimension,), jnp.float32),
+                jnp.ones((self.dimension,), jnp.float32),
+            )
+        return (False,) + self._identity_codec
+
+    def _device_insert(self, slots: np.ndarray) -> None:
+        """Insert freshly put slots into the live adjacency, in the pow2
+        batch ladder of the bulk build (full ``hnsw.build_batch`` batches,
+        the remainder padded to its own pow2 with -1): candidate discovery
+        walks the graph as it stands, so rows of an earlier batch — and of
+        every earlier upsert — are found. ``store.device_lock`` is held
+        for the donated update only; nothing is read back."""
+        from dingo_tpu.common.config import FLAGS
+        from dingo_tpu.ops.graph_build import insert_batch, ladder_batches
+        from dingo_tpu.trace import TRACER
+
+        if not len(slots):
+            return
+        store = self.store
+        beam = self._beam_width(self.parameter.efconstruction, 1)
+        max_iters = max(1, int(FLAGS.get("hnsw_max_iters")))
+        alpha = float(FLAGS.get("hnsw_build_alpha"))
+        with TRACER.start_child("hnsw.insert_batch"):
+            for chunk in ladder_batches(
+                    slots, int(FLAGS.get("hnsw_build_batch"))):
+                with store.device_lock:
+                    sq_on, vmin, scale = self._codec()
+                    store.adj, _, dropped = insert_batch(
+                        store.adj, store.vecs, store.sqnorm,
+                        store.device_mask(), chunk,
+                        self._entry_device(), vmin, scale,
+                        beam=beam, max_iters=max_iters,
+                        metric=self._kernel_metric, sq=sq_on,
+                        alpha_sq=alpha * alpha,
+                    )
+                    self._dropped_d = dropped if self._dropped_d is None \
+                        else self._dropped_d + dropped
+                if self._entry_slot < 0:
+                    # the first inserted row anchors all later walks (what
+                    # insert_batch answers too; known here without a sync)
+                    self._entry_slot = int(chunk[0])
+        self._adj_ledger_stale = True
+        METRICS.gauge("hnsw.graph_nodes", region_id=self.id).set(
+            float(len(store))
+        )
 
     # -- device graph mirror -------------------------------------------------
     def _install_adjacency(self, labels: np.ndarray, adj_nodes: np.ndarray,
@@ -238,20 +422,9 @@ class TpuHnsw(_SlotStoreIndex):
         self._entry_slot = entry
         METRICS.gauge("hnsw.graph_nodes", region_id=self.id).set(float(n))
         # state-integrity: the adjacency artifact resets with every mirror
-        # swap (a full install, not an incremental write). Neighbor slots
-        # translate to EXTERNAL ids so the digest survives slot
-        # renumbering across snapshot load — the same canonical form the
-        # scrub recomputes from the device mirror.
-        from dingo_tpu.obs.integrity import INTEGRITY
-
-        if INTEGRITY.tracking(self):
-            INTEGRITY.reset_artifact(self, "adjacency")
-            live_slots = np.flatnonzero(store.ids_by_slot >= 0)
-            if len(live_slots):
-                INTEGRITY.note_write(
-                    self, "adjacency", store.ids_by_slot[live_slots],
-                    store.ids_of_slots(full[live_slots]),
-                )
+        # swap (a full install, not an incremental write)
+        self._adj_ledger_stale = False
+        self._seed_adjacency_ledger(full)
 
     def _export_level0(self):
         """(labels [n], adjacency [n, deg]) snapshot of the native level-0
@@ -280,7 +453,10 @@ class TpuHnsw(_SlotStoreIndex):
         re-exports. Keyed on the native graph version AND the store
         mutation version — an upsert of an existing id re-slots nothing
         natively but can remap label->slot (delete + re-add), so both
-        sides gate."""
+        sides gate. A device-owned graph (`_native_pending`) is never
+        re-exported: there is nothing to re-export it from."""
+        if self._native_pending and self.store.adj is not None:
+            return
         want = (
             int(_lib().hnsw_graph_version(self._graph)),
             self.store.mutation_version,
@@ -297,13 +473,16 @@ class TpuHnsw(_SlotStoreIndex):
     def adjacency_in_sync(self) -> bool:
         """True while the device adjacency mirror matches the native graph
         AND the store (the scrub only checks the adjacency artifact then —
-        a pending lazy re-export is staleness, not corruption)."""
-        return (
-            self.store.adj is not None
-            and self._graph_key == (
-                int(_lib().hnsw_graph_version(self._graph)),
-                self.store.mutation_version,
-            )
+        a pending lazy re-export is staleness, not corruption). A device-
+        owned graph is in sync with itself; its ledger is stale between a
+        TPU-arm write and the next save, which re-seeds it."""
+        if self.store.adj is None:
+            return False
+        if self._native_pending:
+            return not self._adj_ledger_stale
+        return self._graph_key == (
+            int(_lib().hnsw_graph_version(self._graph)),
+            self.store.mutation_version,
         )
 
     # -- device bulk build (ISSUE 18) ----------------------------------------
@@ -316,7 +495,7 @@ class TpuHnsw(_SlotStoreIndex):
         Returns None when the crossover gate says host (``hnsw.device_build``
         auto = TPU-only — the host insert loop stays the CPU arm and the
         parity oracle) or when the index already holds rows (bulk build
-        constructs from empty; incremental inserts keep the native path).
+        constructs from empty; incremental inserts go through upsert()).
         """
         from dingo_tpu.common.config import hnsw_device_build_enabled
 
@@ -328,22 +507,9 @@ class TpuHnsw(_SlotStoreIndex):
 
     @integrity_mutation
     def _bulk_put(self, ids: np.ndarray, vectors: np.ndarray) -> np.ndarray:
-        """upsert() minus the native ``hnsw_add``: store put + rerank offer
-        + quality/integrity ledgers. The graph edge work happens in the
-        bulk session's device builder; the native graph back-fills lazily
-        via _ensure_native_graph()."""
-        vectors = self._prep_vectors(vectors)
-        ids = np.ascontiguousarray(ids, np.int64)
-        if len(ids) != len(vectors):
-            raise InvalidParameter("ids/vectors length mismatch")
-        slots = self.store.put(ids, vectors)
-        self._offer_rerank(slots, vectors)
-        from dingo_tpu.obs.quality import QUALITY
-
-        QUALITY.observe_write(self, ids, vectors)
-        self._integrity_write(ids, vectors)
-        self.write_count_since_save += len(ids)
-        return slots
+        """The rows of a bulk session: store put + ledgers, no edges (the
+        session's device builder makes them)."""
+        return self._put_rows(ids, vectors)[2]
 
     def _install_built_adjacency(self, adj, entry_slot: int) -> None:
         """Install a device-built [capacity, deg] adjacency as THE graph:
@@ -366,30 +532,41 @@ class TpuHnsw(_SlotStoreIndex):
                     store.mutation_version,
                 )
                 self._native_pending = True
-            n = len(store)
+            self._adj_ledger_stale = False
             METRICS.gauge("hnsw.graph_nodes", region_id=self.id).set(
-                float(n)
+                float(len(store))
             )
-            from dingo_tpu.obs.integrity import INTEGRITY
-
-            if INTEGRITY.tracking(self):
-                full = np.asarray(adj)
-                INTEGRITY.reset_artifact(self, "adjacency")
-                live_slots = np.flatnonzero(store.ids_by_slot >= 0)
-                if len(live_slots):
-                    INTEGRITY.note_write(
-                        self, "adjacency", store.ids_by_slot[live_slots],
-                        store.ids_of_slots(full[live_slots]),
-                    )
+            self._seed_adjacency_ledger(adj)
         finally:
             self._integrity_end()
 
+    def _seed_adjacency_ledger(self, adj) -> None:
+        """Reset the adjacency artifact to what `adj` ([capacity, deg],
+        device or host) holds. Neighbour slots translate to EXTERNAL ids so
+        the digest survives slot renumbering across snapshot load — the
+        same canonical form the scrub recomputes from the device."""
+        from dingo_tpu.obs.integrity import INTEGRITY
+
+        if not INTEGRITY.tracking(self):
+            return
+        store = self.store
+        INTEGRITY.reset_artifact(self, "adjacency")
+        live_slots = np.flatnonzero(store.ids_by_slot >= 0)
+        if len(live_slots):
+            full = np.asarray(adj)
+            INTEGRITY.note_write(
+                self, "adjacency", store.ids_by_slot[live_slots],
+                store.ids_of_slots(full[live_slots]),
+            )
+
     def _ensure_native_graph(self) -> None:
-        """Replay the store's rows into the native graph after a device
-        bulk build — triggered by the first host-path use (write, host
-        search, save), not by the build itself: a device-served region
-        never pays it. Streams BACKFILL_CHUNK rows per native add call
-        (O(chunk) host memory); quantized tiers replay the decoded
+        """Replay the store's rows into the native graph when the device
+        adjacency has been the graph (a device bulk build, TPU-arm writes,
+        a loaded device snapshot) — triggered by the first CPU-arm use
+        (write, host search, save), never on the TPU arm: a device-served
+        region does not pay it. A native graph that holds older rows is
+        replaced, not patched. Streams BACKFILL_CHUNK rows per native add
+        call (O(chunk) host memory); quantized tiers replay the decoded
         surrogate, the store's tier semantics. The handover COMPLETES
         here: once the native graph holds the rows, its level-0 export
         re-installs as the device mirror (one ordinary lazy re-export),
@@ -399,6 +576,9 @@ class TpuHnsw(_SlotStoreIndex):
         if not self._native_pending:
             return
         self._native_pending = False
+        if int(_lib().hnsw_total_count(self._graph)):
+            _lib().hnsw_free(self._graph)
+            self._graph = self._new_native_graph()
         store = self.store
         live = np.flatnonzero(store.valid_h)
         ids = store.ids_by_slot[live]
@@ -406,13 +586,8 @@ class TpuHnsw(_SlotStoreIndex):
             chunk = np.ascontiguousarray(ids[s:s + BACKFILL_CHUNK],
                                          np.int64)
             _, rows = store.gather(chunk)
-            rows = np.ascontiguousarray(rows, np.float32)
-            _lib().hnsw_add(
-                self._graph,
-                len(chunk),
-                chunk.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-                rows.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-            )
+            self._native_add(chunk, np.ascontiguousarray(rows, np.float32))
+        self._deleted_slots = 0
         self._graph_key = None
         with store.device_lock:
             self._ensure_device_graph()
@@ -487,19 +662,46 @@ class TpuHnsw(_SlotStoreIndex):
         ef: Optional[int] = None,
         staged=None,
     ):
-        queries = self._prep_queries(queries)
-        b = queries.shape[0]
-        # request-pinned ef wins; else the SLO tuner's override; else the
-        # construction-derived default (obs/tuner.py walks ladder values)
-        ef = max(int(ef or self.tuned("ef", self.ef_search_default)),
-                 int(topk))
-        self._count_search()
-        if self._device_search_on():
-            return self._device_search_async(
-                queries, b, int(topk), filter_spec, ef, staged=staged
-            )
-        return self._host_search_async(queries, b, int(topk), filter_spec,
-                                       ef, staged=staged)
+        from dingo_tpu.trace import TRACER
+
+        # index.dispatch: entry to kernels enqueued (prep, pad and H2D,
+        # mask capture, enqueue); NOOP for an unsampled request
+        with TRACER.start_child("index.dispatch"):
+            queries = self._prep_queries(queries)
+            b = queries.shape[0]
+            # request-pinned ef wins; else the SLO tuner's override; else
+            # the construction-derived default (obs/tuner.py walks ladder
+            # values)
+            ef = max(int(ef or self.tuned("ef", self.ef_search_default)),
+                     int(topk))
+            self._count_search()
+            if self._device_search_on():
+                fetch, finish = self._device_dispatch(
+                    queries, b, int(topk), filter_spec, ef, staged)
+                name = "beam_search"
+            else:
+                fetch, finish = self._host_dispatch(
+                    queries, b, int(topk), filter_spec, ef, staged)
+                name = "rerank"
+        # the device wait of a sampled request: from here (kernels
+        # enqueued, lock released) to the fetch's return in resolve();
+        # never a sync of its own (ops/distance.device_wait_begin)
+        from dingo_tpu.ops.distance import device_wait_begin
+
+        wait = device_wait_begin(name)
+        lease = finish.lease
+
+        def resolve() -> List[SearchResult]:
+            try:
+                fetched = jax.device_get(fetch)
+                wait.end()
+                # index.resolve: the host work after the fetch
+                with TRACER.start_child("index.resolve"):
+                    return finish(fetched)
+            finally:
+                lease.release()
+
+        return resolve
 
     def _device_search_on(self) -> bool:
         from dingo_tpu.common.config import hnsw_device_enabled
@@ -518,10 +720,54 @@ class TpuHnsw(_SlotStoreIndex):
             return max(fixed, topk)
         return max(shape_bucket(max(ef, topk)), 1)
 
-    def _device_search_async(self, queries, b, topk, filter_spec, ef,
-                             staged=None):
+    def _finisher(self, lease, queries, b, topk, filter_spec, beam,
+                  walk=None):
+        """The host half of a search, run by resolve() on the ONE fetched
+        group: slots -> ids, heat, quality; `walk` = (capacity-free walk
+        diagnostics follow the reply in the same group)."""
+        from dingo_tpu.obs.heat import HEAT, heat_enabled
+
+        store = self.store
+        heat_on = heat_enabled()
+        if heat_on:
+            HEAT.register_layout(self.id, "slot", self._heat_layout)
+
+        def finish(fetched) -> List[SearchResult]:
+            dists_h, slots_h = fetched[0], fetched[1]
+            w = 1.0
+            if walk is not None:
+                stats_h = fetched[2][:b]      # [b, 3]: rounds, visited, live
+                self._note_walk_stats(
+                    stats_h[:, 0], stats_h[:, 1], stats_h[:, 2], beam, *walk
+                )
+                # the per-query visited count weights the heat touch by
+                # how much of the graph the walk crossed
+                w = float(max(1.0, np.mean(stats_h[:, 1]) / max(1, beam)))
+            if heat_on:
+                # result slots mark the graph neighborhoods the walk
+                # landed in; arrays ALREADY in this fetch group
+                HEAT.observe(self.id, "slot", slots_h[:b], weight=w)
+            ids = store.ids_of_slots(slots_h[:b])
+            # head-sampled shadow scoring, attributed to the beam bucket
+            # (the LADDER value: a raw client-pinned ef would mint
+            # unbounded label cardinality; async lane, noop at rate 0)
+            from dingo_tpu.obs.quality import QUALITY
+
+            QUALITY.observe_search(
+                self, queries, topk, ids, dists_h[:b],
+                bucket=f"ef={beam}", filter_spec=filter_spec,
+            )
+            return [strip_invalid(i, d) for i, d in zip(ids, dists_h[:b])]
+
+        finish.lease = lease
+        return finish
+
+    def _device_dispatch(self, queries, b, topk, filter_spec, ef, staged):
+        """Enqueue walk + exact rerank; -> (fetch group, finisher)."""
         from dingo_tpu.common.config import FLAGS
-        from dingo_tpu.ops.beam import beam_search
+        from dingo_tpu.ops.beam import beam_search, round_slots
+        from dingo_tpu.ops.topk import begin_host_fetch
+        from dingo_tpu.trace import TRACER
 
         store = self.store
         beam = self._beam_width(ef, topk)
@@ -529,96 +775,59 @@ class TpuHnsw(_SlotStoreIndex):
         METRICS.counter("hnsw.device_searches", region_id=self.id).add(1)
         prep = self._prep_filter(filter_spec)
         # staging-ring upload (serving pipeline): claimed only when the
-        # identity check proves it was built from THESE queries
+        # identity check proves it was built from THESE queries; else the
+        # padded rows ride the program's own launch
         qpad = staged.take(queries) if staged is not None else None
         if qpad is None:
-            qpad = jnp.asarray(_pad_batch(queries))
+            qpad = _pad_batch(queries)
         lease = store.begin_search()
         try:
-            with store.device_lock:
+            # asking for the lock to holding it (timed when sampled)
+            with TRACER.start_child("index.lock_wait"):
+                store.device_lock.acquire()
+            try:
                 self._ensure_device_graph()
                 valid = store.device_mask()
                 fmask = self._device_filter_mask(filter_spec, prep)
-                sq_on = (
-                    self._precision == "sq8"
-                    and store.sq_params is not None
-                )
-                if sq_on:
-                    vmin, scale = store.sq_vmin_d, store.sq_scale_d
+                if fmask is None:
+                    fmask = valid
+                if self._precision == "sq8":
+                    sq_on, vmin, scale = self._codec()
+                    qpad = jnp.asarray(qpad)
+                    rslots, hops, vcount, occ = beam_search(
+                        store.adj, store.vecs, store.sqnorm, valid, fmask,
+                        qpad, self._entry_device(), vmin, scale,
+                        beam=beam, max_iters=max_iters,
+                        metric=self._kernel_metric, sq=sq_on,
+                    )
+                    dists, out_slots = self._final_rerank(
+                        qpad, rslots, topk)
+                    stats = jnp.stack([hops, vcount, occ], axis=1)
                 else:
-                    vmin = jnp.zeros((self.dimension,), jnp.float32)
-                    scale = jnp.ones((self.dimension,), jnp.float32)
-                cap = store.capacity
-                rslots, hops, vcount, occ = beam_search(
-                    store.adj,
-                    store.vecs,
-                    store.sqnorm,
-                    valid,
-                    fmask if fmask is not None else valid,
-                    qpad,
-                    jnp.asarray(self._entry_slot, jnp.int32),
-                    vmin,
-                    scale,
-                    beam=beam,
-                    max_iters=max_iters,
-                    metric=self._kernel_metric,
-                    sq=sq_on,
-                )
-                dists, out_slots = self._final_rerank(qpad, rslots, topk)
+                    dists, out_slots, stats = hnsw_search_program(
+                        store.adj, store.vecs, store.sqnorm, valid, fmask,
+                        qpad, self._entry_device(),
+                        beam=beam, max_iters=max_iters,
+                        metric=self._kernel_metric, k=topk,
+                    )
+            finally:
+                store.device_lock.release()
         except Exception:
             lease.release()
             raise
-        # one-sync epilogue: walk diagnostics (hops/vcount/occ) join the
-        # SAME D2H copy group as the reply — previously they rode the
-        # device_get cold (no async copy started), adding a serialized
-        # transfer to every resolve
-        from dingo_tpu.ops.topk import begin_host_fetch
+        # one-sync epilogue: the walk's diagnostics join the SAME D2H copy
+        # group as the reply
+        fetch = begin_host_fetch(dists, out_slots, stats)
+        return fetch, self._finisher(
+            lease, queries, b, topk, filter_spec, beam,
+            walk=(len(store), round_slots(beam, self._graph_deg)),
+        )
 
-        fetch = begin_host_fetch(dists, out_slots, hops, vcount, occ)
-        from dingo_tpu.ops.distance import device_wait_begin
+    def _host_dispatch(self, queries, b, topk, filter_spec, ef, staged):
+        """Native graph candidates + enqueued exact rerank; -> (fetch
+        group, finisher)."""
+        from dingo_tpu.trace import TRACER
 
-        # device wait of a sampled request, ended at resolve()'s one fetch
-        wait = device_wait_begin("beam_search")
-        from dingo_tpu.obs.heat import HEAT, heat_enabled
-
-        heat_on = heat_enabled()
-        if heat_on:
-            HEAT.register_layout(self.id, "slot", self._heat_layout)
-
-        def resolve() -> List[SearchResult]:
-            try:
-                dists_h, slots_h, hops_h, vc_h, occ_h = jax.device_get(
-                    fetch
-                )
-                wait.end()
-                self._note_walk_stats(
-                    hops_h[:b], vc_h[:b], occ_h[:b], cap, beam
-                )
-                if heat_on:
-                    # result slots mark the graph neighborhoods the walk
-                    # landed in; the per-query visited count weights the
-                    # touch by how much of the graph the walk crossed.
-                    # Both arrays were ALREADY in this fetch group.
-                    w = float(max(1.0, np.mean(vc_h[:b]) / max(1, beam)))
-                    HEAT.observe(self.id, "slot", slots_h[:b], weight=w)
-                ids = store.ids_of_slots(slots_h[:b])
-                # head-sampled shadow scoring, attributed to the beam
-                # bucket the walk ran with (async lane; noop at rate 0)
-                from dingo_tpu.obs.quality import QUALITY
-
-                QUALITY.observe_search(
-                    self, queries, topk, ids, dists_h[:b],
-                    bucket=f"ef={beam}", filter_spec=filter_spec,
-                )
-                return [strip_invalid(i, d)
-                        for i, d in zip(ids, dists_h[:b])]
-            finally:
-                lease.release()
-
-        return resolve
-
-    def _host_search_async(self, queries, b, topk, filter_spec, ef,
-                           staged=None):
         self._ensure_native_graph()
         METRICS.counter("hnsw.host_searches", region_id=self.id).add(1)
         # 1) CPU graph: over-fetched candidate labels per query.
@@ -658,44 +867,23 @@ class TpuHnsw(_SlotStoreIndex):
         store = self.store
         lease = store.begin_search()   # slots stable until resolve
         try:
-            with store.device_lock:    # vecs/sqnorm are donatable
+            with TRACER.start_child("index.lock_wait"):
+                store.device_lock.acquire()    # vecs/sqnorm are donatable
+            try:
                 dists, out_slots = self._final_rerank(
                     qpad, jnp.asarray(cand), topk
                 )
+            finally:
+                store.device_lock.release()
         except Exception:
             lease.release()
             raise
         from dingo_tpu.ops.topk import begin_host_fetch
 
-        fetch = begin_host_fetch(dists, out_slots)
-        from dingo_tpu.obs.heat import HEAT, heat_enabled
-
-        heat_on = heat_enabled()
-        if heat_on:
-            HEAT.register_layout(self.id, "slot", self._heat_layout)
-
-        def resolve() -> List[SearchResult]:
-            try:
-                dists_h, slots_h = jax.device_get(fetch)
-                if heat_on:
-                    HEAT.observe(self.id, "slot", slots_h[:b])
-                ids = store.ids_of_slots(slots_h[:b])
-                from dingo_tpu.obs.quality import QUALITY
-
-                # bucket = the LADDER value (same attribution as the
-                # device path): raw client-pinned ef would mint unbounded
-                # label cardinality and split one setting across names
-                QUALITY.observe_search(
-                    self, queries, topk, ids, dists_h[:b],
-                    bucket=f"ef={self._beam_width(ef, topk)}",
-                    filter_spec=filter_spec,
-                )
-                return [strip_invalid(i, d)
-                        for i, d in zip(ids, dists_h[:b])]
-            finally:
-                lease.release()
-
-        return resolve
+        return begin_host_fetch(dists, out_slots), self._finisher(
+            lease, queries, b, topk, filter_spec,
+            self._beam_width(ef, topk),
+        )
 
     def _final_rerank(self, qpad, cand_slots, topk: int):
         """Exact device rerank of a candidate set (ops/rerank.py); caller
@@ -735,17 +923,27 @@ class TpuHnsw(_SlotStoreIndex):
             metric=metric,
         )
 
-    def _note_walk_stats(self, hops, vcount, occ, cap, beam) -> None:
+    def _note_walk_stats(self, hops, vcount, occ, beam, live,
+                         round_slots) -> None:
         """Fold one resolved device walk into the metrics plane (called
-        from resolve(): the hot path never synchronizes for stats)."""
-        METRICS.gauge("hnsw.mean_hops", region_id=self.id).set(
-            float(np.mean(hops)) if len(hops) else 0.0
-        )
+        from resolve(): the hot path never synchronizes for stats).
+        `live` = the index's live rows at dispatch, `round_slots` = the
+        candidate slots one round gathers and scores."""
+        if not len(hops):
+            return
+        hops_mean = float(np.mean(hops))
+        METRICS.gauge("hnsw.mean_hops", region_id=self.id).set(hops_mean)
+        # over LIVE rows, not the slot store's capacity: the reading does
+        # not halve when the store doubles
         METRICS.gauge("hnsw.visited_fraction", region_id=self.id).set(
-            float(np.mean(vcount)) / max(1, cap) if len(vcount) else 0.0
+            float(np.mean(vcount)) / max(1, live)
         )
         METRICS.gauge("hnsw.beam_occupancy", region_id=self.id).set(
-            float(np.mean(occ)) / max(1, beam) if len(occ) else 0.0
+            float(np.mean(occ)) / max(1, beam)
+        )
+        # what the implementation gathers, beside the rows it visits
+        METRICS.gauge("hnsw.gathered_rows_per_query", region_id=self.id).set(
+            hops_mean * round_slots
         )
 
     def _heat_layout(self) -> dict:
@@ -783,7 +981,8 @@ class TpuHnsw(_SlotStoreIndex):
         return len(self.store)
 
     def get_deleted_count(self) -> int:
-        return int(_lib().hnsw_deleted_count(self._graph))
+        return int(_lib().hnsw_deleted_count(self._graph)) \
+            + self._deleted_slots
 
     def get_memory_size(self) -> int:
         return self.store.memory_size() + int(_lib().hnsw_memory(self._graph))
@@ -796,29 +995,65 @@ class TpuHnsw(_SlotStoreIndex):
         total = deleted + self.get_count()
         return total > 0 and deleted * 2 > total
 
-    def _save_meta(self) -> dict:
+    def _save_meta(self, graph: Optional[dict] = None) -> dict:
         meta = super()._save_meta()
-        meta["hnsw_graph"] = {
+        meta["hnsw_graph"] = graph or {
             "deg": self._graph_deg,
             "nodes": int(_lib().hnsw_total_count(self._graph)),
             "entry_label": int(_lib().hnsw_entry_label(self._graph)),
         }
         return meta
 
+    def _device_graph_snapshot(self, adj_h: np.ndarray):
+        """The device adjacency as it is written to disk: (labels [n],
+        adjacency [n, deg] in the node space of the live rows in slot
+        order, graph meta). load() puts the rows back in that order, so
+        node space is the loaded store's slot space; an edge to a
+        tombstoned slot is dropped."""
+        store = self.store
+        live = store.ids_by_slot >= 0
+        rank = np.cumsum(live, dtype=np.int64).astype(np.int32) - 1
+        rows = adj_h[live]
+        safe = np.maximum(rows, 0)
+        nodes = np.where((rows >= 0) & live[safe], rank[safe], np.int32(-1))
+        entry = self._entry_slot
+        entry_live = 0 <= entry < len(live) and bool(live[entry])
+        return store.ids_by_slot[live], nodes, {
+            "deg": self._graph_deg,
+            "nodes": int(live.sum()),
+            "entry_label": int(store.ids_by_slot[entry]) if entry_live
+            else -1,
+            "entry_slot": int(rank[entry]) if entry_live else -1,
+            "device_graph": True,
+        }
+
     def save(self, path: str) -> None:
-        self._ensure_native_graph()
+        """TPU arm: rows + the device adjacency itself + entry; no native
+        blob is made or written. CPU arm: rows + native blob + its level-0
+        export, after the back-fill a device-built graph owes it. The
+        copy off the device holds ``store.device_lock`` (one hold, so rows
+        and adjacency are of one moment), the file writes do not."""
+        device = self._native_pending and self._tpu_arm()
+        if not device:
+            self._ensure_native_graph()
         os.makedirs(path, exist_ok=True)
-        if self._precision == "sq8" and self.store.sq_params is not None:
-            snap = self.store.codes_to_host()
+        store = self.store
+        sq = self._precision == "sq8" and store.sq_params is not None
+        adj_h = dropped = None
+        with store.device_lock:
+            snap = store.codes_to_host() if sq else store.to_host()
+            if device:
+                adj_h = np.asarray(store.adj)
+                dropped, self._dropped_d = self._dropped_d, None
+        if sq:
             np.savez(
                 os.path.join(path, "hnsw_vectors.npz"),
                 ids=snap["ids"],
                 codes=snap["codes"],
-                sq_vmin=self.store.sq_params.vmin,
-                sq_scale=self.store.sq_params.scale,
+                sq_vmin=store.sq_params.vmin,
+                sq_scale=store.sq_params.scale,
             )
         else:
-            snap = self.store.to_host()
             np.savez(
                 os.path.join(path, "hnsw_vectors.npz"),
                 ids=snap["ids"],
@@ -826,21 +1061,38 @@ class TpuHnsw(_SlotStoreIndex):
                 # lossless)
                 vectors=np.asarray(snap["vectors"], np.float32),
             )
-        size = _lib().hnsw_save_size(self._graph)
-        buf = np.empty(size, np.uint8)
-        written = _lib().hnsw_save(
-            self._graph, buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8))
-        )
-        with open(os.path.join(path, "hnsw_graph.bin"), "wb") as f:
-            f.write(buf[:written].tobytes())
-        # device-graph adjacency rides the snapshot (node space + labels)
-        # so load() serves device searches without a native re-export
-        labels, adj = self._export_level0()
+        blob_path = os.path.join(path, "hnsw_graph.bin")
+        graph_meta = None
+        if device:
+            labels, adj, graph_meta = self._device_graph_snapshot(adj_h)
+            if os.path.exists(blob_path):
+                os.remove(blob_path)   # an older CPU-arm snapshot's
+            if self._adj_ledger_stale:
+                # TPU-arm writes since the last seeding: the ledger takes
+                # the host copy this save made anyway
+                self._adj_ledger_stale = False
+                self._seed_adjacency_ledger(adj_h)
+            if dropped is not None:
+                METRICS.counter(
+                    "build.reverse_dropped", region_id=self.id
+                ).add(int(dropped))
+        else:
+            size = _lib().hnsw_save_size(self._graph)
+            buf = np.empty(size, np.uint8)
+            written = _lib().hnsw_save(
+                self._graph,
+                buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+            )
+            with open(blob_path, "wb") as f:
+                f.write(buf[:written].tobytes())
+            labels, adj = self._export_level0()
+        # the adjacency rides the snapshot (node space + labels) so load()
+        # serves device searches without a rebuild or a native re-export
         np.savez(
             os.path.join(path, "hnsw_adj.npz"), labels=labels, adj=adj
         )
         with open(os.path.join(path, "meta.json"), "w") as f:
-            json.dump(self._save_meta(), f)
+            json.dump(self._save_meta(graph_meta), f)
 
     def load(self, path: str) -> None:
         with open(os.path.join(path, "meta.json")) as f:
@@ -849,7 +1101,8 @@ class TpuHnsw(_SlotStoreIndex):
         data = np.load(os.path.join(path, "hnsw_vectors.npz"))
         self.store = _new_tier_store(
             self._precision, self.dimension, self.parameter,
-            capacity=max(len(data["ids"]), 1),
+            capacity=max(len(data["ids"]), int(self.parameter.max_elements),
+                         1),
         )
         self._init_precision(self.parameter, tier=self._precision)
         if "codes" in data.files:
@@ -867,20 +1120,31 @@ class TpuHnsw(_SlotStoreIndex):
         elif len(data["ids"]):
             self.store.put(np.asarray(data["ids"], np.int64),
                            data["vectors"])
-        blob = np.fromfile(os.path.join(path, "hnsw_graph.bin"), np.uint8)
-        new_graph = _lib().hnsw_load(
-            blob.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)), len(blob)
-        )
-        if not new_graph:
-            raise InvalidParameter("bad hnsw graph blob")
+        graph_meta = meta.get("hnsw_graph") or {}
+        device = bool(graph_meta.get("device_graph"))
+        if device:
+            # a device-graph snapshot carries no native blob: the native
+            # graph starts empty and is back-filled only by a CPU-arm use
+            new_graph = self._new_native_graph()
+        else:
+            blob = np.fromfile(os.path.join(path, "hnsw_graph.bin"),
+                               np.uint8)
+            new_graph = _lib().hnsw_load(
+                blob.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+                len(blob),
+            )
+            if not new_graph:
+                raise InvalidParameter("bad hnsw graph blob")
         _lib().hnsw_free(self._graph)
         self._graph = new_graph
         self._filter_cache.clear()
         self._graph_key = None
         self._entry_slot = -1
+        self._deleted_slots = 0
+        self._dropped_d = None
         self._native_pending = False   # the loaded blob IS the graph
         adj_path = os.path.join(path, "hnsw_adj.npz")
-        graph_meta = meta.get("hnsw_graph")
+        installed = False
         if graph_meta and os.path.exists(adj_path) \
                 and int(graph_meta.get("deg", -1)) == self._graph_deg:
             snap = np.load(adj_path)
@@ -894,6 +1158,13 @@ class TpuHnsw(_SlotStoreIndex):
                     int(_lib().hnsw_graph_version(self._graph)),
                     self.store.mutation_version,
                 )
+            installed = True
+        if device:
+            if not installed:
+                raise InvalidParameter(
+                    "device-graph snapshot without a usable adjacency"
+                )
+            self._native_pending = True    # the adjacency IS the graph
         self.apply_log_id = meta["apply_log_id"]
         self.write_count_since_save = 0
         self._integrity_on_restore(meta)

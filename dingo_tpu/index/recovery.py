@@ -217,9 +217,12 @@ class DeviceRecoveryPlane:
                 store.vecs_blk = None
                 store.bsq_blk = None
                 freed = True
-            if getattr(store, "adj", None) is not None:
-                # HNSW re-exports adjacency lazily on the next device
-                # search; until then the host beam fallback serves
+            if getattr(store, "adj", None) is not None \
+                    and not getattr(idx, "_native_pending", False):
+                # a MIRROR of the native graph: HNSW re-exports it lazily
+                # on the next device search; until then the host beam
+                # fallback serves. A device-owned adjacency (TPU arm) is
+                # the graph itself and stays
                 store.adj = None
                 store.graph_deg = 0
                 if hasattr(idx, "_graph_key"):

@@ -7,13 +7,22 @@ adjacency — a dense ``[capacity, deg]`` int32 array in slot space
 (SlotStore.adj) — so every step is regular gather + matmul + masked
 top-k work the MXU/VPU are built for:
 
-  frontier gather    one ``jnp.take`` on the adjacency: [b, beam] beam
-                     slots -> [b, beam*deg] candidate slots
-  candidate scores   one ``[b, beam*deg] x d`` einsum against the
-                     SlotStore rows (bf16 pairs down for the bf16 tier,
-                     sq8 decodes on the fly — the PR 4 precision tiers)
+  frontier gather    each beam entry is expanded ONCE: a round takes the
+                     best ``frontier_width(beam)`` entries not expanded
+                     yet (an expanded entry's neighbours are all visited
+                     already, so expanding it again gathers rows for
+                     nothing) and one ``jnp.take`` on the adjacency turns
+                     [b, width] slots into [b, width*deg] candidate slots
+  candidate scores   the survivors of the visited mask and the dedup are
+                     sorted to the front and the wave is cut in half
+                     (``CAND_SHARE``); one ``[b, width*deg/2] x d`` einsum
+                     against the SlotStore rows (bf16 pairs down for the
+                     bf16 tier, sq8 decodes on the fly — the PR 4
+                     precision tiers)
   visited set        a per-query PACKED bitmask over capacity
-                     ([b, capacity/32] uint32, 1 bit per slot). Marking
+                     ([b, capacity/32] uint32, 1 bit per slot), seeded
+                     with the store-invalid slots so that one lookup
+                     gates "seen" and "deleted" alike. Marking
                      uses scatter-ADD, which is a correct bitwise OR
                      here: a slot passes the not-yet-visited mask at
                      most once over the whole walk and in-batch
@@ -25,10 +34,11 @@ top-k work the MXU/VPU are built for:
   beam update        masked ``lax.top_k`` over old beam + candidates
 
 Termination: a fixed iteration cap (``hnsw.max_iters``) plus an
-early-exit-by-convergence flag — a query goes inactive once an
-expansion round admits no new candidate into its beam, and the
-``lax.while_loop`` stops when every query is inactive. Inactive queries
-ride along (lockstep has no partial shapes) but cannot change state.
+early-exit-by-convergence flag — a query goes inactive once every live
+entry of its beam has been expanded (a round that admits no new
+candidate leaves none to expand), and the ``lax.while_loop`` stops when
+every query is inactive. Inactive queries ride along (lockstep has no
+partial shapes) but cannot change state: their frontier is empty.
 
 Filter pushdown (the PR 3 filter-mask cache, applied device-side): the
 kernel keeps TWO candidate lists. The ROUTING beam admits any
@@ -50,6 +60,32 @@ import jax.numpy as jnp
 from jax import lax
 
 from dingo_tpu.obs.sentinel import sentinel_jit
+
+#: beam entries expanded per round (the CAGRA "search width"): bounds the
+#: round's candidate wave at FRONTIER * deg slots whatever the beam. On the
+#: v5e a round costs by the slot (the visited-bit lookup is a scalar
+#: gather, ~12 ns each), not by the byte: 32 reads fewer slots in all
+#: than 64 or 128 at the same recall (PERF.md, PR 31)
+FRONTIER = 32
+
+#: candidate slots a round scores, as a share of the wave: after the
+#: visited mask and the dedup most slots are holes (a tenth are new), so
+#: the survivors are sorted to the front and the wave is cut in half
+#: before its rows are gathered. A survivor past the cut is dropped (its
+#: parent is not expanded again): it needs over half a wave of NEW
+#: distinct rows, which only a walk's first rounds can come near
+CAND_SHARE = 2
+
+
+def frontier_width(beam: int) -> int:
+    """Beam entries one round expands (static, from the beam bucket)."""
+    return max(1, min(int(beam), FRONTIER))
+
+
+def round_slots(beam: int, deg: int) -> int:
+    """Candidate slots one round reads the adjacency for (what
+    ``hnsw.gathered_rows_per_query`` counts a round as)."""
+    return frontier_width(beam) * int(deg)
 
 
 def _candidate_scores(vecs, sqnorm, qd, slots, metric, sq, vmin, scale):
@@ -108,15 +144,23 @@ def beam_search(adj, vecs, sqnorm, valid, fmask, queries, entry, vmin,
     entry = entry.astype(jnp.int32)
     entry_ok = entry >= 0
     e_safe = jnp.maximum(entry, 0)
-    visited = jnp.zeros((b, nwords), jnp.uint32)
+    # store-invalid slots start out "visited": ONE bit lookup a slot then
+    # gates both (a second scalar gather, of `valid`, cost a round as much
+    # as the lookup itself)
+    inv = jnp.pad(~valid, (0, nwords * 32 - cap)).reshape(nwords, 32)
+    inv_words = jnp.sum(
+        inv.astype(jnp.uint32) << jnp.arange(32, dtype=jnp.uint32)[None, :],
+        axis=1, dtype=jnp.uint32,
+    )
     ebit = jnp.where(
         entry_ok,
         jnp.uint32(1) << (e_safe.astype(jnp.uint32) & 31),
         jnp.uint32(0),
     )
-    visited = visited.at[
-        jnp.arange(b), jnp.broadcast_to(e_safe >> 5, (b,))
-    ].add(jnp.broadcast_to(ebit, (b,)))
+    visited = jnp.broadcast_to(
+        inv_words | jnp.where(jnp.arange(nwords) == (e_safe >> 5), ebit, 0),
+        (b, nwords),
+    )
 
     # seed: the entry always anchors the ROUTING beam (even when it is
     # tombstoned or filtered out — its neighbors must still be reachable;
@@ -138,26 +182,43 @@ def beam_search(adj, vecs, sqnorm, valid, fmask, queries, entry, vmin,
     )
     active = jnp.broadcast_to(entry_ok, (b,))
     hops = jnp.zeros((b,), jnp.int32)
+    width = frontier_width(beam)
+    keep = max(1, width * deg // CAND_SHARE)
+    bexp = jnp.zeros((b, beam), bool)
+    floor = jnp.finfo(jnp.float32).min
 
     def cond(st):
-        it, active = st[0], st[6]
+        it, active = st[0], st[7]
         return (it < max_iters) & jnp.any(active)
 
     def body(st):
-        it, bslots, bscores, rslots, rscores, visited, active, hops = st
+        (it, bslots, bscores, bexp, rslots, rscores, visited, active,
+         hops) = st
         hops = hops + active.astype(jnp.int32)
-        # 1) frontier gather: every beam entry expands one hop
-        safe_b = jnp.where(bslots >= 0, bslots, 0)
-        neigh = jnp.take(adj, safe_b, axis=0)            # [b, beam, deg]
-        neigh = jnp.where((bslots >= 0)[:, :, None], neigh, -1)
-        neigh = neigh.reshape(b, beam * deg)
-        # 2) drop holes, already-visited and store-invalid candidates
+        # 1) frontier gather: the best `width` unexpanded beam entries
+        #    expand one hop (the floor keeps a tombstoned entry seed,
+        #    scored -inf, expandable: its neighbours must stay reachable)
+        fkey = jnp.where(
+            bexp | (bslots < 0), -jnp.inf, jnp.maximum(bscores, floor)
+        )
+        fv, fi = lax.top_k(fkey, width)
+        fslots = jnp.where(
+            jnp.isneginf(fv), -1, jnp.take_along_axis(bslots, fi, axis=1)
+        )
+        # top_k's -inf picks are holes or expanded already: marking them
+        # changes nothing
+        bexp = bexp.at[rowix, fi].set(True)
+        neigh = jnp.take(adj, jnp.maximum(fslots, 0), axis=0)
+        neigh = jnp.where((fslots >= 0)[:, :, None], neigh, -1)
+        neigh = neigh.reshape(b, width * deg)            # [b, width*deg]
+        # 2) drop holes, already-visited and store-invalid candidates (the
+        #    invalid ones were marked visited before the walk)
         ok = neigh >= 0
         safe_n = jnp.where(ok, neigh, 0)
         words = safe_n >> 5
         bits = (safe_n & 31).astype(jnp.uint32)
         seen = (jnp.take_along_axis(visited, words, axis=1) >> bits) & 1
-        new = ok & (seen == 0) & jnp.take(valid, safe_n)
+        new = ok & (seen == 0)
         # 3) in-batch dedup: sort by slot (cap sorts holes last), mask
         #    runs — duplicates of one slot carry identical scores, so
         #    keeping the first survivor is exact
@@ -166,7 +227,10 @@ def beam_search(adj, vecs, sqnorm, valid, fmask, queries, entry, vmin,
         dup = jnp.concatenate(
             [jnp.zeros((b, 1), bool), cs[:, 1:] == cs[:, :-1]], axis=1
         )
-        cand = jnp.where((cs < cap) & ~dup, cs, -1)
+        # survivors first (a second sort), then the wave is cut: the row
+        # gather, the einsum and both merges run on `keep` slots
+        cs = jnp.sort(jnp.where(dup, cap, cs), axis=1)[:, :keep]
+        cand = jnp.where(cs < cap, cs, -1)
         # 4) one einsum scores the whole candidate wave
         cscores = score(cand)
         # 5) mark survivors visited (scatter-add == OR: each slot
@@ -187,7 +251,10 @@ def beam_search(adj, vecs, sqnorm, valid, fmask, queries, entry, vmin,
             jnp.concatenate([bslots, cand], axis=1), mi, axis=1
         )
         mslots = jnp.where(jnp.isneginf(mv), -1, mslots)
-        entered = jnp.any((mi >= beam) & ~jnp.isneginf(mv), axis=1)
+        mexp = jnp.take_along_axis(
+            jnp.concatenate([bexp, jnp.zeros(cand.shape, bool)], axis=1),
+            mi, axis=1,
+        )
         # 7) result merge: masked candidates never enter this beam
         relig = (cand >= 0) & jnp.take(res_ok, csafe)
         rv, ri = lax.top_k(
@@ -200,17 +267,20 @@ def beam_search(adj, vecs, sqnorm, valid, fmask, queries, entry, vmin,
             jnp.concatenate([rslots, cand], axis=1), ri, axis=1
         )
         nrslots = jnp.where(jnp.isneginf(rv), -1, nrslots)
-        # 8) convergence: a query with no beam admission is done — every
-        #    reachable unvisited node is now worse than its whole beam
-        active = active & entered
-        return (it + 1, mslots, mv, nrslots, rv, visited, active, hops)
+        # 8) convergence: a query whose live beam entries are all
+        #    expanded is done — every reachable unvisited node is worse
+        #    than its whole beam
+        active = active & jnp.any(~mexp & (mslots >= 0), axis=1)
+        return (it + 1, mslots, mv, mexp, nrslots, rv, visited, active,
+                hops)
 
-    st = (jnp.int32(0), bslots, bscores, rslots, rscores, visited, active,
-          hops)
+    st = (jnp.int32(0), bslots, bscores, bexp, rslots, rscores, visited,
+          active, hops)
     st = lax.while_loop(cond, body, st)
-    rslots, visited, hops = st[3], st[5], st[7]
-    vcount = jnp.sum(
-        lax.population_count(visited), axis=1
+    rslots, visited, hops = st[4], st[6], st[8]
+    vcount = (
+        jnp.sum(lax.population_count(visited), axis=1)
+        - jnp.sum(lax.population_count(inv_words))
     ).astype(jnp.int32)
     occ = jnp.sum((rslots >= 0).astype(jnp.int32), axis=1)
     return rslots, hops, vcount, occ

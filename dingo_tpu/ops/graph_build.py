@@ -37,6 +37,11 @@ insert batch at a time:
                         (``build.reverse_dropped``) — the next batch's
                         walk rediscovers those neighborhoods.
 
+Live insertion: ``insert_batch`` reads the adjacency as it stands, so it
+serves the bulk build (from empty) and the TPU arm's ``upsert`` into a
+graph that is being searched (index/hnsw.py ``_device_insert``) alike;
+``ladder_batches`` cuts either's rows into the same pow2 ladder.
+
 Shape discipline: the batch is pow2-padded with -1 slots and the caller
 reserves store capacity up front, so a full build ladder compiles a
 handful of programs and steady state (batch 2..N) compiles ZERO — the
@@ -76,6 +81,22 @@ def _next_pow2(n: int) -> int:
     while p < n:
         p <<= 1
     return p
+
+
+def ladder_batches(slots: np.ndarray, batch_rows: int):
+    """Split slots into the insert ladder: full batches of `batch_rows`
+    (pow2, at least 8), then the remainder padded with -1 to its own pow2
+    — a handful of compiled programs whatever the write sizes."""
+    bb = _next_pow2(max(8, int(batch_rows)))
+    slots = np.asarray(slots, np.int32)
+    for s in range(0, len(slots), bb):
+        chunk = slots[s:s + bb]
+        size = bb if len(chunk) == bb else max(8, _next_pow2(len(chunk)))
+        if len(chunk) < size:
+            chunk = np.concatenate(
+                [chunk, np.full(size - len(chunk), -1, np.int32)]
+            )
+        yield chunk
 
 
 def _decoded_rows(vecs, slots, sq, vmin, scale):
@@ -171,6 +192,28 @@ def insert_batch(adj, vecs, sqnorm, valid, batch_slots, entry, vmin,
     s_pc = _scores_from_rows(crows, csq, qd, metric)
     s_pc = jnp.where(cand >= 0, s_pc, -jnp.inf)
 
+    # candidate-pair dots once ([b, C, C], one batched matmul): a round of
+    # the selection then reads a column of it instead of every candidate
+    # row again (deg rounds over [b, C, d] were 12 % of an insert on the
+    # v5e, PERF.md PR 31)
+    cdots = jnp.einsum(
+        "bid,bjd->bij", crows, crows,
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+    def kept_scores(j):
+        """[b, C] scores of every candidate against the kept one j [b, 1]
+        (the formulas of ops/rerank._scores_from_rows with the kept row as
+        the query; these only prune, every installed edge was scored by
+        _scores_from_rows itself)."""
+        dots = jnp.take_along_axis(cdots, j[:, None, :], axis=2)[:, :, 0]
+        if metric is Metric.L2:
+            return -(jnp.take_along_axis(csq, j, axis=1) - 2.0 * dots + csq)
+        if metric is Metric.COSINE:
+            return dots * jax.lax.rsqrt(jnp.maximum(csq, 1e-30))
+        return dots
+
     def select(i, st):
         selected, alive = st
         masked = jnp.where(alive, s_pc, -jnp.inf)
@@ -179,10 +222,7 @@ def insert_batch(adj, vecs, sqnorm, valid, batch_slots, entry, vmin,
         pick = jnp.take_along_axis(cand, j, axis=1)[:, 0]
         selected = selected.at[:, i].set(jnp.where(ok, pick, -1))
         alive = alive & (jnp.arange(nc)[None, :] != j)
-        kept = jnp.take_along_axis(crows, j[:, :, None], axis=1)[:, 0, :]
-        s_ck = _scores_from_rows(
-            crows, csq, kept.astype(jnp.float32), metric
-        )
+        s_ck = kept_scores(j)
         # RNG* occlusion: c is dominated once the kept neighbor explains
         # it better than the inserted point does
         alive = alive & ~(ok[:, None] & (alpha_sq * s_ck > s_pc))
@@ -246,7 +286,12 @@ def insert_batch(adj, vecs, sqnorm, valid, batch_slots, entry, vmin,
             jnp.take(srcs, wclip), -1,
         )
         cand2 = jnp.concatenate([old, inc], axis=1)           # [rc, deg+w]
-        cand2 = jnp.where(cand2 == d_e[:, None], -1, cand2)
+        # a live graph can hold edges to rows deleted since (tombstones):
+        # a re-pruned row sheds them
+        cand2 = jnp.where(
+            (cand2 == d_e[:, None])
+            | ~jnp.take(valid, jnp.maximum(cand2, 0)), -1, cand2
+        )
         c2 = jnp.where(cand2 >= 0, cand2, cap)
         c2 = jnp.sort(c2, axis=1)
         dup2 = jnp.concatenate(
@@ -338,11 +383,7 @@ class BulkGraphBuilder:
             self._pend = self._pend[self.batch_rows:]
 
     def _flush(self, slots: np.ndarray) -> None:
-        bb = self.batch_rows
-        if len(slots) < bb:
-            slots = np.concatenate(
-                [slots, np.full(bb - len(slots), -1, np.int32)]
-            )
+        """Insert one ladder batch (pow2 long, -1 padded)."""
         store = self.store
         with store.device_lock:
             self._ensure_adj()
@@ -372,9 +413,9 @@ class BulkGraphBuilder:
         sync — per-batch state (entry, drop counter) stays device-side."""
         assert not self._done, "builder already finished"
         self._done = True
-        if len(self._pend):
-            self._flush(self._pend)
-            self._pend = np.empty((0,), np.int32)
+        for chunk in ladder_batches(self._pend, self.batch_rows):
+            self._flush(chunk)
+        self._pend = np.empty((0,), np.int32)
         self._ensure_adj()    # a zero-row build still yields a mirror
         entry, dropped = jax.device_get((self._entry_d, self._dropped_d))
         METRICS.counter(

@@ -55,6 +55,7 @@ def index_parameter_to_pb(p: Optional[IndexParameter]) -> pb.VectorIndexParamete
     out.default_nprobe = p.default_nprobe
     out.efconstruction = p.efconstruction
     out.nlinks = p.nlinks
+    out.max_elements = p.max_elements
     out.host_vectors = p.host_vectors
     out.scalar_speedup_keys.extend(p.scalar_speedup_keys)
     out.precision = p.precision
@@ -74,6 +75,7 @@ def index_parameter_from_pb(m: pb.VectorIndexParameter) -> Optional[IndexParamet
         default_nprobe=m.default_nprobe or 80,
         efconstruction=m.efconstruction or 200,
         nlinks=m.nlinks or 32,
+        max_elements=m.max_elements,
         host_vectors=m.host_vectors,
         scalar_speedup_keys=tuple(m.scalar_speedup_keys),
         precision=m.precision,

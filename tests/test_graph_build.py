@@ -118,7 +118,10 @@ def test_adjacency_byte_stable_under_fixed_seed(corpus):
 def test_incremental_insert_parity_after_bulk_build(corpus):
     """First host-path write back-fills the native graph from the store
     (O(chunk) replays), after which ordinary incremental upsert/delete
-    and both search paths behave exactly as on a host-built index."""
+    and both search paths behave exactly as on a host-built index.
+    This is the CPU arm (`hnsw.device_search` is off at the write): with
+    both gates on a write goes into the device adjacency and nothing is
+    back-filled (tests/test_hnsw_one_graph.py)."""
     ids, x, q = corpus
     rng = np.random.default_rng(5)
     idx = bulk_build(64, ids, x)

@@ -99,7 +99,10 @@ def test_device_final_order_matches_host_on_agreeing_sets(corpus):
 
 def test_incremental_upsert_delete_adjacency_sync(corpus):
     """Writes dirty the mirror; the next device search re-exports and the
-    walk sees the new/removed rows."""
+    walk sees the new/removed rows. The CPU arm's mirror
+    (`hnsw.device_build` is off here, so the native graph takes the
+    writes): the TPU arm re-exports nothing
+    (tests/test_hnsw_one_graph.py)."""
     ids, x, q = corpus
     idx = new_index(32, hnsw_param())
     idx.add(ids[:2000], x[:2000])

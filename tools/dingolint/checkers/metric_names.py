@@ -98,9 +98,15 @@ FAMILY_NAMES = {
         "hnsw.host_searches",       # native C++ beam fallback searches
         "hnsw.adjacency_rebuilds",  # level-0 exports into the device
                                     # mirror (writes dirty it)
+        "hnsw.native_adds",         # rows fed to the native graph (CPU-
+                                    # arm writes, back-fills; 0 on the
+                                    # TPU arm)
         "hnsw.graph_nodes",         # exported nodes incl. tombstones
         "hnsw.mean_hops",           # beam-expansion rounds per walk
-        "hnsw.visited_fraction",    # visited-bitmask population / capacity
+        "hnsw.visited_fraction",    # visited-bitmask population / LIVE
+                                    # rows
+        "hnsw.gathered_rows_per_query",  # rounds x candidate slots a
+                                    # round: rows the walk gathers
         "hnsw.beam_occupancy",      # live result-beam entries / beam width
         "hnsw.filter_mask_hits",    # (fingerprint, store version) cache
         "hnsw.filter_mask_misses",

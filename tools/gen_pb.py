@@ -218,6 +218,9 @@ NEW_FIELDS = {
     # "" (conf default) / "fp32" / "bf16" / "sq8"
     "VectorIndexParameter": [
         ("precision", 13, T.TYPE_STRING, None, False),
+        # HNSW: rows the region is created for (the upstream's hnsw
+        # max_elements): slot store + device adjacency sized at creation
+        ("max_elements", 14, T.TYPE_INT64, None, False),
     ],
     # heartbeat transport for the metrics payload
     "StoreHeartbeatRequest": [
