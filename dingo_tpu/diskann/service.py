@@ -44,9 +44,7 @@ class DiskAnnService:
         if core is None:
             return resp
         try:
-            vectors = np.asarray(
-                [list(v.values) for v in req.vectors], np.float32
-            )
+            vectors = convert.float_rows_from_pb(req.vectors)
             resp.already_recv_vector_count = core.push_data(
                 np.asarray(list(req.vector_ids), np.int64),
                 vectors, req.has_more,
@@ -91,9 +89,7 @@ class DiskAnnService:
         if core is None:
             return resp
         try:
-            queries = np.asarray(
-                [list(v.values) for v in req.vectors], np.float32
-            )
+            queries = convert.float_rows_from_pb(req.vectors)
             rows = core.search(queries, int(req.top_n or 10),
                                nprobe=int(req.nprobe) or None)
         except (DiskAnnError, InvalidParameter, ValueError) as e:

@@ -140,6 +140,10 @@ class IndexService:
         self.node = node
         self._coalescer = None
         self._coalescer_lock = threading.Lock()
+        # rows by the path that decoded them (convert.float_rows_from_pb),
+        # from the start: a path that never ran reads 0, not "no such series"
+        for name in ("decode_wire_rows", "decode_boxed_rows"):
+            METRICS.counter("service." + name).add(0)
 
     def _get_coalescer(self):
         from dingo_tpu.common.coalescer import SearchCoalescer
@@ -220,7 +224,7 @@ class IndexService:
         lat = METRICS.latency("vector_search", region.id)
         t0 = time.perf_counter_ns()
         try:
-            # service.decode: the request's boxed floats to arrays
+            # service.decode: the request's float rows to one array
             with TRACER.start_child("service.decode"):
                 binary = convert.is_binary_parameter(
                     region.definition.index_parameter
@@ -383,8 +387,8 @@ class IndexService:
                 for v in req_vectors
             ])
         else:
-            vectors = np.asarray(
-                [list(v.vector.values) for v in req_vectors], np.float32
+            vectors = convert.float_rows_from_pb(
+                [v.vector for v in req_vectors]
             )
         scalars = [convert.scalar_from_pb(v.scalar_data) for v in req_vectors]
         table_values = None
@@ -401,7 +405,7 @@ class IndexService:
         if region is None:
             return resp
         try:
-            # service.decode: the rows' boxed floats to arrays
+            # service.decode: the rows' floats, ids and scalars to arrays
             with TRACER.start_child("service.decode"):
                 ids, vectors, scalars, table_values = \
                     self._vector_batch_from_pb(region, req.vectors)
