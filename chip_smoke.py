@@ -149,8 +149,8 @@ def _wait_for(what: str, probe, timeout_s: float, every_s: float = 0.5):
 
 # -------------------------------------------------------------------- data
 def make_corpus(rows: int, seed: int):
-    """Clustered mixture as bench.py builds it: iid gaussians have
-    near-orthogonal neighbours and defeat any IVF."""
+    """Clustered mixture: iid gaussians have near-orthogonal neighbours
+    and defeat any IVF."""
     import numpy as np
 
     rng = np.random.default_rng(seed)

@@ -266,40 +266,12 @@ FLAGS.define("ivf_prune_scan", "auto", mutable=True,
                    "blocked metadata. 'auto' (default) = on (the kernels "
                    "fall back to the plain fused scan when the dimension "
                    "doesn't block); False forces the non-pruning kernels")
-FLAGS.define("hnsw_device_search", "auto", mutable=True,
-             help_="route HNSW searches through the device-resident graph "
-                   "tier: a batched lockstep beam search over the flattened "
-                   "level-0 adjacency (ops/beam.py), quantized-tier compute "
-                   "+ exact device rerank of the final beam. 'auto' "
-                   "(default) enables it on TPU only — the XLA walk wins "
-                   "when hundreds of queries amortize each gather/einsum "
-                   "round; the host C++ beam stays the CPU arm and the "
-                   "parity oracle. True/False force")
-FLAGS.define("hnsw_device_beam", 0, mutable=True,
-             help_="fixed candidate-beam width for the device HNSW walk; "
-                   "0 (default) derives it from the request ef via the "
-                   "{1,1.5}x-pow2 shape-bucket ladder so steady-state "
-                   "serving reuses a handful of compiled programs")
 FLAGS.define("hnsw_max_iters", 48, mutable=True,
              help_="hard cap on lockstep beam-expansion rounds of the "
                    "device HNSW walk (one round = expand every beam entry "
                    "one hop). The walk exits earlier once every query's "
                    "beam has converged; the cap bounds worst-case latency "
                    "on adversarial graphs")
-FLAGS.define("hnsw_device_build", "auto", mutable=True,
-             help_="build HNSW graphs on the device "
-                   "(ops/graph_build.py), bulk rebuilds and, with "
-                   "hnsw_device_search also on, every upsert into the "
-                   "live graph (the device adjacency is then the one "
-                   "graph): pow2 insert batches walk the adjacency as it "
-                   "stands with the lockstep beam "
-                   "kernel, occlusion-prune neighbors as masked top-k "
-                   "over the candidate score matrix, and install reverse "
-                   "edges with degree-clamped re-pruning; the native "
-                   "graph back-fills only on a CPU-arm use. "
-                   "'auto' (default) = TPU-only — MXU batch throughput "
-                   "is the whole point; the host insert loop stays the "
-                   "CPU arm and the parity oracle. True/False force")
 FLAGS.define("hnsw_build_batch", 256, mutable=True,
              help_="rows per device bulk-build insert batch (rounded up "
                    "to a power of two; the final partial batch pads with "
@@ -461,7 +433,7 @@ FLAGS.define("pipeline_enabled", "auto", mutable=True,
                    "TPU-only (on CPU the backend is synchronous so overlap "
                    "buys nothing and the extra thread hop costs latency). "
                    "True/False force; same tri-state crossover discipline "
-                   "as hnsw_device_search")
+                   "as use_pallas_ivf_search")
 FLAGS.define("pipeline_depth", 2, mutable=True,
              help_="staging-ring depth per coalescer key (pow2-ladder "
                    "shaped host buffers): batch N+1's query upload can "
@@ -653,8 +625,8 @@ def pallas_interpret() -> bool:
 
 def require_device() -> Dict[str, Any]:
     """Initialise the JAX backend of a process that owns the device (the
-    store role, bench.py, chip_smoke.py's kernel phase) and refuse
-    anything but a TPU: with JAX_PLATFORMS unset, jax falls back to the
+    store role, chip_smoke.py's kernel phase) and refuse anything but a
+    TPU: with JAX_PLATFORMS unset, jax falls back to the
     CPU when the TPU cannot be initialised, and every 'auto' crossover
     would then quietly resolve to its CPU arm. The only way onto the CPU
     is an explicit JAX_PLATFORMS=cpu in the environment (what the tests
@@ -721,28 +693,6 @@ def prune_scan_enabled() -> bool:
     return True if v is None else v
 
 
-def hnsw_device_enabled() -> bool:
-    """Tri-state hnsw.device_search: 'auto' keeps the device graph walk
-    TPU-only (the lockstep beam needs MXU batch throughput to beat the
-    native C++ graph; on CPU the host path wins and doubles as the
-    parity oracle). True/False force."""
-    v = _parse_tri(FLAGS.get("hnsw_device_search"))
-    if v is None:
-        return _on_tpu()
-    return v
-
-
-def hnsw_device_build_enabled() -> bool:
-    """Tri-state hnsw.device_build: 'auto' keeps bulk device construction
-    TPU-only — the batched beam walks and masked top-k selection rounds
-    need MXU throughput to beat the native C++ insert loop; the host
-    build stays the CPU arm and the parity oracle. True/False force."""
-    v = _parse_tri(FLAGS.get("hnsw_device_build"))
-    if v is None:
-        return _on_tpu()
-    return v
-
-
 def train_sample_rows() -> int:
     """Row cap shared by every train path (conf train.sample_rows,
     floor 0). 0 = full corpus: trainers feed every live row and lift
@@ -803,8 +753,6 @@ def auto_arms() -> Dict[str, bool]:
         "use_pallas_fused_search": pallas_fused_enabled(2048),
         "use_pallas_ivf_search": pallas_ivf_enabled(256),
         "ivf_prune_scan": prune_scan_enabled(),
-        "hnsw_device_search": hnsw_device_enabled(),
-        "hnsw_device_build": hnsw_device_build_enabled(),
         "pipeline_enabled": serving_pipeline_enabled(),
         "vector_blocked_layout": blocked_layout_enabled(),
     }

@@ -1,52 +1,32 @@
-"""TpuHnsw: a graph index whose TPU arm keeps ONE graph, on the device.
+"""TpuHnsw: a graph index with ONE graph, the device adjacency.
 
 Reference: VectorIndexHnsw (src/vector/vector_index_hnsw.{h,cc} — wraps
 hnswlib::HierarchicalNSW with L2Space/InnerProductSpace,
 vector_index_hnsw.cc:154-181; NeedToRebuild when deleted count exceeds half
 the TOTAL element count :577-589; hnswlib-file Save/Load :310).
 
-Two arms share one SlotStore + one exact device rerank; which one a
-process walks follows from the backend it observes (``hnsw.device_search``
-and ``hnsw.device_build``, both ``auto`` = TPU-only):
+The level-0 adjacency in slot space (``SlotStore.adj``, dense
+``[capacity, deg]`` int32, deg = nlinks*2) IS the graph, on whatever
+backend the process has. ``upsert`` puts the rows and inserts them into
+the live adjacency in pow2 batches (ops/graph_build.insert_batch:
+candidate discovery by the lockstep beam walk, occlusion pruning, reverse
+edges; the adjacency is donated under ``store.device_lock``), so an
+acknowledged row is found by the next search with no O(N) step;
+``delete`` tombstones the slot and the walk routes around it; searches
+run as one jitted lockstep beam search (ops/beam.py: frontier gather on
+the adjacency, candidate distances via one einsum against the SlotStore,
+a per-query packed visited bitmask, masked top-k beam updates, a fixed
+iteration cap with early exit) ending in the exact device rerank
+(ops/rerank.py); ``save`` persists rows + adjacency + entry and ``load``
+serves from them. An index is in one of two states: adjacency installed,
+or not yet (no row written).
 
-  TPU arm (both on) — the level-0 adjacency in slot space
-  (``SlotStore.adj``, dense ``[capacity, deg]`` int32, deg = nlinks*2) IS
-  the graph. ``upsert`` puts the rows and inserts them into the live
-  adjacency in pow2 batches (ops/graph_build.insert_batch: candidate
-  discovery by the lockstep beam walk, occlusion pruning, reverse edges;
-  the adjacency is donated under ``store.device_lock``), so an
-  acknowledged row is found by the next search with no O(N) step;
-  ``delete`` tombstones the slot and the walk routes around it; searches
-  run as one jitted lockstep beam search (ops/beam.py: frontier gather on
-  the adjacency, candidate distances via one einsum against the SlotStore,
-  a per-query packed visited bitmask, masked top-k beam updates, a fixed
-  iteration cap with early exit); ``save`` persists rows + adjacency +
-  entry and ``load`` serves from them. No native graph is fed, exported or
-  written: ``hnsw.native_adds``, ``hnsw.adjacency_rebuilds`` and
-  ``hnsw.host_searches`` stay 0.
-
-  CPU arm (either off) and parity oracle — graph construction and beam
-  search run in our own C++ NSW implementation (native/hnsw/hnsw.cc, an
-  original implementation, not a copy of hnswlib). The graph returns an
-  over-fetched candidate set (ef per query) and the device re-ranks it.
-  With ``hnsw.device_search`` forced on over a native-built graph, the
-  native level-0 adjacency exports into the device mirror, keyed on
-  (native graph version, store mutation version) and lazily re-exported
-  on the first search after a write — the IVF `_ensure_view` discipline.
-  A device-owned graph met by this arm (a flag flipped, a host search
-  asked for) is replayed into the native graph first
-  (``_ensure_native_graph``, ``build.backfills``).
-
-Both arms end in the SAME exact device rerank (ops/rerank.py), so the
-final ordering is byte-identical whenever the candidate sets agree.
 Filter pushdown applies the PR 3 filter-mask cache device-side inside
-the beam kernel (masked candidates never enter the result beam); the
-host path reuses the same cached mask for its post-filter.
+the beam kernel (masked candidates never enter the result beam).
 """
 
 from __future__ import annotations
 
-import ctypes
 import json
 import os
 from typing import List, Optional
@@ -74,24 +54,9 @@ from dingo_tpu.index.flat import (
 from dingo_tpu.obs.sentinel import sentinel_jit
 from dingo_tpu.ops.distance import Metric, np_normalize
 
-_LIB = None
-
 #: filter-mask cache entries kept per index (same bound as the IVF cache:
 #: distinct live filter shapes per region are few)
 FILTER_CACHE_SIZE = 16
-
-#: rows replayed per native back-fill chunk after a device bulk build
-#: (O(chunk) host memory, the streaming-rebuild discipline)
-BACKFILL_CHUNK = 8192
-
-
-def _lib():
-    global _LIB
-    if _LIB is None:
-        from dingo_tpu.native import load_hnsw
-
-        _LIB = load_hnsw()
-    return _LIB
 
 
 @sentinel_jit("index.hnsw.search",
@@ -138,54 +103,35 @@ class TpuHnsw(_SlotStoreIndex):
                                      capacity=max(0, int(p.max_elements)))
         self._init_precision(parameter, tier=precision)
         self.ef_search_default = max(64, p.efconstruction // 2)
-        self._graph = self._new_native_graph()
         self._kernel_metric = p.metric
         self._kernel_nbits = 0
-        #: level-0 degree cap of the exported adjacency (hnsw M0 = 2*M)
+        #: level-0 degree cap of the adjacency (hnsw M0 = 2*M)
         self._graph_deg = max(1, int(p.nlinks)) * 2
-        #: (native graph version, store mutation version) the device
-        #: adjacency mirror was built against; None = never built
-        self._graph_key = None
         self._entry_slot = -1
-        #: rows tombstoned while the device adjacency was the graph
+        #: rows tombstoned since the adjacency was installed
         self._deleted_slots = 0
-        #: the device adjacency is THE graph and the native graph does not
-        #: hold it (TPU-arm writes, a device bulk build, a loaded device
-        #: snapshot) — the first CPU-arm use (write, host search, save)
-        #: back-fills the native graph from the store's rows
-        self._native_pending = False
-        #: TPU-arm writes since the adjacency ledger was last seeded: the
-        #: scrub and the snapshot skip the artifact until save() re-seeds
-        #: it from the host copy it takes anyway
+        #: writes since the adjacency ledger was last seeded: the scrub
+        #: and the snapshot skip the artifact until save() re-seeds it
+        #: from the host copy it takes anyway
         self._adj_ledger_stale = False
-        #: reverse edges dropped by TPU-arm inserts (device scalar, folded
-        #: into ``build.reverse_dropped`` at save: no sync on a write)
+        #: reverse edges dropped by inserts (device scalar, folded into
+        #: ``build.reverse_dropped`` at save: no sync on a write)
         self._dropped_d = None
         self._identity_codec = None
         #: (entry slot, its device scalar): uploaded when the entry moves,
         #: not with every search
         self._entry_cached = (None, None)
-        # the counters that say which arm served exist from the start: an
-        # arm that never ran reads 0, not "no such series"
+        # `hnsw.native_adds`, `hnsw.adjacency_rebuilds` and
+        # `hnsw.host_searches` count nothing any more (there is no native
+        # graph): benchmark/metrics/hnsw_{native_adds,adjacency_rebuilds,
+        # host_searches}.json read them in `hnsw768.conc4`, those files are
+        # the benchmark's, and the `benchmark` issue that retires the
+        # three metrics takes these registrations with them
         for name in ("native_adds", "adjacency_rebuilds", "host_searches",
                      "device_searches"):
             METRICS.counter("hnsw." + name, region_id=index_id).add(0)
         #: fingerprint -> (store version, numpy mask, device mask or None)
         self._filter_cache: dict = {}
-
-    def _new_native_graph(self):
-        p = self.parameter
-        return _lib().hnsw_new(
-            p.dimension, 0 if p.metric is Metric.L2 else 1, p.nlinks,
-            p.efconstruction, self.id,
-        )
-
-    def __del__(self):  # noqa: D105
-        try:
-            if getattr(self, "_graph", None):
-                _lib().hnsw_free(self._graph)
-        except Exception:
-            pass
 
     # -- prep ---------------------------------------------------------------
     def _prep_vectors(self, vectors: np.ndarray) -> np.ndarray:
@@ -218,22 +164,9 @@ class TpuHnsw(_SlotStoreIndex):
         if self._precision == "sq8" and vectors is not None:
             self.store.maybe_train(self._prep_vectors(vectors))
 
-    def _tpu_arm(self) -> bool:
-        """True where the device adjacency is the one graph: searches walk
-        it (``hnsw.device_search``) and writes insert into it
-        (``hnsw.device_build``); both ``auto`` = TPU-only, so the arm
-        follows the backend the process observes."""
-        from dingo_tpu.common.config import (
-            hnsw_device_build_enabled,
-            hnsw_device_enabled,
-        )
-
-        return hnsw_device_build_enabled() and hnsw_device_enabled()
-
     def _put_rows(self, ids: np.ndarray, vectors: np.ndarray):
-        """Store put + rerank offer + quality/integrity ledgers: what every
-        write does whichever graph takes the edges. -> (ids, vectors,
-        slots)"""
+        """Store put + rerank offer + quality/integrity ledgers: what an
+        upsert and a bulk session's add share. -> (ids, vectors, slots)"""
         vectors = self._prep_vectors(vectors)
         ids = np.ascontiguousarray(ids, np.int64)
         if len(ids) != len(vectors):
@@ -249,31 +182,13 @@ class TpuHnsw(_SlotStoreIndex):
         self.write_count_since_save += len(ids)
         return ids, vectors, slots
 
-    def _native_add(self, ids: np.ndarray, vectors: np.ndarray) -> None:
-        _lib().hnsw_add(
-            self._graph,
-            len(ids),
-            ids.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            vectors.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-        )
-        METRICS.counter("hnsw.native_adds", region_id=self.id).add(len(ids))
-
     @integrity_mutation
     def upsert(self, ids: np.ndarray, vectors: np.ndarray) -> None:
-        if self._tpu_arm():
-            self._own_device_graph()
-            _, _, slots = self._put_rows(ids, vectors)
-            self._device_insert(np.asarray(slots, np.int32))
-            return
-        self._ensure_native_graph()
-        ids, vectors, _ = self._put_rows(ids, vectors)
-        self._native_add(ids, vectors)
+        _, _, slots = self._put_rows(ids, vectors)
+        self._device_insert(np.asarray(slots, np.int32))
 
     @integrity_mutation
     def delete(self, ids: np.ndarray) -> None:
-        tpu = self._tpu_arm() and self._native_pending
-        if not tpu:
-            self._ensure_native_graph()
         ids = np.ascontiguousarray(ids, np.int64)
         slots = self.store.remove_slots(ids)
         removed = int((slots >= 0).sum())
@@ -282,38 +197,25 @@ class TpuHnsw(_SlotStoreIndex):
 
         QUALITY.observe_delete(self, ids)
         self._integrity_delete(ids)
-        if tpu:
-            # a tombstone: the slot leaves the validity mask, the walk
-            # routes around it, and neighbours' edges to it now translate
-            # to no id — the adjacency ledger waits for the next save
-            self._deleted_slots += removed
-            self._adj_ledger_stale = True
-        else:
-            _lib().hnsw_delete(
-                self._graph, len(ids),
-                ids.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            )
+        # a tombstone: the slot leaves the validity mask, the walk routes
+        # around it, and neighbours' edges to it now translate to no id —
+        # the adjacency ledger waits for the next save
+        self._deleted_slots += removed
+        self._adj_ledger_stale = True
+        self._entry_slot = self._live_entry(self._entry_slot)
         self.write_count_since_save += removed
 
-    # -- TPU arm: the device adjacency as the one graph ----------------------
-    def _own_device_graph(self) -> None:
-        """Make the device adjacency the graph writes go into. A fresh
-        index gets an empty adjacency; a native-built graph (a loaded
-        CPU-arm snapshot, a flag flipped under a live index) hands its
-        level-0 export over once. From here the native graph is stale
-        (`_native_pending`) until a CPU-arm use replays the rows."""
-        store = self.store
-        if self._native_pending and store.adj is not None:
-            return
-        with store.device_lock:
-            if int(_lib().hnsw_total_count(self._graph)):
-                self._ensure_device_graph()
-            elif store.adj is None:
-                store.set_graph(
-                    np.full((store.capacity, self._graph_deg), -1, np.int32),
-                    self._graph_deg,
-                )
-            self._native_pending = True
+    # -- the device adjacency -------------------------------------------------
+    def _live_entry(self, entry: int) -> int:
+        """`entry` while its slot holds a live row, else any live slot
+        (greedy descent reaches the same basin in a few hops; a walk from
+        a tombstoned entry whose neighbours are tombstoned too finds
+        nothing), else -1: an index with no live row answers empty."""
+        valid = self.store.valid_h
+        if 0 <= entry < len(valid) and valid[entry]:
+            return int(entry)
+        live_slots = np.flatnonzero(valid)
+        return int(live_slots[0]) if len(live_slots) else -1
 
     def _entry_device(self):
         if self._entry_cached[0] != self._entry_slot:
@@ -349,6 +251,11 @@ class TpuHnsw(_SlotStoreIndex):
         if not len(slots):
             return
         store = self.store
+        if store.adj is None:      # the index's first rows
+            store.set_graph(
+                np.full((store.capacity, self._graph_deg), -1, np.int32),
+                self._graph_deg,
+            )
         beam = self._beam_width(self.parameter.efconstruction, 1)
         max_iters = max(1, int(FLAGS.get("hnsw_max_iters")))
         alpha = float(FLAGS.get("hnsw_build_alpha"))
@@ -376,18 +283,17 @@ class TpuHnsw(_SlotStoreIndex):
             float(len(store))
         )
 
-    # -- device graph mirror -------------------------------------------------
     def _install_adjacency(self, labels: np.ndarray, adj_nodes: np.ndarray,
                            entry_label: int) -> None:
-        """Remap a node-space level-0 export ([n] labels, [n, deg] neighbor
-        node indices, -1 padded) into the slot-space device mirror.
-        Caller holds store.device_lock. Nodes whose label has no live slot
-        (store-deleted tombstones) are dropped — their slot may already
-        serve a different vector, so they cannot route device-side; the
+        """Remap a snapshot's node-space adjacency ([n] labels, [n, deg]
+        neighbor node indices, -1 padded) into the slot-space device
+        adjacency. Caller holds store.device_lock. Nodes whose label has
+        no live slot are dropped — their slot may already serve a
+        different vector, so they cannot route device-side; the
         need_to_rebuild() trigger bounds how degraded the graph can get.
 
         Integrity-bracketed like a write path: the install swaps the
-        mirror AND rebuilds the adjacency ledger mid-flight — a scrub
+        adjacency AND rebuilds its ledger mid-flight — a scrub
         overlapping it must classify as raced, not corruption."""
         self._integrity_begin()
         try:
@@ -413,95 +319,30 @@ class TpuHnsw(_SlotStoreIndex):
         if entry_label >= 0:
             entry = int(store.slots_of(
                 np.asarray([entry_label], np.int64))[0])
-        if entry < 0 and n:
-            # entry tombstoned in the store: any live slot restarts the
-            # walk (greedy descent reaches the same basin in a few hops)
-            live_slots = np.flatnonzero(store.valid_h)
-            if len(live_slots):
-                entry = int(live_slots[0])
-        self._entry_slot = entry
+        self._entry_slot = self._live_entry(entry)
         METRICS.gauge("hnsw.graph_nodes", region_id=self.id).set(float(n))
-        # state-integrity: the adjacency artifact resets with every mirror
-        # swap (a full install, not an incremental write)
+        # state-integrity: the adjacency artifact resets with every full
+        # install (not an incremental write)
         self._adj_ledger_stale = False
         self._seed_adjacency_ledger(full)
 
-    def _export_level0(self):
-        """(labels [n], adjacency [n, deg]) snapshot of the native level-0
-        graph (node space)."""
-        n = int(_lib().hnsw_total_count(self._graph))
-        labels = np.empty(n, np.int64)
-        adj = np.full((n, self._graph_deg), -1, np.int32)
-        if n:
-            # n is passed back in as the buffer capacity: the native side
-            # clamps to it, so an insert racing between the count and the
-            # export cannot overflow these arrays (the version key forces
-            # a clean re-export on the next search either way)
-            _lib().hnsw_export_level0(
-                self._graph,
-                n,
-                self._graph_deg,
-                labels.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-                adj.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-            )
-        return labels, adj
-
-    def _ensure_device_graph(self) -> None:
-        """Lazy sync of the device adjacency (caller holds
-        store.device_lock): steady-state read traffic finds a fresh mirror
-        and pays one tuple compare; the first search after a write batch
-        re-exports. Keyed on the native graph version AND the store
-        mutation version — an upsert of an existing id re-slots nothing
-        natively but can remap label->slot (delete + re-add), so both
-        sides gate. A device-owned graph (`_native_pending`) is never
-        re-exported: there is nothing to re-export it from."""
-        if self._native_pending and self.store.adj is not None:
-            return
-        want = (
-            int(_lib().hnsw_graph_version(self._graph)),
-            self.store.mutation_version,
-        )
-        if self._graph_key == want and self.store.adj is not None:
-            return
-        labels, adj = self._export_level0()
-        self._install_adjacency(
-            labels, adj, int(_lib().hnsw_entry_label(self._graph))
-        )
-        self._graph_key = want
-        METRICS.counter("hnsw.adjacency_rebuilds", region_id=self.id).add(1)
-
     def adjacency_in_sync(self) -> bool:
-        """True while the device adjacency mirror matches the native graph
-        AND the store (the scrub only checks the adjacency artifact then —
-        a pending lazy re-export is staleness, not corruption). A device-
-        owned graph is in sync with itself; its ledger is stale between a
-        TPU-arm write and the next save, which re-seeds it."""
-        if self.store.adj is None:
-            return False
-        if self._native_pending:
-            return not self._adj_ledger_stale
-        return self._graph_key == (
-            int(_lib().hnsw_graph_version(self._graph)),
-            self.store.mutation_version,
-        )
+        """True while the adjacency ledger describes the device adjacency
+        (the scrub only checks the artifact then): it is stale between a
+        write and the next save, which re-seeds it — staleness, not
+        corruption."""
+        return self.store.adj is not None and not self._adj_ledger_stale
 
     # -- device bulk build (ISSUE 18) ----------------------------------------
     def bulk_builder(self, expect_rows: int = 0):
         """Bulk-construction session (manager.build_index feeds scan
         chunks through it): rows stream into the SlotStore and the level-0
-        graph builds on device in pow2 batches (ops/graph_build.py),
-        batches-of-rows MXU work instead of one native insert at a time.
+        graph builds on device in pow2 batches (ops/graph_build.py).
 
-        Returns None when the crossover gate says host (``hnsw.device_build``
-        auto = TPU-only — the host insert loop stays the CPU arm and the
-        parity oracle) or when the index already holds rows (bulk build
+        Returns None when the index already holds rows (bulk build
         constructs from empty; incremental inserts go through upsert()).
         """
-        from dingo_tpu.common.config import hnsw_device_build_enabled
-
-        if not hnsw_device_build_enabled():
-            return None
-        if len(self.store) or int(_lib().hnsw_total_count(self._graph)):
+        if len(self.store) or self.store.adj is not None:
             return None
         return _HnswBulkSession(self, expect_rows)
 
@@ -512,26 +353,15 @@ class TpuHnsw(_SlotStoreIndex):
         return self._put_rows(ids, vectors)[2]
 
     def _install_built_adjacency(self, adj, entry_slot: int) -> None:
-        """Install a device-built [capacity, deg] adjacency as THE graph:
-        the mirror serves device searches immediately, `_graph_key` pins it
-        against the lazy native re-export (which would clobber it with an
-        empty graph), and `_native_pending` arms the back-fill. Integrity-
-        bracketed like _install_adjacency — same mirror-swap semantics."""
+        """Install a bulk-built [capacity, deg] adjacency as the graph.
+        Integrity-bracketed like _install_adjacency — same swap
+        semantics."""
         self._integrity_begin()
         try:
             store = self.store
             with store.device_lock:
                 store.set_graph(adj, self._graph_deg)
-                entry = int(entry_slot)
-                if entry < 0 or not store.valid_h[entry]:
-                    live_slots = np.flatnonzero(store.valid_h)
-                    entry = int(live_slots[0]) if len(live_slots) else -1
-                self._entry_slot = entry
-                self._graph_key = (
-                    int(_lib().hnsw_graph_version(self._graph)),
-                    store.mutation_version,
-                )
-                self._native_pending = True
+                self._entry_slot = self._live_entry(int(entry_slot))
             self._adj_ledger_stale = False
             METRICS.gauge("hnsw.graph_nodes", region_id=self.id).set(
                 float(len(store))
@@ -558,40 +388,6 @@ class TpuHnsw(_SlotStoreIndex):
                 self, "adjacency", store.ids_by_slot[live_slots],
                 store.ids_of_slots(full[live_slots]),
             )
-
-    def _ensure_native_graph(self) -> None:
-        """Replay the store's rows into the native graph when the device
-        adjacency has been the graph (a device bulk build, TPU-arm writes,
-        a loaded device snapshot) — triggered by the first CPU-arm use
-        (write, host search, save), never on the TPU arm: a device-served
-        region does not pay it. A native graph that holds older rows is
-        replaced, not patched. Streams BACKFILL_CHUNK rows per native add
-        call (O(chunk) host memory); quantized tiers replay the decoded
-        surrogate, the store's tier semantics. The handover COMPLETES
-        here: once the native graph holds the rows, its level-0 export
-        re-installs as the device mirror (one ordinary lazy re-export),
-        so every representation — device walk, host beam, snapshot,
-        integrity adjacency digest — describes the same topology from
-        this point on."""
-        if not self._native_pending:
-            return
-        self._native_pending = False
-        if int(_lib().hnsw_total_count(self._graph)):
-            _lib().hnsw_free(self._graph)
-            self._graph = self._new_native_graph()
-        store = self.store
-        live = np.flatnonzero(store.valid_h)
-        ids = store.ids_by_slot[live]
-        for s in range(0, len(ids), BACKFILL_CHUNK):
-            chunk = np.ascontiguousarray(ids[s:s + BACKFILL_CHUNK],
-                                         np.int64)
-            _, rows = store.gather(chunk)
-            self._native_add(chunk, np.ascontiguousarray(rows, np.float32))
-        self._deleted_slots = 0
-        self._graph_key = None
-        with store.device_lock:
-            self._ensure_device_graph()
-        METRICS.counter("build.backfills", region_id=self.id).add(1)
 
     # -- filter-mask cache ---------------------------------------------------
     def _prep_filter(self, filter_spec: Optional[FilterSpec]):
@@ -675,20 +471,19 @@ class TpuHnsw(_SlotStoreIndex):
             ef = max(int(ef or self.tuned("ef", self.ef_search_default)),
                      int(topk))
             self._count_search()
-            if self._device_search_on():
-                fetch, finish = self._device_dispatch(
-                    queries, b, int(topk), filter_spec, ef, staged)
-                name = "beam_search"
-            else:
-                fetch, finish = self._host_dispatch(
-                    queries, b, int(topk), filter_spec, ef, staged)
-                name = "rerank"
+            if self._entry_slot < 0:
+                # no live row: nothing to walk, no launch
+                empty = SearchResult(ids=np.empty(0, np.int64),
+                                     distances=np.empty(0, np.float32))
+                return lambda: [empty] * b
+            fetch, finish = self._device_dispatch(
+                queries, b, int(topk), filter_spec, ef, staged)
         # the device wait of a sampled request: from here (kernels
         # enqueued, lock released) to the fetch's return in resolve();
         # never a sync of its own (ops/distance.device_wait_begin)
         from dingo_tpu.ops.distance import device_wait_begin
 
-        wait = device_wait_begin(name)
+        wait = device_wait_begin("beam_search")
         lease = finish.lease
 
         def resolve() -> List[SearchResult]:
@@ -703,28 +498,18 @@ class TpuHnsw(_SlotStoreIndex):
 
         return resolve
 
-    def _device_search_on(self) -> bool:
-        from dingo_tpu.common.config import hnsw_device_enabled
-
-        return hnsw_device_enabled() and len(self.store) > 0
-
     def _beam_width(self, ef: int, topk: int) -> int:
-        """ef -> beam ladder: a fixed conf width wins, else the
-        {1,1.5}x-pow2 shape bucket keeps steady-state serving on a
-        handful of compiled programs (k/beam/max_iters are static)."""
-        from dingo_tpu.common.config import FLAGS
+        """ef -> beam ladder: the {1,1.5}x-pow2 shape bucket keeps
+        steady-state serving on a handful of compiled programs
+        (k/beam/max_iters are static)."""
         from dingo_tpu.index.ivf_layout import shape_bucket
 
-        fixed = int(FLAGS.get("hnsw_device_beam"))
-        if fixed > 0:
-            return max(fixed, topk)
         return max(shape_bucket(max(ef, topk)), 1)
 
-    def _finisher(self, lease, queries, b, topk, filter_spec, beam,
-                  walk=None):
+    def _finisher(self, lease, queries, b, topk, filter_spec, beam, walk):
         """The host half of a search, run by resolve() on the ONE fetched
-        group: slots -> ids, heat, quality; `walk` = (capacity-free walk
-        diagnostics follow the reply in the same group)."""
+        group: slots -> ids, walk diagnostics, heat, quality; `walk` =
+        (live rows at dispatch, candidate slots a round gathers)."""
         from dingo_tpu.obs.heat import HEAT, heat_enabled
 
         store = self.store
@@ -734,15 +519,13 @@ class TpuHnsw(_SlotStoreIndex):
 
         def finish(fetched) -> List[SearchResult]:
             dists_h, slots_h = fetched[0], fetched[1]
-            w = 1.0
-            if walk is not None:
-                stats_h = fetched[2][:b]      # [b, 3]: rounds, visited, live
-                self._note_walk_stats(
-                    stats_h[:, 0], stats_h[:, 1], stats_h[:, 2], beam, *walk
-                )
-                # the per-query visited count weights the heat touch by
-                # how much of the graph the walk crossed
-                w = float(max(1.0, np.mean(stats_h[:, 1]) / max(1, beam)))
+            stats_h = fetched[2][:b]      # [b, 3]: rounds, visited, live
+            self._note_walk_stats(
+                stats_h[:, 0], stats_h[:, 1], stats_h[:, 2], beam, *walk
+            )
+            # the per-query visited count weights the heat touch by how
+            # much of the graph the walk crossed
+            w = float(max(1.0, np.mean(stats_h[:, 1]) / max(1, beam)))
             if heat_on:
                 # result slots mark the graph neighborhoods the walk
                 # landed in; arrays ALREADY in this fetch group
@@ -786,7 +569,6 @@ class TpuHnsw(_SlotStoreIndex):
             with TRACER.start_child("index.lock_wait"):
                 store.device_lock.acquire()
             try:
-                self._ensure_device_graph()
                 valid = store.device_mask()
                 fmask = self._device_filter_mask(filter_spec, prep)
                 if fmask is None:
@@ -801,7 +583,7 @@ class TpuHnsw(_SlotStoreIndex):
                         metric=self._kernel_metric, sq=sq_on,
                     )
                     dists, out_slots = self._final_rerank(
-                        qpad, rslots, topk)
+                        qpad, rslots, topk, vmin, scale)
                     stats = jnp.stack([hops, vcount, occ], axis=1)
                 else:
                     dists, out_slots, stats = hnsw_search_program(
@@ -823,104 +605,26 @@ class TpuHnsw(_SlotStoreIndex):
             walk=(len(store), round_slots(beam, self._graph_deg)),
         )
 
-    def _host_dispatch(self, queries, b, topk, filter_spec, ef, staged):
-        """Native graph candidates + enqueued exact rerank; -> (fetch
-        group, finisher)."""
-        from dingo_tpu.trace import TRACER
-
-        self._ensure_native_graph()
-        METRICS.counter("hnsw.host_searches", region_id=self.id).add(1)
-        # 1) CPU graph: over-fetched candidate labels per query.
-        cand_labels = np.empty((b, ef), np.int64)
-        cand_d = np.empty((b, ef), np.float32)
-        _lib().hnsw_search(
-            self._graph, b,
-            queries.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-            ef, ef,
-            cand_labels.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            cand_d.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-        )
-        # 2) host filter on candidates via the shared (fingerprint, store
-        #    version) mask cache (the graph has no filter pushdown; the
-        #    reference's HnswRangeFilterFunctor filters inside the beam —
-        #    over-fetch + post-filter keeps the graph branch-free instead).
-        prep = self._prep_filter(filter_spec)
-        flat = cand_labels.reshape(-1)
-        slots = self.store.slots_of(flat).reshape(b, ef)
-        valid = slots >= 0
-        if prep is not None:
-            fmask = prep[2]
-            if prep[1] != self.store.mutation_version:  # raced with write
-                fmask = filter_spec.slot_mask(self.store.ids_by_slot)
-            safe = np.where(slots >= 0, slots, 0)
-            valid &= fmask[safe]
-        # 3) exact device rerank (shared with the device path).
-        qpad = staged.take(queries) if staged is not None else None
-        if qpad is None:
-            qpad = jnp.asarray(_pad_batch(queries))
-        bb = qpad.shape[0]
-        cand = np.where(valid, slots, -1).astype(np.int32)
-        if bb != b:
-            cand = np.concatenate(
-                [cand, np.full((bb - b, ef), -1, np.int32)]
-            )
-        store = self.store
-        lease = store.begin_search()   # slots stable until resolve
-        try:
-            with TRACER.start_child("index.lock_wait"):
-                store.device_lock.acquire()    # vecs/sqnorm are donatable
-            try:
-                dists, out_slots = self._final_rerank(
-                    qpad, jnp.asarray(cand), topk
-                )
-            finally:
-                store.device_lock.release()
-        except Exception:
-            lease.release()
-            raise
-        from dingo_tpu.ops.topk import begin_host_fetch
-
-        return begin_host_fetch(dists, out_slots), self._finisher(
-            lease, queries, b, topk, filter_spec,
-            self._beam_width(ef, topk),
-        )
-
-    def _final_rerank(self, qpad, cand_slots, topk: int):
-        """Exact device rerank of a candidate set (ops/rerank.py); caller
-        holds store.device_lock. fp32 reranks exactly; bf16 gathers the
-        stored bf16 rows and scores in f32 (bf16-exact); sq8 decodes codes
-        in-kernel (exact for the tier) and, when the PR 4 rerank cache
-        holds rows, chains the cached f32-exact rerank on top."""
-        from dingo_tpu.ops.rerank import (
-            exact_rerank_device,
-            sq_rerank_device,
-        )
+    def _final_rerank(self, qpad, cand_slots, topk: int, vmin, scale):
+        """The sq8 tier's rerank of a walk's candidate set (ops/rerank.py;
+        the float tiers rerank inside ``hnsw_search_program``); caller
+        holds store.device_lock. Decodes codes in-kernel (exact for the
+        tier) and, when the PR 4 rerank cache holds rows, chains the
+        cached f32-exact rerank on top."""
+        from dingo_tpu.ops.rerank import sq_rerank_device
 
         store = self.store
-        metric = self._kernel_metric
-        if self._precision == "sq8":
-            if store.sq_params is None:
-                # empty untrained store: identity codec keeps the kernel
-                # well-defined without installing params (FLAT convention)
-                vmin = jnp.zeros((self.dimension,), jnp.float32)
-                scale = jnp.ones((self.dimension,), jnp.float32)
-            else:
-                vmin, scale = store.sq_vmin_d, store.sq_scale_d
-            cache = self._rerank_cache
-            if cache is not None and len(cache):
-                kk = int(cand_slots.shape[1])
-                dists, slots = sq_rerank_device(
-                    store.vecs, vmin, scale, store.sqnorm, qpad,
-                    cand_slots, k=kk, metric=metric,
-                )
-                return self._dispatch_rerank(qpad, dists, slots, topk)
-            return sq_rerank_device(
-                store.vecs, vmin, scale, store.sqnorm, qpad, cand_slots,
-                k=topk, metric=metric,
+        cache = self._rerank_cache
+        if cache is not None and len(cache):
+            kk = int(cand_slots.shape[1])
+            dists, slots = sq_rerank_device(
+                store.vecs, vmin, scale, store.sqnorm, qpad,
+                cand_slots, k=kk, metric=self._kernel_metric,
             )
-        return exact_rerank_device(
-            store.vecs, store.sqnorm, qpad, cand_slots, k=topk,
-            metric=metric,
+            return self._dispatch_rerank(qpad, dists, slots, topk)
+        return sq_rerank_device(
+            store.vecs, vmin, scale, store.sqnorm, qpad, cand_slots,
+            k=topk, metric=self._kernel_metric,
         )
 
     def _note_walk_stats(self, hops, vcount, occ, beam, live,
@@ -981,11 +685,7 @@ class TpuHnsw(_SlotStoreIndex):
         return len(self.store)
 
     def get_deleted_count(self) -> int:
-        return int(_lib().hnsw_deleted_count(self._graph)) \
-            + self._deleted_slots
-
-    def get_memory_size(self) -> int:
-        return self.store.memory_size() + int(_lib().hnsw_memory(self._graph))
+        return self._deleted_slots
 
     def need_to_rebuild(self) -> bool:
         """Reference trigger: deleted_count > total/2
@@ -995,21 +695,12 @@ class TpuHnsw(_SlotStoreIndex):
         total = deleted + self.get_count()
         return total > 0 and deleted * 2 > total
 
-    def _save_meta(self, graph: Optional[dict] = None) -> dict:
-        meta = super()._save_meta()
-        meta["hnsw_graph"] = graph or {
-            "deg": self._graph_deg,
-            "nodes": int(_lib().hnsw_total_count(self._graph)),
-            "entry_label": int(_lib().hnsw_entry_label(self._graph)),
-        }
-        return meta
-
-    def _device_graph_snapshot(self, adj_h: np.ndarray):
-        """The device adjacency as it is written to disk: (labels [n],
-        adjacency [n, deg] in the node space of the live rows in slot
-        order, graph meta). load() puts the rows back in that order, so
-        node space is the loaded store's slot space; an edge to a
-        tombstoned slot is dropped."""
+    def _graph_snapshot(self, adj_h: np.ndarray):
+        """The adjacency as it is written to disk: (labels [n], adjacency
+        [n, deg] in the node space of the live rows in slot order, graph
+        meta). load() puts the rows back in that order, so node space is
+        the loaded store's slot space; an edge to a tombstoned slot is
+        dropped."""
         store = self.store
         live = store.ids_by_slot >= 0
         rank = np.cumsum(live, dtype=np.int64).astype(np.int32) - 1
@@ -1028,23 +719,18 @@ class TpuHnsw(_SlotStoreIndex):
         }
 
     def save(self, path: str) -> None:
-        """TPU arm: rows + the device adjacency itself + entry; no native
-        blob is made or written. CPU arm: rows + native blob + its level-0
-        export, after the back-fill a device-built graph owes it. The
-        copy off the device holds ``store.device_lock`` (one hold, so rows
-        and adjacency are of one moment), the file writes do not."""
-        device = self._native_pending and self._tpu_arm()
-        if not device:
-            self._ensure_native_graph()
+        """Rows + the adjacency itself + entry. The copy off the device
+        holds ``store.device_lock`` (one hold, so rows and adjacency are
+        of one moment), the file writes do not."""
         os.makedirs(path, exist_ok=True)
         store = self.store
         sq = self._precision == "sq8" and store.sq_params is not None
-        adj_h = dropped = None
         with store.device_lock:
             snap = store.codes_to_host() if sq else store.to_host()
-            if device:
-                adj_h = np.asarray(store.adj)
-                dropped, self._dropped_d = self._dropped_d, None
+            # no row was ever written: an empty graph of the same shape
+            adj_h = np.asarray(store.adj) if store.adj is not None \
+                else np.full((store.capacity, self._graph_deg), -1, np.int32)
+            dropped, self._dropped_d = self._dropped_d, None
         if sq:
             np.savez(
                 os.path.join(path, "hnsw_vectors.npz"),
@@ -1061,43 +747,43 @@ class TpuHnsw(_SlotStoreIndex):
                 # lossless)
                 vectors=np.asarray(snap["vectors"], np.float32),
             )
+        labels, adj, graph_meta = self._graph_snapshot(adj_h)
         blob_path = os.path.join(path, "hnsw_graph.bin")
-        graph_meta = None
-        if device:
-            labels, adj, graph_meta = self._device_graph_snapshot(adj_h)
-            if os.path.exists(blob_path):
-                os.remove(blob_path)   # an older CPU-arm snapshot's
-            if self._adj_ledger_stale:
-                # TPU-arm writes since the last seeding: the ledger takes
-                # the host copy this save made anyway
-                self._adj_ledger_stale = False
-                self._seed_adjacency_ledger(adj_h)
-            if dropped is not None:
-                METRICS.counter(
-                    "build.reverse_dropped", region_id=self.id
-                ).add(int(dropped))
-        else:
-            size = _lib().hnsw_save_size(self._graph)
-            buf = np.empty(size, np.uint8)
-            written = _lib().hnsw_save(
-                self._graph,
-                buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-            )
-            with open(blob_path, "wb") as f:
-                f.write(buf[:written].tobytes())
-            labels, adj = self._export_level0()
-        # the adjacency rides the snapshot (node space + labels) so load()
-        # serves device searches without a rebuild or a native re-export
+        if os.path.exists(blob_path):
+            # a snapshot of before PR 33 from a store without a TPU held
+            # the native graph's blob here: nothing reads it any more
+            os.remove(blob_path)
+        if self._adj_ledger_stale:
+            # writes since the last seeding: the ledger takes the host
+            # copy this save made anyway
+            self._adj_ledger_stale = False
+            self._seed_adjacency_ledger(adj_h)
+        if dropped is not None:
+            METRICS.counter(
+                "build.reverse_dropped", region_id=self.id
+            ).add(int(dropped))
+        # node space + labels: load() remaps them into the slot space of
+        # the store it fills, and serves without a rebuild
         np.savez(
             os.path.join(path, "hnsw_adj.npz"), labels=labels, adj=adj
         )
+        meta = self._save_meta()
+        meta["hnsw_graph"] = graph_meta
         with open(os.path.join(path, "meta.json"), "w") as f:
-            json.dump(self._save_meta(graph_meta), f)
+            json.dump(meta, f)
 
     def load(self, path: str) -> None:
         with open(os.path.join(path, "meta.json")) as f:
             meta = json.load(f)
         self._check_meta(meta)
+        graph_meta = meta.get("hnsw_graph") or {}
+        adj_path = os.path.join(path, "hnsw_adj.npz")
+        if not os.path.exists(adj_path) \
+                or int(graph_meta.get("deg", -1)) != self._graph_deg:
+            # the manager's rebuild from the engine takes over
+            raise InvalidParameter(
+                "hnsw snapshot without a usable adjacency"
+            )
         data = np.load(os.path.join(path, "hnsw_vectors.npz"))
         self.store = _new_tier_store(
             self._precision, self.dimension, self.parameter,
@@ -1120,51 +806,19 @@ class TpuHnsw(_SlotStoreIndex):
         elif len(data["ids"]):
             self.store.put(np.asarray(data["ids"], np.int64),
                            data["vectors"])
-        graph_meta = meta.get("hnsw_graph") or {}
-        device = bool(graph_meta.get("device_graph"))
-        if device:
-            # a device-graph snapshot carries no native blob: the native
-            # graph starts empty and is back-filled only by a CPU-arm use
-            new_graph = self._new_native_graph()
-        else:
-            blob = np.fromfile(os.path.join(path, "hnsw_graph.bin"),
-                               np.uint8)
-            new_graph = _lib().hnsw_load(
-                blob.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-                len(blob),
-            )
-            if not new_graph:
-                raise InvalidParameter("bad hnsw graph blob")
-        _lib().hnsw_free(self._graph)
-        self._graph = new_graph
         self._filter_cache.clear()
-        self._graph_key = None
-        self._entry_slot = -1
         self._deleted_slots = 0
         self._dropped_d = None
-        self._native_pending = False   # the loaded blob IS the graph
-        adj_path = os.path.join(path, "hnsw_adj.npz")
-        installed = False
-        if graph_meta and os.path.exists(adj_path) \
-                and int(graph_meta.get("deg", -1)) == self._graph_deg:
-            snap = np.load(adj_path)
-            with self.store.device_lock:
-                self._install_adjacency(
-                    np.asarray(snap["labels"], np.int64),
-                    np.asarray(snap["adj"], np.int32),
-                    int(graph_meta.get("entry_label", -1)),
-                )
-                self._graph_key = (
-                    int(_lib().hnsw_graph_version(self._graph)),
-                    self.store.mutation_version,
-                )
-            installed = True
-        if device:
-            if not installed:
-                raise InvalidParameter(
-                    "device-graph snapshot without a usable adjacency"
-                )
-            self._native_pending = True    # the adjacency IS the graph
+        snap = np.load(adj_path)
+        # a snapshot of before PR 33 from a store without a TPU carries
+        # the same labels + level-0 adjacency (its native graph's export)
+        # and a blob, hnsw_graph.bin, that nothing reads any more
+        with self.store.device_lock:
+            self._install_adjacency(
+                np.asarray(snap["labels"], np.int64),
+                np.asarray(snap["adj"], np.int32),
+                int(graph_meta.get("entry_label", -1)),
+            )
         self.apply_log_id = meta["apply_log_id"]
         self.write_count_since_save = 0
         self._integrity_on_restore(meta)
@@ -1173,8 +827,7 @@ class TpuHnsw(_SlotStoreIndex):
 class _HnswBulkSession:
     """One bulk construction: rows in via add(), graph installed by
     finish(). Owns a BulkGraphBuilder over the index's SlotStore;
-    index-level bookkeeping (ledgers, rerank offers, native back-fill
-    arming) stays in TpuHnsw."""
+    index-level bookkeeping (ledgers, rerank offers) stays in TpuHnsw."""
 
     def __init__(self, index: TpuHnsw, expect_rows: int = 0):
         from dingo_tpu.common.config import FLAGS

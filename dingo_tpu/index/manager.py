@@ -101,7 +101,7 @@ class VectorIndexManager:
             # index directly — peak host memory is O(chunk), not O(corpus)
             # (the old path materialized the full row list AND a second
             # full copy for the train sample). Indexes exposing a bulk
-            # session (TpuHnsw behind the hnsw.device_build crossover)
+            # session (an empty TpuHnsw)
             # construct their graph on device from the same chunks.
             mk = getattr(index, "bulk_builder", None)
             bulk = mk() if mk is not None else None

@@ -7,11 +7,11 @@ fail, a search would 500. The ladder turns it into graceful degradation:
 
   rung 1  drop_rerank   — free the region's DeviceRerankCache (bf16/sq8
                           tiers; recall-advisory, rebuilt by future offers)
-  rung 2  evict_mirrors — free the dimension-blocked scan mirror and the
-                          HNSW adjacency mirror (both are DERIVED copies;
-                          the pruned/beam kernels fall back to the dense
-                          paths that gate on `vecs_blk is not None` /
-                          re-export lazily)
+  rung 2  evict_mirrors — free the dimension-blocked scan mirror (a
+                          DERIVED copy; the pruned kernel falls back to
+                          the dense path that gates on `vecs_blk is not
+                          None`). An HNSW adjacency is the graph itself
+                          and stays
   rung 3  retry         — re-run the failed op once against the slimmer
                           footprint (index mutations are upserts/deletes:
                           idempotent, safe to re-apply)
@@ -216,17 +216,6 @@ class DeviceRecoveryPlane:
                 # to the dense scan, not a correctness change
                 store.vecs_blk = None
                 store.bsq_blk = None
-                freed = True
-            if getattr(store, "adj", None) is not None \
-                    and not getattr(idx, "_native_pending", False):
-                # a MIRROR of the native graph: HNSW re-exports it lazily
-                # on the next device search; until then the host beam
-                # fallback serves. A device-owned adjacency (TPU arm) is
-                # the graph itself and stays
-                store.adj = None
-                store.graph_deg = 0
-                if hasattr(idx, "_graph_key"):
-                    idx._graph_key = None
                 freed = True
         return freed
 

@@ -257,10 +257,10 @@ class SlotStore:
         return size
 
     def set_graph(self, adj: np.ndarray, deg: int) -> None:
-        """Install the slot-space adjacency mirror: [capacity, deg] int32
-        neighbor slots, -1 padded. The owning index (TpuHnsw) builds it
-        from the native graph export; a full swap (not a scatter) because
-        one node insert can rewire arbitrary neighbors' lists."""
+        """Install the slot-space adjacency: [capacity, deg] int32
+        neighbor slots, -1 padded. The owning index (TpuHnsw) hands over
+        an empty one before its first insert, a snapshot's on load and a
+        bulk session's at its end; inserts then donate it in place."""
         if adj.shape != (self.capacity, deg):
             raise ValueError(
                 f"adjacency shape {adj.shape} != ({self.capacity}, {deg})"

@@ -927,7 +927,7 @@ class TierManager:
     def _release_device(src, region_id: int) -> None:
         """The retire hook (ISSUE 19 satellite): a region leaving HBM
         must drop its device-side bookkeeping with it — rerank cache,
-        blocked scan mirror, HNSW adjacency mirror, filter-mask cache —
+        blocked scan mirror, HNSW adjacency, filter-mask cache —
         and the HBM ledger must forget the region so hbm.region.bytes
         zeroes and DEVPEAK stops reporting ghost residency. Mirrors the
         recovery ladder's eviction rungs (index/recovery.py) plus the
@@ -951,8 +951,6 @@ class TierManager:
                 if getattr(store, "adj", None) is not None:
                     store.adj = None
                     store.graph_deg = 0
-                    if hasattr(src, "_graph_key"):
-                        src._graph_key = None
         from dingo_tpu.obs.hbm import HBM
 
         HBM.update_region(region_id, {})   # zero the live owner gauges

@@ -1,10 +1,9 @@
-"""ctypes bindings to the native C++ runtime pieces (sources in native/).
+"""ctypes bindings to the native C++ runtime piece (sources in native/).
 
-The shared libraries are built on demand, on the machine that runs them:
-the environment guarantees g++ but no pip installs, so the repo ships
-sources and compiles lazily (cached .so next to this file, git-ignored).
-Only HNSW and the LSM raw engine need them; IVF/FLAT indexes and the WAL
-engine never load native code.
+The shared library is built on demand, on the machine that runs it: the
+environment guarantees g++ but no pip installs, so the repo ships sources
+and compiles lazily (cached .so next to this file, git-ignored). Only the
+LSM raw engine needs it; no index and not the WAL engine load native code.
 """
 
 from __future__ import annotations
@@ -72,7 +71,7 @@ def _build(lib: str, src: str) -> str:
         except FileNotFoundError as e:
             raise RuntimeError(
                 f"{lib} is built from native/{src} on first use and g++ is "
-                "missing; HNSW indexes and the LSM engine need it"
+                "missing; the LSM engine needs it"
             ) from e
         tmp_stamp = f"{stamp}.{os.getpid()}"
         with open(tmp_stamp, "w") as f:
@@ -80,46 +79,6 @@ def _build(lib: str, src: str) -> str:
         os.replace(tmp, path)
         os.replace(tmp_stamp, stamp)
     return path
-
-
-def load_hnsw() -> ctypes.CDLL:
-    lib = ctypes.CDLL(_build("libdingohnsw.so", "hnsw/hnsw.cc"))
-    c = ctypes
-    lib.hnsw_new.restype = c.c_void_p
-    lib.hnsw_new.argtypes = [c.c_int, c.c_int, c.c_int, c.c_int, c.c_uint64]
-    lib.hnsw_free.argtypes = [c.c_void_p]
-    lib.hnsw_add.argtypes = [
-        c.c_void_p, c.c_int, c.POINTER(c.c_int64), c.POINTER(c.c_float),
-    ]
-    lib.hnsw_delete.restype = c.c_int
-    lib.hnsw_delete.argtypes = [c.c_void_p, c.c_int, c.POINTER(c.c_int64)]
-    lib.hnsw_search.argtypes = [
-        c.c_void_p, c.c_int, c.POINTER(c.c_float), c.c_int, c.c_int,
-        c.POINTER(c.c_int64), c.POINTER(c.c_float),
-    ]
-    lib.hnsw_count.restype = c.c_int64
-    lib.hnsw_count.argtypes = [c.c_void_p]
-    lib.hnsw_deleted_count.restype = c.c_int64
-    lib.hnsw_deleted_count.argtypes = [c.c_void_p]
-    lib.hnsw_memory.restype = c.c_int64
-    lib.hnsw_memory.argtypes = [c.c_void_p]
-    lib.hnsw_total_count.restype = c.c_int64
-    lib.hnsw_total_count.argtypes = [c.c_void_p]
-    lib.hnsw_graph_version.restype = c.c_int64
-    lib.hnsw_graph_version.argtypes = [c.c_void_p]
-    lib.hnsw_entry_label.restype = c.c_int64
-    lib.hnsw_entry_label.argtypes = [c.c_void_p]
-    lib.hnsw_export_level0.argtypes = [
-        c.c_void_p, c.c_int64, c.c_int,
-        c.POINTER(c.c_int64), c.POINTER(c.c_int32),
-    ]
-    lib.hnsw_save_size.restype = c.c_int64
-    lib.hnsw_save_size.argtypes = [c.c_void_p]
-    lib.hnsw_save.restype = c.c_int64
-    lib.hnsw_save.argtypes = [c.c_void_p, c.POINTER(c.c_uint8)]
-    lib.hnsw_load.restype = c.c_void_p
-    lib.hnsw_load.argtypes = [c.POINTER(c.c_uint8), c.c_int64]
-    return lib
 
 
 def load_lsm() -> ctypes.CDLL:

@@ -5,7 +5,7 @@ The stack observes latency (trace), resources (metrics/hbm), quality
 (obs/quality.py) and pressure (obs/pressure.py) — this module observes
 *state*: whether the bytes an index actually serves from still match what
 was written. One region's data lives simultaneously as SlotStore rows,
-sq8 codes, a dimension-blocked scan mirror, an HNSW adjacency mirror and
+sq8 codes, a dimension-blocked scan mirror, an HNSW adjacency and
 an IVF bucket arrangement; silent drift between any of them (a scatter
 bug, a bad restore, flipped HBM) is the failure mode nothing else
 catches.
@@ -62,11 +62,11 @@ SCRUB_CHUNK = 65536
 SNAPSHOT_ARTIFACTS = ("rows", "adjacency", "ivf_buckets", "pq_codes")
 
 #: artifacts EXCLUDED from the heartbeat digest vector the coordinator
-#: compares across replicas: the adjacency ledger is rewritten by the
-#: LAZY device-mirror re-export (search-timing-driven, not raft-ordered),
-#: so two healthy replicas at the same applied index can legitimately
-#: hold different adjacency digests — comparing them would read pure
-#: staleness as divergence. The scrub (adjacency_in_sync-gated) and the
+#: compares across replicas: the adjacency ledger is stale between a
+#: write and the replica's next save, which re-seeds it whole (crontab-
+#: driven, not raft-ordered), so two healthy replicas at the same applied
+#: index can legitimately hold different adjacency digests — comparing
+#: them would read pure staleness as divergence. The scrub (adjacency_in_sync-gated) and the
 #: snapshot meta still cover the artifact.
 HEARTBEAT_EXCLUDED = frozenset({"adjacency"})
 
@@ -456,8 +456,9 @@ class IntegrityPlane:
     def _state_arms(self, index) -> Dict[str, Any]:
         """Artifact -> chunk-iterator factory for everything the index's
         CURRENT device/host state materializes. Adjacency and bucket arms
-        only appear while their mirror/view is in sync with the store —
-        a pending lazy re-export is staleness, not corruption."""
+        only appear while their ledger/view is in sync with the store —
+        an adjacency ledger waiting for the next save's re-seed, or a
+        pending lazy view rebuild, is staleness, not corruption."""
         arms: Dict[str, Any] = {}
         store = getattr(index, "store", None)
         if store is None or getattr(store, "ids_by_slot", None) is None:
@@ -527,7 +528,7 @@ class IntegrityPlane:
             led = self.ledger(index)
         rep = led.report()["artifacts"]
         # only artifacts whose backing state is CURRENT may persist: a
-        # stale adjacency ledger (mirror pending re-export) must not gate
+        # stale adjacency ledger (writes since its last seeding) must not gate
         # the restore against bytes the snapshot never carried
         valid = set(self._state_arms(index))
         if getattr(index, "_assign_h", None) is not None \

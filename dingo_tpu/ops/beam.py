@@ -1,8 +1,8 @@
 """Batched lockstep beam search over a device-resident graph index.
 
-TPU-native HNSW serving (ROADMAP item 5 / ISSUE 8 tentpole): the host
-C++ graph (native/hnsw) walks pointers one query at a time; this kernel
-walks hundreds of queries in lockstep over the FLATTENED level-0
+TPU-native HNSW serving (ROADMAP item 5 / ISSUE 8 tentpole): a pointer
+graph walks one query at a time; this kernel walks hundreds of queries
+in lockstep over the FLATTENED level-0
 adjacency — a dense ``[capacity, deg]`` int32 array in slot space
 (SlotStore.adj) — so every step is regular gather + matmul + masked
 top-k work the MXU/VPU are built for:
@@ -50,8 +50,7 @@ exists. Unfiltered searches pass the validity mask for both and the two
 lists coincide.
 
 Returned slots are UNORDERED evidence: the caller reranks them with the
-exact device rerank (ops/rerank.py) so final ordering is byte-identical
-with the host graph path whenever the candidate sets agree.
+exact device rerank (ops/rerank.py), which sets the final ordering.
 """
 
 from __future__ import annotations

@@ -38,7 +38,7 @@ insert batch at a time:
                         walk rediscovers those neighborhoods.
 
 Live insertion: ``insert_batch`` reads the adjacency as it stands, so it
-serves the bulk build (from empty) and the TPU arm's ``upsert`` into a
+serves the bulk build (from empty) and ``upsert`` into a
 graph that is being searched (index/hnsw.py ``_device_insert``) alike;
 ``ladder_batches`` cuts either's rows into the same pow2 ladder.
 
@@ -329,8 +329,8 @@ def insert_batch(adj, vecs, sqnorm, valid, batch_slots, entry, vmin,
 class BulkGraphBuilder:
     """Accumulates store slots into pow2 insert batches and maintains the
     under-construction adjacency as a device array. Pure slot/store
-    level: index-level concerns (row puts, integrity ledgers, native
-    back-fill) live in index/hnsw.py's bulk session.
+    level: index-level concerns (row puts, integrity ledgers) live in
+    index/hnsw.py's bulk session.
 
     Not thread-safe; one builder per build. Flushes take
     store.device_lock (the vecs/sqnorm references are donatable by

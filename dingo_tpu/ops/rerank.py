@@ -23,9 +23,8 @@ descending) so they drop in right after any scan kernel:
   sq_rerank_device      — the sq8 tier's exact-for-the-tier rerank:
                           candidates gather as uint8 codes, decode to the
                           bf16 surrogate in-kernel, and score with f32
-                          accumulation. Used by the HNSW device/host graph
-                          paths so both produce the same final ordering
-                          from the same candidate set; chain
+                          accumulation. Ends the HNSW sq8 tier's
+                          search; chain
                           cached_rerank_device after it to upgrade cached
                           rows to true f32-exact scores.
 

@@ -1,12 +1,12 @@
 """Device-side bulk index construction (ISSUE 18): batched MXU graph
 build, streaming rebuild, shared train-sample conf.
 
-The host insert loop stays the parity oracle: a device-built graph must
-reach at least the host-built graph's recall at equal ef, build
-byte-identically under a fixed seed, keep steady-state recompiles at
-zero across the insert ladder, and hand over cleanly to the native
-graph (back-fill) when the host path needs it. The manager build must
-stream scan chunks — peak host memory O(chunk), not O(corpus).
+numpy's exact top-k is the oracle: a bulk-built graph must reach the
+recall the retired native insert loop's graph reached at equal ef
+(ISSUE 33 measured it on this corpus), build byte-identically under a
+fixed seed, keep steady-state recompiles at zero across the insert
+ladder, and take ordinary upserts and deletes afterwards. The manager
+build must stream scan chunks — peak host memory O(chunk), not O(corpus).
 """
 
 import numpy as np
@@ -21,8 +21,6 @@ from dingo_tpu.ops.distance import Metric
 @pytest.fixture(autouse=True)
 def _restore_flags():
     yield
-    FLAGS.set("hnsw_device_build", "auto")
-    FLAGS.set("hnsw_device_search", "auto")
     FLAGS.set("hnsw_build_batch", 256)
     FLAGS.set("hnsw_build_alpha", 1.0)
     FLAGS.set("train_sample_rows", 65536)
@@ -68,7 +66,6 @@ def recall(res, want, k=10):
 def bulk_build(rid, ids, x, chunk=500, **param_kw):
     """Build an index through the bulk device session in scan-sized
     chunks (the manager feed pattern)."""
-    FLAGS.set("hnsw_device_build", True)
     idx = new_index(rid, hnsw_param(**param_kw))
     sess = idx.bulk_builder(expect_rows=len(ids))
     assert sess is not None
@@ -78,29 +75,30 @@ def bulk_build(rid, ids, x, chunk=500, **param_kw):
     return idx
 
 
+#: recall@10 at ef 128 of the device walk over the graph the retired
+#: native insert loop built on `corpus` (the parent of PR 33)
+HOST_BUILT_RECALL = {
+    (Metric.L2, "fp32"): 0.99, (Metric.L2, "sq8"): 0.97,
+    (Metric.INNER_PRODUCT, "fp32"): 1.0, (Metric.INNER_PRODUCT, "sq8"): 1.0,
+    (Metric.COSINE, "fp32"): 1.0, (Metric.COSINE, "sq8"): 0.98,
+}
+
+
 @pytest.mark.parametrize("metric", [Metric.L2, Metric.INNER_PRODUCT,
                                     Metric.COSINE])
 @pytest.mark.parametrize("tier", ["fp32", "sq8"])
-def test_device_built_recall_at_least_host_built(corpus, metric, tier):
-    """The acceptance gate: searching a DEVICE-built graph reaches at
-    least the recall of searching a HOST-built graph at equal ef, per
-    metric x precision tier (both arms use the device walk, so only the
-    construction differs)."""
+def test_bulk_built_recall_against_exact_topk(corpus, metric, tier):
+    """The acceptance gate: searching a bulk-built graph reaches, against
+    numpy's exact top-k, the recall the native-built graph reached at
+    equal ef, per metric x precision tier."""
     ids, x, q = corpus
     dev = bulk_build(60, ids, x, metric=metric, precision=tier)
-    FLAGS.set("hnsw_device_build", False)
-    host = new_index(61, hnsw_param(metric=metric, precision=tier))
-    host.add(ids, x)
     want = exact_topk(x, ids, q, 10, metric)
-    FLAGS.set("hnsw_device_search", True)
-    r_host = recall(host.search(q, 10, ef=128), want)
     r_dev = recall(dev.search(q, 10, ef=128), want)
-    # sq8 arms quantize the candidate scores during construction, so the
-    # two graphs see slightly different geometry — allow the noise floor
-    tol = 1e-9 if tier == "fp32" else 0.05
-    assert r_dev >= r_host - tol
-    if metric is Metric.L2 and tier == "fp32":
-        assert r_dev >= 0.9     # the built graph actually routes
+    # sq8 quantizes the candidate scores during construction, so two
+    # builders see slightly different geometry — allow the noise floor
+    tol = 1e-4 if tier == "fp32" else 0.05
+    assert r_dev >= HOST_BUILT_RECALL[metric, tier] - tol
 
 
 def test_adjacency_byte_stable_under_fixed_seed(corpus):
@@ -115,38 +113,27 @@ def test_adjacency_byte_stable_under_fixed_seed(corpus):
     assert a._entry_slot == b._entry_slot
 
 
-def test_incremental_insert_parity_after_bulk_build(corpus):
-    """First host-path write back-fills the native graph from the store
-    (O(chunk) replays), after which ordinary incremental upsert/delete
-    and both search paths behave exactly as on a host-built index.
-    This is the CPU arm (`hnsw.device_search` is off at the write): with
-    both gates on a write goes into the device adjacency and nothing is
-    back-filled (tests/test_hnsw_one_graph.py)."""
+def test_incremental_insert_after_bulk_build(corpus):
+    """A bulk-built adjacency is the live graph: ordinary upserts insert
+    into it, deletes tombstone in it, and the corpus it was built from
+    stays served."""
     ids, x, q = corpus
     rng = np.random.default_rng(5)
     idx = bulk_build(64, ids, x)
-    assert idx._native_pending
     # a second bulk session on a non-empty index must refuse
     assert idx.bulk_builder() is None
-    bf = METRICS.counter("build.backfills", region_id=64)
-    bf0 = bf.get()
+    adj_shape = idx.store.adj.shape
     extra = rng.standard_normal((60, 32)).astype(np.float32)
     eids = np.arange(len(ids), len(ids) + 60, dtype=np.int64)
-    idx.upsert(eids, extra)       # triggers the back-fill, then inserts
-    assert bf.get() == bf0 + 1
-    assert not idx._native_pending
-    FLAGS.set("hnsw_device_search", True)
+    idx.upsert(eids, extra)
+    assert idx.store.adj.shape == adj_shape
     res = idx.search(extra[:10], 1, ef=64)
     hit = np.mean([len(r.ids) and r.ids[0] == w
                    for r, w in zip(res, eids[:10])])
     assert hit >= 0.9
-    # host path serves the same corpus post-back-fill
-    FLAGS.set("hnsw_device_search", False)
     want = exact_topk(x, ids, q, 10, Metric.L2)
     assert recall(idx.search(q, 10, ef=128), want) >= 0.9
-    # deletes flow through both representations
     idx.delete(eids)
-    FLAGS.set("hnsw_device_search", True)
     for r in idx.search(extra[:5], 5, ef=64):
         assert (r.ids < len(ids)).all()
 
@@ -164,15 +151,15 @@ def test_zero_steady_state_recompiles_across_ladder(corpus):
 
 
 def test_save_load_after_bulk_build(tmp_path, corpus):
-    """save() back-fills first, so the snapshot carries a complete native
-    blob and the restored index serves without knowing the build arm."""
+    """The snapshot carries the built adjacency itself and the restored
+    index serves without knowing how its graph was built."""
     ids, x, q = corpus
     idx = bulk_build(67, ids[:600], x[:600])
     idx.save(str(tmp_path))
-    assert not idx._native_pending
+    assert sorted(p.name for p in tmp_path.iterdir()) == [
+        "hnsw_adj.npz", "hnsw_vectors.npz", "meta.json"]
     idx2 = new_index(67, hnsw_param())
     idx2.load(str(tmp_path))
-    FLAGS.set("hnsw_device_search", True)
     want = exact_topk(x[:600], ids[:600], q, 10, Metric.L2)
     assert recall(idx2.search(q, 10, ef=128), want) >= 0.9
 
@@ -253,17 +240,15 @@ def test_manager_build_streams_bounded_chunks(monkeypatch):
     assert [r.ids[0] for r in res] == [0, 1]
 
 
-def test_manager_build_uses_bulk_device_arm():
-    """With the crossover forced on, manager.build_index constructs the
-    HNSW graph through the device bulk session (build.device_builds) and
-    the result serves both paths."""
+def test_manager_build_uses_bulk_session():
+    """manager.build_index constructs the HNSW graph through the device
+    bulk session (build.device_builds) and the result serves."""
     from dingo_tpu.index.manager import VectorIndexManager
 
     raw, engine, storage, region = _make_stack(71)
     rng = np.random.default_rng(3)
     x = rng.standard_normal((900, 16)).astype(np.float32)
     storage.vector_add(region, np.arange(900, dtype=np.int64), x)
-    FLAGS.set("hnsw_device_build", True)
     db = METRICS.counter("build.device_builds", region_id=71)
     db0 = db.get()
     mgr = VectorIndexManager(raw)
@@ -286,7 +271,6 @@ def test_remat_override_goes_through_bulk_path():
     rng = np.random.default_rng(4)
     x = rng.standard_normal((600, 16)).astype(np.float32)
     storage.vector_add(region, np.arange(600, dtype=np.int64), x)
-    FLAGS.set("hnsw_device_build", True)
     db = METRICS.counter("build.device_builds", region_id=72)
     db0 = db.get()
     override = DeviceRecoveryPlane.remat_parameter(

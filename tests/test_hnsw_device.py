@@ -1,26 +1,20 @@
 """Device graph tier (ISSUE 8): batched beam-search HNSW on the device.
 
-Host C++ graph path = parity oracle: the device walk must reach at least
-the host path's recall at equal ef, adjacency must stay in sync across
-upserts/deletes, the ef/beam shape-bucket ladder must keep steady-state
-recompiles at zero, the filter pushdown must match the host post-filter,
-and the adjacency must survive a snapshot round-trip.
+numpy's exact top-k is the oracle: the walk over the graph its own
+inserts built must reach the recall the retired native graph reached at
+equal ef (ISSUE 33 measured it on this corpus, per metric and tier),
+upserts and deletes must show in the next search with nothing rebuilt,
+the ef/beam shape-bucket ladder must keep steady-state recompiles at
+zero, the filter pushdown must return only eligible rows at the
+unfiltered recall, and the adjacency must survive a snapshot round-trip.
 """
 
 import numpy as np
 import pytest
 
-from dingo_tpu.common.config import FLAGS
 from dingo_tpu.common.metrics import METRICS
 from dingo_tpu.index import FilterSpec, IndexParameter, IndexType, new_index
 from dingo_tpu.ops.distance import Metric
-
-
-@pytest.fixture(autouse=True)
-def _restore_flags():
-    yield
-    FLAGS.set("hnsw_device_search", "auto")
-    FLAGS.set("hnsw_device_beam", 0)
 
 
 @pytest.fixture(scope="module")
@@ -60,63 +54,48 @@ def recall(res, want, k=10):
     ))
 
 
+#: recall@10 at ef 96 of the retired native graph on `corpus` (the parent
+#: of PR 33, host arm): what the one graph has to reach. The quantized
+#: tiers are bounded by their codes, not by the graph
+HOST_RECALL = {
+    (Metric.L2, "fp32"): 1.0, (Metric.L2, "bf16"): 1.0,
+    (Metric.L2, "sq8"): 0.9666,
+    (Metric.INNER_PRODUCT, "fp32"): 1.0,
+    (Metric.INNER_PRODUCT, "bf16"): 1.0,
+    (Metric.INNER_PRODUCT, "sq8"): 0.9916,
+    (Metric.COSINE, "fp32"): 1.0, (Metric.COSINE, "bf16"): 0.9916,
+    (Metric.COSINE, "sq8"): 0.975,
+}
+
+
 @pytest.mark.parametrize("metric", [Metric.L2, Metric.INNER_PRODUCT,
                                     Metric.COSINE])
 @pytest.mark.parametrize("tier", ["fp32", "bf16", "sq8"])
-def test_device_recall_at_least_host(corpus, metric, tier):
-    """The acceptance gate: device beam recall@10 >= host recall at equal
-    ef, per metric x precision tier."""
+def test_recall_against_exact_topk(corpus, metric, tier):
+    """The acceptance gate: recall@10 against numpy's exact top-k at ef
+    96, per metric x precision tier, at what the native graph met."""
     ids, x, q = corpus
     idx = new_index(30, hnsw_param(metric=metric, precision=tier))
     idx.add(ids, x)
     want = exact_topk(x, ids, q, 10, metric)
-    FLAGS.set("hnsw_device_search", False)
-    r_host = recall(idx.search(q, 10, ef=96), want)
-    FLAGS.set("hnsw_device_search", True)
-    r_dev = recall(idx.search(q, 10, ef=96), want)
-    assert r_dev >= r_host - 1e-9
-    if metric is Metric.L2:
-        assert r_dev >= 0.9     # the walk actually finds neighbors
+    assert recall(idx.search(q, 10, ef=96), want) \
+        >= HOST_RECALL[metric, tier] - 1e-4
 
 
-def test_device_final_order_matches_host_on_agreeing_sets(corpus):
-    """Both paths end in the SAME exact device rerank: when recall is
-    saturated the final id ordering is byte-identical."""
-    ids, x, q = corpus
-    idx = new_index(31, hnsw_param())
-    idx.add(ids, x)
-    FLAGS.set("hnsw_device_search", False)
-    host = idx.search(q, 10, ef=128)
-    FLAGS.set("hnsw_device_search", True)
-    dev = idx.search(q, 10, ef=128)
-    want = exact_topk(x, ids, q, 10, Metric.L2)
-    if recall(host, want) == 1.0 and recall(dev, want) == 1.0:
-        for a, b in zip(host, dev):
-            np.testing.assert_array_equal(a.ids, b.ids)
-            np.testing.assert_allclose(a.distances, b.distances,
-                                       rtol=1e-6, atol=1e-5)
-
-
-def test_incremental_upsert_delete_adjacency_sync(corpus):
-    """Writes dirty the mirror; the next device search re-exports and the
-    walk sees the new/removed rows. The CPU arm's mirror
-    (`hnsw.device_build` is off here, so the native graph takes the
-    writes): the TPU arm re-exports nothing
-    (tests/test_hnsw_one_graph.py)."""
+def test_incremental_upsert_delete_show_in_next_search(corpus):
+    """A write goes into the live adjacency: the next search finds the new
+    rows and misses the removed ones, and nothing is rebuilt or exported
+    in between."""
     ids, x, q = corpus
     idx = new_index(32, hnsw_param())
     idx.add(ids[:2000], x[:2000])
-    FLAGS.set("hnsw_device_search", True)
     rb = METRICS.counter("hnsw.adjacency_rebuilds", region_id=32)
-    idx.search(q, 10, ef=64)
     rb0 = rb.get()
-    # repeated read-only searches must NOT re-export
+    adj_before = idx.store.adj
     idx.search(q, 10, ef=64)
-    assert rb.get() == rb0
-    # new rows become findable after one search-triggered resync
+    assert idx.store.adj is adj_before      # a search installs nothing
     idx.upsert(ids[2000:2300], x[2000:2300])
     res = idx.search(x[2000:2300:30], 1, ef=64)
-    assert rb.get() == rb0 + 1
     hit = np.mean([
         len(r.ids) and r.ids[0] == want_id
         for r, want_id in zip(res, ids[2000:2300:30])
@@ -126,8 +105,8 @@ def test_incremental_upsert_delete_adjacency_sync(corpus):
     idx.delete(ids[:500])
     res = idx.search(q, 20, ef=128)
     for r in res:
-        assert (r.ids >= 500).all()
-    assert rb.get() == rb0 + 2
+        assert len(r.ids) == 20 and (r.ids >= 500).all()
+    assert rb.get() == rb0
 
 
 def test_steady_state_recompiles_zero_under_ladder(corpus):
@@ -137,7 +116,6 @@ def test_steady_state_recompiles_zero_under_ladder(corpus):
     ids, x, q = corpus
     idx = new_index(33, hnsw_param())
     idx.add(ids, x)
-    FLAGS.set("hnsw_device_search", True)
     idx.warmup(batches=(1, 8, 32), topk=10, ef=64)
     rc = METRICS.counter("xla.recompiles")
     rc0 = rc.get()
@@ -147,35 +125,32 @@ def test_steady_state_recompiles_zero_under_ladder(corpus):
 
 
 def test_filter_pushdown_equivalence(corpus):
-    """Masked candidates never enter the result beam: device results
-    satisfy the filter, recall matches the host post-filter path, and the
-    second identical filter hits the (fingerprint, store version) cache."""
+    """Masked candidates never enter the result beam: results satisfy the
+    filter, recall against numpy's exact top-k over the eligible rows is
+    what the native graph's post-filter met here (1.0), and the second
+    identical filter hits the (fingerprint, store version) cache."""
     ids, x, q = corpus
     idx = new_index(34, hnsw_param())
     idx.add(ids, x)
     spec = FilterSpec(ranges=[(500, 1500)])
     sub = (ids >= 500) & (ids < 1500)
     want = exact_topk(x[sub], ids[sub], q, 10, Metric.L2)
-    FLAGS.set("hnsw_device_search", False)
-    r_host = recall(idx.search(q, 10, spec, ef=160), want)
-    FLAGS.set("hnsw_device_search", True)
     hits = METRICS.counter("hnsw.filter_mask_hits", region_id=34)
     h0 = hits.get()
     res = idx.search(q, 10, spec, ef=160)
     for r in res:
         assert ((r.ids >= 500) & (r.ids < 1500)).all()
-    assert recall(res, want) >= r_host - 1e-9
+    assert recall(res, want) >= 1.0 - 1e-9
     idx.search(q, 10, spec, ef=160)
     assert hits.get() > h0
 
 
 def test_snapshot_roundtrip_adjacency(tmp_path, corpus):
-    """hnsw_adj.npz + meta restore the device mirror without a native
-    re-export, and the restored index serves identical device results."""
+    """hnsw_adj.npz + meta restore the adjacency, and the restored index
+    serves identical results."""
     ids, x, q = corpus
     idx = new_index(35, hnsw_param())
     idx.add(ids[:2000], x[:2000])
-    FLAGS.set("hnsw_device_search", True)
     before = idx.search(q, 10, ef=96)
     idx.save(str(tmp_path))
     idx2 = new_index(35, hnsw_param())
@@ -184,10 +159,7 @@ def test_snapshot_roundtrip_adjacency(tmp_path, corpus):
     np.testing.assert_array_equal(
         np.asarray(idx.store.adj), np.asarray(idx2.store.adj)
     )
-    rb = METRICS.counter("hnsw.adjacency_rebuilds", region_id=35)
-    rb0 = rb.get()
     after = idx2.search(q, 10, ef=96)
-    assert rb.get() == rb0      # mirror restored from the snapshot
     for a, b in zip(before, after):
         np.testing.assert_array_equal(a.ids, b.ids)
 
@@ -198,7 +170,6 @@ def test_sq8_snapshot_keeps_codes(tmp_path, corpus):
     ids, x, q = corpus
     idx = new_index(36, hnsw_param(precision="sq8"))
     idx.add(ids[:1500], x[:1500])
-    FLAGS.set("hnsw_device_search", True)
     before = idx.search(q, 10, ef=96)
     idx.save(str(tmp_path))
     idx2 = new_index(36, hnsw_param(precision="sq8"))
@@ -209,21 +180,32 @@ def test_sq8_snapshot_keeps_codes(tmp_path, corpus):
 
 
 def test_entry_tombstone_falls_back(corpus):
-    """Deleting most of the graph (possibly including the entry node)
-    still leaves the device walk serving the remaining rows."""
+    """Deleting the entry row, then most of the graph, leaves the walk
+    serving the remaining rows: the entry moves to a live slot (at the
+    parent of PR 33 the device arm answered empty from here on). With no
+    live row left the index answers empty, and serves again after the
+    next write."""
     ids, x, q = corpus
     idx = new_index(37, hnsw_param())
     idx.add(ids[:300], x[:300])
+    entry_id = int(idx.store.ids_by_slot[idx._entry_slot])
+    idx.delete(np.asarray([entry_id], np.int64))
+    assert idx._entry_slot >= 0 and idx.store.valid_h[idx._entry_slot]
+    assert all(len(r.ids) == 5 for r in idx.search(q, 5, ef=64))
     idx.delete(ids[:250])
-    FLAGS.set("hnsw_device_search", True)
     res = idx.search(q, 5, ef=64)
     for r in res:
         assert len(r.ids) > 0
         assert ((r.ids >= 250) & (r.ids < 300)).all()
+    idx.delete(ids[250:300])
+    assert idx._entry_slot == -1
+    assert all(len(r.ids) == 0 for r in idx.search(q, 5, ef=64))
+    idx.upsert(ids[300:400], x[300:400])
+    res = idx.search(x[300:304], 1, ef=64)
+    assert [int(r.ids[0]) for r in res] == [300, 301, 302, 303]
 
 
 def test_device_empty_index(corpus):
-    FLAGS.set("hnsw_device_search", True)
     idx = new_index(38, hnsw_param())
     res = idx.search(np.zeros((2, 32), np.float32), 5)
     assert all(len(r.ids) == 0 for r in res)

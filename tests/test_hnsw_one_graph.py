@@ -1,9 +1,8 @@
-"""HNSW's TPU arm keeps ONE graph, the device adjacency (ISSUE 31).
+"""HNSW keeps ONE graph, the device adjacency (ISSUE 31; on every backend
+since ISSUE 33).
 
-The arm is forced here the way test_hnsw_device.py and test_graph_build.py
-force theirs (tier-1 runs on the CPU, where both `auto` gates read "host").
-Writes go into the live adjacency through ops/graph_build.insert_batch, no
-native graph is fed, exported or searched, save/load persist the adjacency
+Writes go into the live adjacency through ops/graph_build.insert_batch,
+nothing else is fed, exported or searched, save/load persist the adjacency
 itself, and the device walk + exact rerank agree with the plain numpy
 reference (tests/ref_graph_walk.py) on the same adjacency.
 """
@@ -15,7 +14,6 @@ import time
 import numpy as np
 import pytest
 
-from dingo_tpu.common.config import FLAGS
 from dingo_tpu.common.metrics import METRICS
 from dingo_tpu.index import IndexParameter, IndexType, new_index
 
@@ -25,15 +23,6 @@ import ref_graph_walk as ref  # noqa: E402
 M, EF, K = 32, 200, 10
 #: the limit benchmark/reference.py holds `dist_err` to
 DIST_TOL = 5e-6
-
-
-@pytest.fixture(autouse=True)
-def _tpu_arm():
-    FLAGS.set("hnsw_device_search", True)
-    FLAGS.set("hnsw_device_build", True)
-    yield
-    FLAGS.set("hnsw_device_search", "auto")
-    FLAGS.set("hnsw_device_build", "auto")
 
 
 def clustered(n, d, seed, queries=32):
@@ -70,9 +59,7 @@ def built_by_upserts(rid, x, batch=1000):
 
 @pytest.fixture(scope="module")
 def small():
-    """4,096 x 64-d built by upsert batches on the TPU arm alone."""
-    FLAGS.set("hnsw_device_search", True)
-    FLAGS.set("hnsw_device_build", True)
+    """4,096 x 64-d built by upsert batches."""
     x, q = clustered(4096, 64, seed=31)
     return built_by_upserts(310, x), x, q
 
@@ -136,7 +123,7 @@ def test_bf16_rerank_fails_the_distance_tolerance(small):
 
 def test_read_your_writes_without_rebuild(small):
     """64 fresh rows, then each is its own nearest neighbour in the next
-    search; nothing is re-exported and nothing native is fed."""
+    search; the counters of the retired native arm stay 0."""
     idx, x, _ = small
     before = counters(idx.id)
     rng = np.random.default_rng(5)
@@ -160,7 +147,7 @@ def test_read_your_writes_without_rebuild(small):
 
 def test_save_load_serves_the_device_graph(tmp_path, small):
     """save -> new index object -> load: the same replies, the adjacency
-    itself on disk, no native blob written and no native graph made."""
+    itself on disk and no other graph file."""
     idx, x, q = small
     want = idx.search(q, K, ef=EF)
     idx.save(str(tmp_path))
@@ -174,9 +161,6 @@ def test_save_load_serves_the_device_graph(tmp_path, small):
         np.testing.assert_allclose(a.distances, b.distances, rtol=1e-6)
     assert counters(idx.id) == dict(
         before, device_searches=before["device_searches"] + 1)
-    from dingo_tpu.index.hnsw import _lib
-
-    assert int(_lib().hnsw_total_count(again._graph)) == 0
     # and it takes writes into the loaded graph
     row = x[:1] + 0.3
     again.upsert(np.asarray([10**7], np.int64), row)
@@ -232,19 +216,118 @@ def test_max_elements_sizes_the_graph_at_creation(tmp_path, small):
     assert hnsw(315, 64).store.capacity < 16384
 
 
-def test_cpu_arm_still_backfills_a_device_graph(small):
-    """The native graph stays the CPU arm and the parity oracle: a host
-    search over a device-owned graph replays the rows into it first."""
+# -- what ISSUE 33 took away, and what it has to keep loading -----------------
+
+def _as_native_arm_snapshot(path, rng):
+    """Rewrite a snapshot into the form the native arm wrote before PR 33:
+    node space in the native graph's own order (not slot order) with a
+    tombstoned node whose row is gone, meta without `device_graph` /
+    `entry_slot`, and the native blob beside them."""
+    import json
+
+    snap = np.load(os.path.join(path, "hnsw_adj.npz"))
+    labels, adj = snap["labels"], snap["adj"]
+    n = len(labels)
+    perm = rng.permutation(n)                 # new node i = old node perm[i]
+    inv = np.empty(n, np.int64)
+    inv[perm] = np.arange(n)
+    adj = np.where(adj >= 0, inv[np.maximum(adj, 0)], -1)[perm]
+    # a node the native graph still held for a deleted row: it has edges,
+    # and a live node points at it
+    adj = np.concatenate([adj, adj[:1]]).astype(np.int32)
+    adj[0, -1] = n
+    labels = np.concatenate([labels[perm], [10**9]]).astype(np.int64)
+    np.savez(os.path.join(path, "hnsw_adj.npz"), labels=labels, adj=adj)
+    with open(os.path.join(path, "meta.json")) as f:
+        meta = json.load(f)
+    graph = meta["hnsw_graph"]
+    meta["hnsw_graph"] = {"deg": graph["deg"], "nodes": n + 1,
+                          "entry_label": graph["entry_label"]}
+    with open(os.path.join(path, "meta.json"), "w") as f:
+        json.dump(meta, f)
+    with open(os.path.join(path, "hnsw_graph.bin"), "wb") as f:
+        f.write(b"not a graph")
+
+
+def test_native_arm_snapshot_loads_and_serves(tmp_path, small):
+    """A snapshot the native arm wrote carries the same labels +
+    level-0 adjacency: load installs it, ignores the blob, and the index
+    answers at the source's recall against numpy."""
+    idx, x, q = small
+    idx.save(str(tmp_path))
+    _as_native_arm_snapshot(str(tmp_path), np.random.default_rng(7))
+    again = hnsw(idx.id, x.shape[1])
+    again.load(str(tmp_path))
+    assert again.get_count() == len(x) and again.adjacency_in_sync()
+    got = again.search(q, K, ef=EF)
+    hits = sum(len(set(r.ids.tolist())
+                   & set(ref.exact_topk(qi, x, K)[0].tolist()))
+               for qi, r in zip(q, got))
+    assert hits / (K * len(q)) >= 0.95
+    # the next save leaves the form every backend writes now
+    again.save(str(tmp_path))
+    assert not os.path.exists(tmp_path / "hnsw_graph.bin")
+
+
+def test_snapshot_without_adjacency_is_refused(tmp_path, small):
+    """No usable adjacency (absent, or of another degree): load raises
+    and the manager's rebuild from the engine takes over, as for a
+    device snapshot without one before."""
+    from dingo_tpu.index.base import InvalidParameter
+
+    idx, x, _ = small
+    idx.save(str(tmp_path))
+    other = new_index(idx.id, IndexParameter(
+        index_type=IndexType.HNSW, dimension=x.shape[1], nlinks=M // 2,
+        efconstruction=EF))
+    with pytest.raises(InvalidParameter):
+        other.load(str(tmp_path))
+    os.remove(tmp_path / "hnsw_adj.npz")
+    with pytest.raises(InvalidParameter):
+        hnsw(idx.id, x.shape[1]).load(str(tmp_path))
+
+
+def test_graph_index_builds_and_opens_no_shared_object(tmp_path, small,
+                                                       monkeypatch):
+    """`dingo_tpu.native` serves the LSM engine only: an HNSW round trip
+    (write, search, save, load, search) never asks it for a library."""
+    import dingo_tpu.native as native
+
+    assert [n for n in dir(native) if n.startswith("load_")] == ["load_lsm"]
+
+    def refuse(*a, **kw):
+        raise AssertionError("a graph index asked for a native library")
+
+    monkeypatch.setattr(native, "_build", refuse)
     _, x, q = small
-    idx = built_by_upserts(313, x[:1024], batch=512)
-    assert idx._native_pending
-    FLAGS.set("hnsw_device_search", False)
-    bf = METRICS.counter("build.backfills", region_id=313)
-    bf0 = bf.get()
-    got = idx.search(q[:4], K, ef=EF)
-    assert bf.get() == bf0 + 1 and not idx._native_pending
-    assert counters(313)["native_adds"] == 1024
-    assert all(len(r.ids) == K for r in got)
+    idx = built_by_upserts(318, x[:512], batch=256)
+    want = idx.search(q[:4], K, ef=EF)
+    idx.delete(np.arange(8, dtype=np.int64))
+    idx.save(str(tmp_path))
+    again = hnsw(318, x.shape[1])
+    again.load(str(tmp_path))
+    got = again.search(q[:4], K, ef=EF)
+    assert all(len(r.ids) == K for r in want + got)
+    assert not any(name.startswith("libdingohnsw")
+                   for name in os.listdir(os.path.dirname(native.__file__)))
+
+
+def test_no_arm_flag_and_the_kept_counters_read_zero(small):
+    """No flag chooses a graph: FLAGS and auto_arms() name none, and the
+    three counters the benchmark still reads stay 0 through a write and
+    a search."""
+    from dingo_tpu.common.config import FLAGS, auto_arms
+
+    assert not [n for n in FLAGS.all() if n.startswith("hnsw_device")]
+    assert not [n for n in auto_arms() if n.startswith("hnsw")]
+    idx, x, q = small
+    searches = counters(idx.id)["device_searches"]
+    idx.upsert(np.asarray([10**7 + 1], np.int64), x[:1] + 0.3)
+    idx.search(q[:4], K, ef=EF)
+    c = counters(idx.id)
+    assert c["native_adds"] == c["host_searches"] \
+        == c["adjacency_rebuilds"] == 0
+    assert c["device_searches"] == searches + 1
 
 
 # -- the served path: coordinator + store + SDK ------------------------------
@@ -261,8 +344,6 @@ def served():
     from dingo_tpu.server.rpc import DingoServer
     from dingo_tpu.store.node import StoreNode
 
-    FLAGS.set("hnsw_device_search", True)
-    FLAGS.set("hnsw_device_build", True)
     meta = MemEngine()
     control = CoordinatorControl(meta, replication=1)
     coord = DingoServer()
@@ -304,7 +385,7 @@ def served():
 def test_served_graph_built_by_upserts_only(served):
     """No bulk session, no VectorBuild: the load's own vector_add batches
     build the graph, and the SDK's searches at ef 200 reach the source's
-    recall with nothing served or fed natively."""
+    recall."""
     client, rid, x, q, base = served
     got = client.vector_search(0, q, topk=K, ef_search=EF)
     hits = 0

@@ -242,27 +242,26 @@ def test_scrub_detects_flipped_blocked_mirror_entry():
         FLAGS.set("vector_blocked_layout", was)
 
 
-def test_scrub_detects_flipped_adjacency_entry():
-    was = FLAGS.get("hnsw_device_search")
-    FLAGS.set("hnsw_device_search", "True")
-    try:
-        ids, x = _corpus(seed=9)
-        idx = new_index(24, _param(IndexType.HNSW))
-        idx.upsert(ids, x)
-        idx.search(x[:2], 5)          # installs the device mirror
-        assert idx.adjacency_in_sync()
-        res = INTEGRITY.scrub_index(idx)
-        assert res["adjacency"]["status"] == "ok"
-        # rewire one neighbor entry to a DIFFERENT live slot
-        slots = idx.store.slots_of(ids[:2])
-        _corrupt_device_array(
-            idx.store, "adj",
-            lambda a: a.__setitem__((int(slots[0]), 0), int(slots[1]))
-        )
-        res = INTEGRITY.scrub_index(idx)
-        _assert_detected(idx, "adjacency", res)
-    finally:
-        FLAGS.set("hnsw_device_search", was)
+def test_scrub_detects_flipped_adjacency_entry(tmp_path):
+    ids, x = _corpus(seed=9)
+    idx = new_index(24, _param(IndexType.HNSW))
+    idx.upsert(ids, x)
+    # the adjacency ledger is stale from a write to the next save, and
+    # the scrub leaves the artifact alone meanwhile
+    assert not idx.adjacency_in_sync()
+    assert "adjacency" not in INTEGRITY.scrub_index(idx)
+    idx.save(str(tmp_path))           # re-seeds the ledger
+    assert idx.adjacency_in_sync()
+    res = INTEGRITY.scrub_index(idx)
+    assert res["adjacency"]["status"] == "ok"
+    # rewire one neighbor entry to a DIFFERENT live slot
+    slots = idx.store.slots_of(ids[:2])
+    _corrupt_device_array(
+        idx.store, "adj",
+        lambda a: a.__setitem__((int(slots[0]), 0), int(slots[1]))
+    )
+    res = INTEGRITY.scrub_index(idx)
+    _assert_detected(idx, "adjacency", res)
 
 
 def test_scrub_detects_flipped_ivf_bucket_entry():
@@ -377,29 +376,23 @@ def test_tampered_snapshot_refused(tmp_path, kind, precision, npz, field):
 
 
 def test_tampered_hnsw_adjacency_snapshot_refused(tmp_path):
-    """The PR 8 hnsw_adj.npz arm: the persisted device-graph mirror is
-    digest-gated too."""
-    was = FLAGS.get("hnsw_device_search")
-    FLAGS.set("hnsw_device_search", "True")
-    try:
-        ids, x = _corpus(seed=15)
-        idx = new_index(33, _param(IndexType.HNSW))
-        idx.upsert(ids, x)
-        idx.search(x[:2], 5)       # installs + syncs the mirror pre-save
-        path = str(tmp_path / "snap")
-        idx.save(path)
-        meta = json.load(open(os.path.join(path, "meta.json")))
-        assert "adjacency" in meta["integrity"]
-        data = dict(np.load(os.path.join(path, "hnsw_adj.npz")))
-        adj = data["adj"]
-        r, c = np.argwhere(adj >= 0)[0]
-        adj[r, c] = int(data["labels"][-1])   # rewire to another node
-        np.savez(os.path.join(path, "hnsw_adj.npz"), **data)
-        fresh = new_index(33, _param(IndexType.HNSW))
-        with pytest.raises(SnapshotCorruption):
-            fresh.load(path)
-    finally:
-        FLAGS.set("hnsw_device_search", was)
+    """The PR 8 hnsw_adj.npz arm: the persisted adjacency is digest-gated
+    too."""
+    ids, x = _corpus(seed=15)
+    idx = new_index(33, _param(IndexType.HNSW))
+    idx.upsert(ids, x)
+    path = str(tmp_path / "snap")
+    idx.save(path)
+    meta = json.load(open(os.path.join(path, "meta.json")))
+    assert "adjacency" in meta["integrity"]
+    data = dict(np.load(os.path.join(path, "hnsw_adj.npz")))
+    adj = data["adj"]
+    r, c = np.argwhere(adj >= 0)[0]
+    adj[r, c] = int(data["labels"][-1])   # rewire to another node
+    np.savez(os.path.join(path, "hnsw_adj.npz"), **data)
+    fresh = new_index(33, _param(IndexType.HNSW))
+    with pytest.raises(SnapshotCorruption):
+        fresh.load(path)
 
 
 def test_manager_falls_back_to_rebuild_on_corrupt_snapshot(tmp_path):
@@ -783,25 +776,21 @@ def test_ledger_survives_enabled_toggle():
     FLAGS.set("integrity_enabled", True)
 
 
-def test_adjacency_excluded_from_heartbeat_vector():
-    """The adjacency ledger follows the LAZY mirror re-export (search
-    timing), not the raft order — it must not ride the replica-compared
-    heartbeat vector, while snapshot meta still carries it."""
-    was = FLAGS.get("hnsw_device_search")
-    FLAGS.set("hnsw_device_search", "True")
-    try:
-        ids, x = _corpus(seed=32)
-        idx = new_index(74, _param(IndexType.HNSW))
-        idx.upsert(ids, x)
-        idx.search(x[:2], 5)                 # installs + ledgers the mirror
-        led = INTEGRITY.peek(idx)
-        assert "adjacency" in led.report()["artifacts"]
-        digests = json.loads(led.heartbeat_view()[1])
-        assert "adjacency" not in digests
-        assert "rows" in digests
-        assert "adjacency" in INTEGRITY.snapshot_artifacts(idx)
-    finally:
-        FLAGS.set("hnsw_device_search", was)
+def test_adjacency_excluded_from_heartbeat_vector(tmp_path):
+    """The adjacency ledger is re-seeded by each replica's own save
+    (crontab timing), not in raft order — it must not ride the
+    replica-compared heartbeat vector, while snapshot meta still carries
+    it."""
+    ids, x = _corpus(seed=32)
+    idx = new_index(74, _param(IndexType.HNSW))
+    idx.upsert(ids, x)
+    idx.save(str(tmp_path))              # seeds the adjacency ledger
+    led = INTEGRITY.peek(idx)
+    assert "adjacency" in led.report()["artifacts"]
+    digests = json.loads(led.heartbeat_view()[1])
+    assert "adjacency" not in digests
+    assert "rows" in digests
+    assert "adjacency" in INTEGRITY.snapshot_artifacts(idx)
 
 
 def test_heartbeat_withheld_while_write_in_flight():

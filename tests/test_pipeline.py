@@ -42,7 +42,6 @@ def pipeline_flags():
     yield
     FLAGS.set("pipeline_enabled", "auto")
     FLAGS.set("pipeline_depth", 2)
-    FLAGS.set("hnsw_device_search", "auto")
 
 
 @pytest.fixture(scope="module")
@@ -77,7 +76,6 @@ def _build(family, precision, corpus, idx_id=1):
             index_type=IndexType.HNSW, dimension=D, nlinks=16,
             efconstruction=80, precision=precision))
         idx.add(ids, x)
-        FLAGS.set("hnsw_device_search", True)
     else:  # pragma: no cover
         raise AssertionError(family)
     return idx

@@ -2,8 +2,7 @@
 persistence, device-resident rerank correctness vs the host rerank, and
 the capacity win (device bytes/vector) the tiers exist for.
 
-Scales are test-sized; the bench-operating-point numbers live in
-bench.py's precision_sweep JSON. The pyproject filterwarnings gate
+Scales are test-sized. The pyproject filterwarnings gate
 ("Some donated buffers were not usable" -> error) rides along on every
 device write these tests trigger.
 """

@@ -9,8 +9,7 @@ and the digest gate refuses any destination copy whose recomputed rows
 artifact disagrees with the source ledger before the swap.
 
 The process-kill-mid-transition story lives in tools/chaos.py
-(tier_kill scenario, auto-parametrized by test_chaos.py); the policy
-tick and bench gates in bench.py memory_pressure.
+(tier_kill scenario, auto-parametrized by test_chaos.py).
 """
 
 import numpy as np

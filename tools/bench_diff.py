@@ -1,7 +1,6 @@
-"""Diff two bench.py JSON summaries and flag performance regressions.
-
-Bench summaries were eyeball-only since round 1; this makes a pair of
-them machine-checkable:
+"""Diff two JSON summaries of the retired bench.py's schema and flag
+performance regressions. Nothing in the tree writes that schema since
+PR 33; the tool and its tests wait for their own issue (ROADMAP C11).
 
     python tools/bench_diff.py OLD.json NEW.json \
         [--qps-drop 0.15] [--recall-drop 0.02] [--bytes-grow 0.25] [--json]

@@ -31,8 +31,6 @@ arming) so a failing run replays exactly from its printed seed.
 
 CLI:  python tools/chaos.py [--seed N] [--json] [scenario ...]
       (no scenario args = the full suite)
-Bench: `python bench.py chaos` runs the suite and emits the bench-schema
-JSON consumed by tools/bench_diff.py (recovery_ms / goodput kinds).
 """
 
 from __future__ import annotations

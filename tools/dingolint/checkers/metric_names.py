@@ -94,14 +94,14 @@ FAMILY_NAMES = {
                                     # windowed QPS the planner reads)
     },
     "hnsw": {
-        "hnsw.device_searches",     # device graph-walk searches (PR 8)
-        "hnsw.host_searches",       # native C++ beam fallback searches
-        "hnsw.adjacency_rebuilds",  # level-0 exports into the device
-                                    # mirror (writes dirty it)
-        "hnsw.native_adds",         # rows fed to the native graph (CPU-
-                                    # arm writes, back-fills; 0 on the
-                                    # TPU arm)
-        "hnsw.graph_nodes",         # exported nodes incl. tombstones
+        "hnsw.device_searches",     # graph-walk searches (PR 8)
+        # registered at 0 and never incremented since PR 33 (no native
+        # graph): benchmark/metrics/hnsw_*.json read them, and the
+        # `benchmark` issue that retires those metrics takes these three
+        "hnsw.host_searches",
+        "hnsw.adjacency_rebuilds",
+        "hnsw.native_adds",
+        "hnsw.graph_nodes",         # rows in the adjacency
         "hnsw.mean_hops",           # beam-expansion rounds per walk
         "hnsw.visited_fraction",    # visited-bitmask population / LIVE
                                     # rows
@@ -308,8 +308,6 @@ FAMILY_NAMES = {
         "build.reverse_dropped",    # degree-clamped reverse edges dropped
                                     # (device fold, read once at finish)
         "build.device_builds",      # completed bulk sessions per region
-        "build.backfills",          # native-graph replays on first
-                                    # host-path use after a bulk build
         "build.train_failures",     # manager train() raised; untrained
                                     # fallback installed (was silent)
         "build.remat_rebuilds",     # PR 13 re-materializations riding

@@ -3,10 +3,8 @@
 Input is JSON from any of the ledger's faces:
 
 - the ``events`` section of a flight bundle (``tools/flight_report.py
-  BUNDLE --json | jq .events``),
-- an ``EventDumpResponse`` dumped as a JSON list of event objects, or
-- a bench scenario's ``events`` list (bench.py records the ledger
-  trajectory for the convergence scenarios).
+  BUNDLE --json | jq .events``), or
+- an ``EventDumpResponse`` dumped as a JSON list of event objects.
 
     python tools/event_report.py EVENTS_FILE [--region N] [--actor A] [--json]
 
