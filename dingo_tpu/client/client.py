@@ -6,6 +6,20 @@ README.md:41): keeps a region map from the coordinator, routes requests to
 region leaders, retries on NotLeader errors, and scatter-gathers multi-region
 vector searches client-side (the server returns per-region results only —
 SURVEY.md §5).
+
+Routing. Searches (``vector_search``, ``table_vector_search``) route from
+the region map as it was last fetched and stamp every request with the
+epoch of the definition it was routed from; the store refuses a stale one
+(10002) and a region that is gone answers 10001 from every peer, and
+either makes the SDK fetch the map and route the whole call again. So a
+split, merge, move or drop made by anyone is seen by the next search. What
+the cache cannot see: a region another client ADDS to a partition without
+changing any region this client knows (a hand-made ``create_index_region``
+over a new id range) is found at the next refresh, not at the next search
+— the upstream's SDK is the same — and a dropped table is served until its
+stores have deleted its regions (a heartbeat), unless the meta watch runs.
+Writes, counts, builds, the ``txn_*`` and ``kv_*`` calls fetch the map on
+every call. ``client.region_map_refreshes{cause}`` counts the fetches.
 """
 
 from __future__ import annotations
@@ -19,6 +33,7 @@ import numpy as np
 from dingo_tpu.client import retry as retry_mod
 from dingo_tpu.common.config import grpc_options
 from dingo_tpu.common.coord_channel import RotatingCoordinatorChannel
+from dingo_tpu.common.metrics import METRICS
 from dingo_tpu.index import codec as vcodec
 from dingo_tpu.server import pb
 from dingo_tpu.server.convert import region_def_from_pb, scalar_from_pb
@@ -33,6 +48,16 @@ class ClientError(RuntimeError):
 class _HedgeMiss(ClientError):
     """Internal: the hedged fast path didn't settle the call (stale
     leader hint, follower rejected) — fall back to the rotation loop."""
+
+
+class _StaleRoute(ClientError):
+    """Internal: the cluster refused a request routed from the cached
+    region map (epoch mismatch, or every peer lost the region) — fetch the
+    map and route the whole call again. `cause` labels the refresh."""
+
+    def __init__(self, cause: str, msg: str):
+        super().__init__(msg)
+        self.cause = cause
 
 
 class _CoordServiceFacade:
@@ -64,7 +89,9 @@ class DingoClient:
         self._store_addrs = dict(store_addrs)
         self._retry = retry_mod.RetryPolicy.from_flags(rounds=4)
         self._channels: Dict[str, grpc.Channel] = {}
+        self._stubs: Dict[Tuple[str, str], ServiceStub] = {}
         self._regions: List = []           # RegionDefinition list
+        self._refresh_cause = threading.local()
         self._leader_hint: Dict[int, str] = {}
         self._table_cache: Dict[str, object] = {}
         self._cache_gen = 0   # bumped by every watcher invalidation
@@ -83,15 +110,42 @@ class DingoClient:
             chan = grpc.insecure_channel(
                 self._store_addrs[store_id], options=grpc_options())
             self._channels[store_id] = chan
-        return ServiceStub(chan, service)
+        stub = self._stubs.get((store_id, service))
+        if stub is None:
+            # one multicallable per method of the service: built once
+            stub = self._stubs[(store_id, service)] = ServiceStub(
+                chan, service)
+        return stub
 
     def refresh_region_map(self) -> None:
+        """Fetch the region map from the coordinator. Every fetch, the
+        SDK's own too (`_refresh`), goes through this name."""
+        cause = getattr(self._refresh_cause, "value", None) or "explicit"
+        self._refresh_cause.value = None
+        METRICS.counter("client.region_map_refreshes",
+                        labels={"cause": cause}).add(1)
         resp = self.coordinator.GetRegionMap(pb.GetRegionMapRequest())
         self._regions = [region_def_from_pb(d) for d in resp.regions]
 
+    def _refresh(self, cause: str) -> None:
+        """The SDK's own fetch, counted under `cause` (one of empty,
+        stale_epoch, region_not_found, region_op)."""
+        self._refresh_cause.value = cause
+        self.refresh_region_map()
+
     def _regions_for_vector_ids(self, partition_id: int, refresh: bool = True):
-        if refresh or not self._regions:
+        """The partition's index regions: from a fresh map, or with
+        refresh=False from the cached one, fetched only when it holds
+        nothing for the partition."""
+        if refresh:
             self.refresh_region_map()
+        found = self._index_regions(partition_id)
+        if not found and not refresh:
+            self._refresh("empty")
+            found = self._index_regions(partition_id)
+        return found
+
+    def _index_regions(self, partition_id: int):
         return [
             d for d in self._regions
             if d.partition_id == partition_id and d.index_parameter is not None
@@ -113,7 +167,8 @@ class DingoClient:
         return order
 
     def _call_leader(self, definition, service: str, method: str, req,
-                     retries: int = 4, hedge: bool = False):
+                     retries: int = 4, hedge: bool = False,
+                     cached_route: bool = False):
         """Leader routing with NotLeader retry (SDK behavior), through the
         shared RetryPolicy: grpc never-served failures rotate with
         equal-jitter backoff + per-store circuit breaker, in-band NotLeader
@@ -125,9 +180,20 @@ class DingoClient:
         ``hedge=True`` (idempotent reads only) additionally races a
         second attempt at the next peer after a p99-derived delay when
         retry.hedge_enabled — falling back to the plain rotation loop if
-        the hedged pair can't settle it (stale hint, follower rejects)."""
+        the hedged pair can't settle it (stale hint, follower rejects).
+
+        ``cached_route=True``: `definition` comes from the cached region
+        map, so the request carries its epoch for the store to check, and
+        a refusal (10002), or 10001 from every peer, raises `_StaleRoute`
+        at once instead of rotating; a call that reaches no leader at all
+        drops the cached map, so the next call routes from a fresh one."""
         order = self._leader_order(definition)
         last_store = {}
+        not_found = set()
+        if cached_route:
+            req.context.region_epoch.version = definition.epoch.version
+            req.context.region_epoch.conf_version = \
+                definition.epoch.conf_version
 
         def _attempt(store_id, attempt):
             last_store["id"] = store_id
@@ -146,8 +212,16 @@ class DingoClient:
                 if "/" in hint:
                     self._leader_hint[definition.region_id] = \
                         hint.split("/")[0]
+            if cached_route:
+                if code == 10001:
+                    not_found.add(last_store.get("id"))
+                if code == 10002 or len(not_found) == len(order):
+                    raise _StaleRoute(
+                        "stale_epoch" if code == 10002
+                        else "region_not_found", resp.error.errmsg)
             if code in (20001, 10001):
                 return (retry_mod.ROTATE, resp.error.errmsg)
+            last_store["fatal"] = True
             return (retry_mod.FATAL, resp.error.errmsg)
 
         if hedge and len(order) >= 2 and self._hedge_enabled():
@@ -160,10 +234,16 @@ class DingoClient:
         # NotLeader rotation waits on raft elections (O(100ms)), not on
         # transport blips — scale the round gap to the election, matching
         # the reference SDK's fixed 100ms inter-round sleep
-        return self._retry.call(
-            order, _attempt, classify=_classify, op=method,
-            error_cls=ClientError, idempotent=True, rounds=retries,
-            base_backoff_ms=100.0)
+        try:
+            return self._retry.call(
+                order, _attempt, classify=_classify, op=method,
+                error_cls=ClientError, idempotent=True, rounds=retries,
+                base_backoff_ms=100.0)
+        except ClientError as e:
+            if cached_route and not isinstance(e, _StaleRoute) \
+                    and not last_store.get("fatal"):
+                self._regions = []   # the route reached no leader
+            raise
 
     @staticmethod
     def _hedge_enabled() -> bool:
@@ -185,6 +265,7 @@ class DingoClient:
         resp = self.coordinator.CreateRegion(req)
         if resp.error.errcode:
             raise ClientError(resp.error.errmsg)
+        self._refresh("region_op")
         return region_def_from_pb(resp.definition)
 
     def split_region(self, region_id: int, split_vector_id: int,
@@ -195,6 +276,7 @@ class DingoClient:
         resp = self.coordinator.SplitRegion(req)
         if resp.error.errcode:
             raise ClientError(resp.error.errmsg)
+        self._refresh("region_op")
         return resp.child_region_id
 
     def create_document_region(self, partition_id: int, id_lo: int,
@@ -217,6 +299,7 @@ class DingoClient:
         resp = self.coordinator.CreateRegion(req)
         if resp.error.errcode:
             raise ClientError(resp.error.errmsg)
+        self._refresh("region_op")
         return region_def_from_pb(resp.definition)
 
     def merge_region(self, target_region_id: int,
@@ -228,6 +311,7 @@ class DingoClient:
         ))
         if resp.error.errcode:
             raise ClientError(resp.error.errmsg)
+        self._refresh("region_op")
 
     def change_peer_region(self, region_id: int,
                            new_peers: Sequence[str]) -> None:
@@ -237,6 +321,7 @@ class DingoClient:
         resp = self.coordinator.ChangePeerRegion(req)
         if resp.error.errcode:
             raise ClientError(resp.error.errmsg)
+        self._refresh("region_op")
 
     def transfer_leader_region(self, region_id: int,
                                target_store: str) -> None:
@@ -320,7 +405,7 @@ class DingoClient:
         resp = self.meta.CreateTable(req)
         if resp.error.errcode:
             raise ClientError(resp.error.errmsg)
-        self.refresh_region_map()
+        self._refresh("region_op")
         return resp.definition
 
     def get_table(self, schema: str, name: str, cached: bool = False):
@@ -427,7 +512,7 @@ class DingoClient:
             schema_name=schema, table_name=name))
         if resp.error.errcode:
             raise ClientError(resp.error.errmsg)
-        self.refresh_region_map()
+        self._refresh("region_op")
 
     def table_vector_add(self, table, ids, vectors, scalars=None) -> None:
         """Route rows to the owning partition by id window; ids outside
@@ -544,10 +629,37 @@ class DingoClient:
 
     def _vector_search_budgeted(self, partition_id, queries, topk,
                                 with_scalar_data, params):
-        regions = self._regions_for_vector_ids(partition_id)
-        if not regions:
-            raise ClientError("no index regions")
+        """Route from the cached map; a stale route (`_StaleRoute`) drops
+        what the round gathered, fetches the map and routes the WHOLE call
+        again, so no row of a region that split or merged meanwhile is
+        merged twice or missed. Bounded by the retry policy's rounds."""
         queries = np.asarray(queries, np.float32)
+        regions = self._regions_for_vector_ids(partition_id, refresh=False)
+        rounds = self._retry.rounds
+        stale = None
+        for round_i in range(rounds):
+            if stale is not None:
+                if round_i > 1:
+                    # the first refresh did not cure it: the coordinator
+                    # has not heard of the change yet (a split between the
+                    # store's bump and its report) — wait as a leader
+                    # rotation would
+                    self._retry.backoff(round_i - 1, "VectorSearch",
+                                        ClientError, round_i, base_ms=100.0)
+                self._refresh(stale.cause)
+                regions = self._index_regions(partition_id)
+            if not regions:
+                raise ClientError("no index regions")
+            try:
+                return self._search_regions(regions, queries, topk,
+                                            with_scalar_data, params)
+            except _StaleRoute as e:
+                stale = e
+        raise ClientError(
+            f"VectorSearch: route still stale after {rounds} rounds: {stale}")
+
+    def _search_regions(self, regions, queries, topk, with_scalar_data,
+                        params):
         merged: List[List[Tuple[int, float]]] = [[] for _ in queries]
         # wire convention: L2/HAMMING distances ascend, IP/COSINE similarity
         # descends (ops/distance.py metric_ascending) — merge accordingly
@@ -575,7 +687,7 @@ class DingoClient:
             if "coprocessor" in params:   # pb.Coprocessor (TABLE filter)
                 req.parameter.coprocessor.CopyFrom(params["coprocessor"])
             resp = self._call_leader(d, "IndexService", "VectorSearch", req,
-                                     hedge=True)
+                                     hedge=True, cached_route=True)
             for qi, row in enumerate(resp.batch_results):
                 for item in row.results:
                     merged[qi].append((item.vector.id, item.distance))
@@ -745,5 +857,6 @@ class DingoClient:
     def close(self) -> None:
         self.stop_meta_watch()
         self._coord_channel.close()
+        self._stubs.clear()
         for chan in self._channels.values():
             chan.close()

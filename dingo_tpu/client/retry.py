@@ -187,7 +187,7 @@ class RetryPolicy:
                 f"{op}: deadline budget exhausted after {attempt} attempt(s)"
             )
 
-    def _backoff(self, round_i: int, op: str, error_cls, attempt: int,
+    def backoff(self, round_i: int, op: str, error_cls, attempt: int,
                  base_ms: Optional[float] = None) -> None:
         """Equal-jitter sleep between rotation rounds — d/2 + U(0, d/2):
         the deterministic half guarantees the wait a rotation exists to
@@ -329,7 +329,7 @@ class RetryPolicy:
                     if v is OK or v is None:
                         return resp
             if round_i < rounds - 1:
-                self._backoff(round_i, op, error_cls, attempt,
+                self.backoff(round_i, op, error_cls, attempt,
                               base_ms=base_backoff_ms)
         raise error_cls(f"{op}: retries exhausted: {last_err}")
 

@@ -224,8 +224,14 @@ class CoordinatorControl:
         and return pending region commands (HandleStoreHeartbeatResponse
         flow, store/heartbeat.cc:294)."""
         with self._lock:
+            # a leader that has not yet executed its DELETE still reports
+            # the region: that must not bring a dropped region back
+            deleting = {c.region_id for c in self.store_ops.get(store_id, ())
+                        if c.cmd_type is RegionCmdType.DELETE}
             for rd in region_defs:
                 known = self.regions.get(rd.region_id)
+                if known is None and rd.region_id in deleting:
+                    continue
                 if known is None or rd.epoch.as_tuple() > known.epoch.as_tuple():
                     self.regions[rd.region_id] = rd
                     self._persist(
@@ -913,7 +919,11 @@ class CoordinatorControl:
         """Store reports the applied split; update metadata + epochs."""
         with self._lock:
             parent = self.regions.get(parent_id)
-            if parent is not None:
+            # a heartbeat may have brought the parent's shrunk definition
+            # already: bumping it again would leave the map a version ahead
+            # of the stores for ever, and every epoch-stamped request
+            # (client.py "Routing") refused
+            if parent is not None and parent.end_key != child.start_key:
                 parent.end_key = child.start_key
                 parent.epoch.version += 1
                 self._persist(_PREFIX_REGION + str(parent_id).encode(), parent)

@@ -117,18 +117,21 @@ def _keys_in_region_or_err(region: Region, keys, resp) -> bool:
 
 
 def _region_or_err(node: StoreNode, context_pb, resp) -> Optional[Region]:
+    stamped = context_pb.region_epoch.version
+    if stamped:
+        # routed from an SDK's cached region map (client.py "Routing")
+        METRICS.counter("service.epoch_stamped").add(1)
     region = node.get_region(context_pb.region_id)
     if region is None:
         _err(resp, 10001, f"region {context_pb.region_id} not found")
         return None
-    # epoch check (reference validates region epoch on every request)
-    if (
-        context_pb.region_epoch.version
-        and context_pb.region_epoch.version != region.epoch.version
-    ):
+    # epoch check (reference validates region epoch on every request): the
+    # refusal is what holds an SDK's cached route true — it fetches the map
+    # and routes the call again
+    if stamped and stamped != region.epoch.version:
+        METRICS.counter("service.epoch_refusals").add(1)
         _err(resp, 10002,
-             f"epoch mismatch {context_pb.region_epoch.version} != "
-             f"{region.epoch.version}")
+             f"epoch mismatch {stamped} != {region.epoch.version}")
         return None
     return region
 
@@ -141,8 +144,10 @@ class IndexService:
         self._coalescer = None
         self._coalescer_lock = threading.Lock()
         # rows by the path that decoded them (convert.float_rows_from_pb),
-        # from the start: a path that never ran reads 0, not "no such series"
-        for name in ("decode_wire_rows", "decode_boxed_rows"):
+        # and requests by what `_region_or_err` saw of their epoch, from the
+        # start: a path that never ran reads 0, not "no such series"
+        for name in ("decode_wire_rows", "decode_boxed_rows",
+                     "epoch_stamped", "epoch_refusals"):
             METRICS.counter("service." + name).add(0)
 
     def _get_coalescer(self):
