@@ -50,6 +50,42 @@ class RunFailure(Exception):
     pass
 
 
+class SetupDeadline:
+    """Set-up's own limit, `harness.json` `setup_deadline_s`, counted from
+    the process's start. The driver stops a whole run at 360 s and says
+    nothing of where it stood; a run that cannot open its window in time
+    ends here instead, with the phase named: when a phase is still running
+    at the deadline (an alarm in the main thread: it breaks the blocking
+    `readline` on a child that never answers as well as a sleep or a gRPC
+    wait), or as soon as `aligned_open` lays the window's open past it."""
+
+    def __init__(self, seconds: float, t_start: float = T_START):
+        self.seconds, self.t_start = float(seconds), t_start
+        self.phase = "store_up"     # then region, load, build, warm, align
+
+    def late(self, after_s: float) -> RunFailure:
+        return RunFailure(f"set-up past its deadline of {self.seconds:g} s in "
+                          f"phase {self.phase} after {after_s:.0f} s")
+
+    def _on_alarm(self, signum, frame):
+        raise self.late(time.monotonic() - self.t_start)
+
+    def arm(self) -> None:
+        signal.signal(signal.SIGALRM, self._on_alarm)
+        signal.setitimer(signal.ITIMER_REAL, max(
+            1e-3, self.t_start + self.seconds - time.monotonic()))
+
+    def check_open(self, t_open: float) -> None:
+        """The open of the window is laid: past the deadline the run ends
+        now, not after the wait for it."""
+        self.phase = "align"
+        if t_open - self.t_start > self.seconds:
+            raise self.late(t_open - self.t_start)
+
+    def disarm(self) -> None:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+
+
 def load_json(*parts):
     with open(os.path.join(*parts)) as f:
         return json.load(f)
@@ -132,6 +168,8 @@ def wait_for(what, probe, timeout_s, children, every_s=0.5):
             got = probe()
             if got:
                 return got
+        except RunFailure:              # the deadline's or a signal's: not retried
+            raise
         except Exception as e:  # noqa: BLE001 — peer not up yet; retried
             last = e
         time.sleep(every_s)
@@ -363,7 +401,13 @@ def aligned_open(ev: dict, harness: dict, earliest: float, seconds: float):
         return earliest, None
     begun = [b for n, b, _e in ev["events"]
              if n.startswith("cron.") and n[5:] in jobs]
-    tick = (max(begun) if begun
+    # a tick's jobs run one after the other on the crontab's one thread and
+    # all come due again `period` after the pump that took them, not after
+    # their own begins: the tick is the earliest begin of the latest one (a
+    # job that waits behind the load's writes holds the later ones back:
+    # 6-14 s in hnsw768.conc4, whose tick then came at the window's second
+    # 26-34 where the latest begin laid it at 40; chip runs, PR 34-35)
+    tick = (min(b for b in begun if b > max(begun) - period / 2) if begun
             else min(ev["crontab"][n]["added"] for n in jobs)) + period
     lo, hi = harness["tick_margin_s"]
     if seconds < lo + hi:
@@ -495,14 +539,22 @@ def main() -> int:
         raise RunFailure(f"signal {signum}")
 
     signal.signal(signal.SIGTERM, on_term)
+    deadline = SetupDeadline(harness["setup_deadline_s"])
+    deadline.arm()
+    failure = None
     try:
         result = drive(args, bench, cell, config, mix, harness, cluster,
-                       rehearsal)
+                       rehearsal, deadline)
     except RunFailure as e:
-        print(f"RUN FAILED: {e}{_tag}", file=sys.stderr, flush=True)
-        return 1
+        failure = e
     finally:
-        cluster.stop()
+        deadline.disarm()
+        # a whole run has stopped its processes itself (`drive`); a failed
+        # one has nothing to save, so its store gets 5 s and not 15
+        cluster.stop(timeout=5.0)
+    if failure is not None:
+        print(f"RUN FAILED: {failure}{_tag}", file=sys.stderr, flush=True)
+        return 1
     if args.sweep:
         return 4
     if rehearsal:
@@ -515,7 +567,8 @@ def main() -> int:
     return 0
 
 
-def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
+def drive(args, bench, cell, config, mix, harness, cluster, rehearsal,
+          deadline) -> dict:
     import numpy as np
 
     import readers
@@ -551,22 +604,26 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
                          f"{cell['chips']}")
 
     # ---- region, load, build ----------------------------------------------
+    deadline.phase = "region"
     cluster.create_region(config)
     for c in cluster.callers:
         c.reply()                       # {"ready": true}; the probe's too
     t0 = time.monotonic()
     setup["region"] = t0 - T_START - setup["store_up"]
+    deadline.phase = "load"
     traffic_callers[0].ask(cmd="load")
     setup["load"] = time.monotonic() - t0
     say(f"load: {config['rows']} rows through vector_add in "
         f"{setup['load']:.1f}s")
     t0 = time.monotonic()
+    deadline.phase = "build"
     status = cluster.build(config)
     setup["build"] = time.monotonic() - t0
     say(f"build: {setup['build']:.1f}s; region {status}")
 
     # ---- warm the cell's own shapes ---------------------------------------
     t0 = time.monotonic()
+    deadline.phase = "warm"
     first = traffic_callers[0].ask(cmd="warm", n=mix["warm_requests"])
     writes = first["writes"]
     for c in traffic_callers[1:]:
@@ -580,6 +637,7 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
         say(f"{first['failed']} warm-up requests failed: {first['errors']}")
 
     if args.sweep:
+        deadline.disarm()
         return sweep(args, mix, traffic_callers, seconds)
 
     # ---- open the window at a fixed offset from the store's schedule ------
@@ -588,6 +646,8 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
     tick = None
     if not args.no_align:
         t_open, tick = aligned_open(ev, harness, t_open, seconds)
+    deadline.check_open(t_open)
+    deadline.disarm()
     t_close = t_open + seconds
     setup["align"] = t_open - time.monotonic()
     setup["total"] = t_open - T_START
@@ -621,12 +681,18 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
         time.sleep(max(0.0, t_open + harness["profile_at_s"]
                        - time.monotonic()))
         started = store.ask(cmd="profile_start", dir=trace_dir)
-        time.sleep(harness["profile_seconds"])
+        # a mix of many small device operations states fewer seconds of its
+        # own: the profiler's stop and the reduction take time by the event
+        time.sleep(mix.get("profile_seconds", harness["profile_seconds"]))
         stopped = store.ask(cmd="profile_stop")
         profile = (started["t_started"], stopped["t"])
+        setup["profile_stop"] = stopped["t_stopped"] - stopped["t"]
     time.sleep(max(0.0, t_close - time.monotonic()))
     files = [c.reply() for c in traffic_callers]     # after the drain
     t_drained = time.monotonic()
+    # after the close: the last replies, and in a traced run what is left of
+    # the profiler's stop (`profile_stop`, which begins inside the window)
+    setup["drain"] = t_drained - t_close
     after = store.ask(cmd="metrics")["metrics"]
     spans = []
     if args.trace:
@@ -634,6 +700,14 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
             spans = json.load(f)
     memory = store.ask(cmd="memory")
     ev = store.ask(cmd="events")
+    if tick is not None:
+        # where the tick really came: `aligned_open` laid it from the
+        # store's record of the ticks before
+        came = [b - t_open for n, b, _e in ev["events"] if n.startswith("cron.")
+                and ev["crontab"].get(n[5:], {}).get("interval_s")
+                == harness["align_interval_s"] and t_open <= b < t_close]
+        if came:
+            readings["tick_at_s"] = min(came)
     coord_ev = cluster.coordinator.ask(cmd="events")
     ev["events"] += [["coord." + n, b, e] for n, b, e in coord_ev["events"]]
     ev["gc"] += [[f"coord.{g}", b, d] for g, b, d in coord_ev["gc"]]
@@ -705,6 +779,7 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
         pass
     trace = {}
     if args.trace:
+        t0 = time.monotonic()
         got = subprocess.run(
             [py, os.path.join(HERE, "trace_reduce.py"), trace_dir],
             env=dict(cluster.env, JAX_PLATFORMS="cpu"), cwd=ROOT,
@@ -713,6 +788,7 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal) -> dict:
             raise RunFailure(f"trace reduction failed:\n{got.stderr[-2000:]}")
         trace = json.loads(got.stdout.strip().splitlines()[-1])
         trace["window_s"] = profile[1] - profile[0]
+        setup["reduce"] = time.monotonic() - t0
         with open(os.path.join(out, "trace_reduced.json"), "w") as f:
             json.dump(trace, f)
         if not args.keep_profile:

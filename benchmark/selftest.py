@@ -10,21 +10,31 @@
 4. the comparison: a perfect program passes, the bfloat16 control does not;
 5. the contract's data: every cell's traffic file states its load (the
    caller count of a closed loop, rate and threads of an open one) and why,
-   every metric has its file, and the bounds in BENCHMARK.json are the ones
-   PERF.md section 2 gives its reasons for;
-6. CPU rehearsals (`JAX_PLATFORMS=cpu`, explicit small `--rows`): every line
+   every metric has its file, the bounds in BENCHMARK.json are the ones
+   PERF.md section 2 gives its reasons for, and a recipe that states
+   `max_elements` states the configuration's rows;
+6. set-up's deadline (`harness.json` `setup_deadline_s`): a child that never
+   answers ends the run at the deadline with the phase named and leaves no
+   process behind, an open of the window laid past it fails in phase `align`,
+   and the window's tick is laid from the earliest begin of a tick's jobs;
+7. CPU rehearsals (`JAX_PLATFORMS=cpu`, explicit small `--rows`): every line
    says `platform: cpu`, the last line is never a pass, and with the timed
    path broken underneath (`--wrap-client faults.py:<fault>`) `correct`
    comes out false; a configuration whose metric or precision no arm of the
-   harness honours is refused.
+   harness honours is refused; a run whose set-up cannot end by the deadline
+   (a scratch copy of the benchmark whose `harness.json` states 3 s) exits 1
+   with the phase named and leaves no process behind.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import re
+import shutil
 import subprocess
 import sys
+import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -254,8 +264,6 @@ def test_comparison() -> None:
 
 # ------------------------------------------------------------------ 5. data
 def test_data() -> None:
-    import re
-
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     for cell in bench["workloads"]:
@@ -294,12 +302,106 @@ def test_data() -> None:
     bounds = {m["name"]: m["bound"] for m in bench["end_to_end"]}
     check(table == bounds, f"BENCHMARK.json's bounds {bounds} are PERF.md "
           f"section 2's {table}")
+    for name in sorted(os.listdir(os.path.join(HERE, "configs"))):
+        config = load("configs", name)
+        stated = config["index_parameter"].get("max_elements")
+        if stated is not None:
+            check(stated == config["rows"],
+                  f"configs/{name}: the recipe's max_elements ({stated}) is "
+                  f"the configuration's rows ({config['rows']})")
     run_seconds = re.search(r"`run_seconds` (\d+)", section)
     check(run_seconds and int(run_seconds.group(1)) == bench["run_seconds"],
           "PERF.md section 2 states BENCHMARK.json's run_seconds")
 
 
-# ------------------------------------------------------------- 6. rehearsals
+# --------------------------------------------------------------- 6. deadline
+def processes_naming(path: str):
+    """Pids of live processes whose command line names `path`."""
+    found = []
+    for pid in os.listdir("/proc"):
+        if pid.isdigit() and int(pid) != os.getpid():
+            try:
+                with open(f"/proc/{pid}/cmdline", "rb") as f:
+                    if path.encode() in f.read():
+                        found.append(int(pid))
+            except OSError:
+                pass
+    return found
+
+
+def test_deadline() -> None:
+    import run as harness
+
+    def failure_of(fn, armed=None) -> str:
+        """What `fn` fails with, under the deadline `armed` if one is given
+        ('' if it returns)."""
+        if armed is not None:
+            armed.arm()
+        try:
+            fn()
+            return ""
+        except harness.RunFailure as e:
+            return str(e)
+        finally:
+            if armed is not None:
+                armed.disarm()
+
+    limits = load("harness.json")
+    check(0 < limits["setup_deadline_s"] + 45 + 20 <= 360 - 45,
+          "harness.json: set-up's deadline, the window and the run's end "
+          "leave 45 s of the driver's 360")
+    # (a) a child that never answers: the blocking readline ends at the
+    # deadline, the phase is named, and nothing is left running
+    out = os.path.join(HERE, "out", "selftest-deadline")
+    shutil.rmtree(out, ignore_errors=True)
+    os.makedirs(out)
+    cluster = harness.Cluster(out, limits["generator_core_share"])
+    stub = harness.Child("stub", [sys.executable, "-c",
+                                  "import time; time.sleep(600)", out],
+                         cluster.off_jax, os.path.join(out, "stub.log"))
+    cluster.callers.append(stub)
+    t0 = time.monotonic()
+    deadline = harness.SetupDeadline(1.0, t_start=t0)
+    deadline.phase = "load"
+    said = failure_of(lambda: stub.ask(cmd="load"), deadline)
+    took = time.monotonic() - t0
+    check(said == "set-up past its deadline of 1 s in phase load after 1 s"
+          and 1.0 <= took < 2.0,
+          f"a child that never answers: {said!r} after {took:.2f} s")
+    cluster.stop()
+    check(stub.p.poll() is not None and not processes_naming(out),
+          "and no child is left alive")
+    shutil.rmtree(out, ignore_errors=True)
+    # the deadline's failure passes through wait_for's retries
+    said = failure_of(
+        lambda: harness.wait_for("nothing", lambda: time.sleep(5), 5, []),
+        harness.SetupDeadline(0.3, t_start=time.monotonic()))
+    check("phase store_up" in said, f"wait_for does not retry past it: {said!r}")
+    # (b) an open of the window laid past the deadline fails in `align`
+    ev = {"crontab": {"scrub": {"interval_s": 60.0, "added": 0.0},
+                      "sweep": {"interval_s": 5.0, "added": 0.0}},
+          "events": [["cron.scrub", 200.0, 200.1], ["cron.sweep", 235.0, 235.5]]}
+    t_open, tick = harness.aligned_open(ev, limits, 225.0, 45.0)
+    check((round(t_open, 6), tick) == (280.0, 320.0),
+          "a set-up that ends 5 s after a grid point waits for the next "
+          f"tick: opens at {t_open}, tick at {tick}")
+    deadline = harness.SetupDeadline(250.0, t_start=0.0)
+    said = failure_of(lambda: deadline.check_open(t_open))
+    check(said == "set-up past its deadline of 250 s in phase align after 280 s",
+          f"an open laid past the deadline: {said!r}")
+    t_open, tick = harness.aligned_open(ev, limits, 215.0, 45.0)
+    deadline.check_open(t_open)
+    check(t_open == 220.0 and deadline.phase == "align",
+          "an open inside the deadline passes")
+    # the tick is the earliest begin of the latest tick's jobs: one that
+    # waited 14 s behind a write does not move it
+    ev["crontab"]["gc"] = {"interval_s": 60.0, "added": 0.0}
+    ev["events"] += [["cron.gc", 214.0, 214.1], ["cron.scrub", 140.0, 140.1]]
+    check(harness.aligned_open(ev, limits, 215.0, 45.0) == (220.0, 260.0),
+          "a tick's job that began late does not move the tick")
+
+
+# ------------------------------------------------------------- 7. rehearsals
 def rehearse(workload: str, *extra):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     got = subprocess.run(
@@ -349,6 +451,33 @@ def test_rehearsals() -> None:
     check(rehearse("flat768.single", "--control", "bf16")["control"][
         "correct"] is False,
         "the bfloat16 control in the program's place: not correct")
+    # a whole run against a deadline it cannot keep: a scratch copy of the
+    # benchmark (the program by a link) whose harness.json states 3 s
+    tree = os.path.join(HERE, "out", "selftest-deadline-tree")
+    shutil.rmtree(tree, ignore_errors=True)
+    shutil.copytree(HERE, os.path.join(tree, "benchmark"),
+                    ignore=shutil.ignore_patterns("out", "sets", "__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tree)
+    os.symlink(os.path.join(ROOT, "dingo_tpu"), os.path.join(tree, "dingo_tpu"))
+    limits = load("harness.json")
+    with open(os.path.join(tree, "benchmark", "harness.json"), "w") as f:
+        json.dump(dict(limits, setup_deadline_s=3.0), f)
+    t0 = time.monotonic()
+    got = subprocess.run(
+        [sys.executable, os.path.join(tree, "benchmark", "run.py"),
+         "--workload", "hnsw768.conc4", "--seed", "5", "--seconds", "4",
+         "--trace", "0", "--rows", "8192", "--no-align"],
+        cwd=tree, env=env, capture_output=True, text=True, timeout=300)
+    took = time.monotonic() - t0
+    check(got.returncode == 1 and re.search(
+        r"RUN FAILED: set-up past its deadline of 3 s in phase "
+        r"(store_up|region|load) after 3 s", got.stderr) is not None
+        and "{" not in got.stdout.strip().splitlines()[-1],
+        f"a run that cannot keep the deadline exits 1 with the phase named "
+        f"and no result ({took:.1f} s): {got.stderr.strip()[-200:]!r}")
+    check(took < 3.0 + 20.0 and not processes_naming(tree),
+          "and leaves no process behind")
+    shutil.rmtree(tree, ignore_errors=True)
 
 
 def main() -> int:
@@ -357,6 +486,7 @@ def main() -> int:
     test_comparison()
     test_reducer()
     test_data()
+    test_deadline()
     if "quick" not in sys.argv[1:]:
         test_rehearsals()
     print("selftest passed", flush=True)

@@ -11,6 +11,8 @@ Runs as a process of its own once the store is gone:
 
 from __future__ import annotations
 
+import bisect
+import functools
 import glob
 import json
 import os
@@ -62,6 +64,7 @@ def gaps_of(intervals):
     return out
 
 
+@functools.lru_cache(maxsize=None)     # a few hundred texts, millions of events
 def short_op(text: str) -> str:
     """`%fusion.2 = f32[...] fusion(...), kind=...` -> `fusion.2:fusion`:
     the instruction's name and opcode, not its whole text."""
@@ -72,14 +75,29 @@ def short_op(text: str) -> str:
     return (name.lstrip("%") + (":" + m.group(1) if m else ""))[:120]
 
 
-def _module_of(stats: dict, start: float, modules) -> str:
+def _module_of(stats: dict, start: float, modules, starts=None) -> str:
+    """The module an operation ran in: by its own stats, else the first
+    event of the device's modules line that holds its start. `starts` (the
+    modules' starts, where they are in order and do not overlap) finds it by
+    bisection: an hnsw trace holds 1.7 M operations under 2,300 modules."""
     for key in ("hlo_module", "module", "program"):
         if stats.get(key):
             return str(stats[key])
+    if starts is not None:
+        i = bisect.bisect_right(starts, start) - 1
+        return modules[i][0] if i >= 0 and start < modules[i][2] else ""
     for name, s, e in modules:
         if s <= start < e:
             return name
     return ""
+
+
+def _ordered_starts(modules):
+    """The modules' starts if each begins at or after the one before ends
+    (then at most one holds a given time, and bisection finds the one the
+    scan would), else None."""
+    ok = all(a[2] <= b[1] for a, b in zip(modules, modules[1:]))
+    return [m[1] for m in modules] if ok else None
 
 
 def _stats(event) -> dict:
@@ -115,13 +133,13 @@ def read_planes(path: str):
                                 ev.start_ns * 1e-9,
                                 (ev.start_ns + ev.duration_ns) * 1e-9)
                                for ev in ln.events]
-            ops = []
+            ops, starts = [], _ordered_starts(modules)
             for ln in lines:
                 if ln.name != OPS_LINE:
                     continue
                 for ev in ln.events:
                     s = ev.start_ns * 1e-9
-                    module = _module_of(_stats(ev), s, modules)
+                    module = _module_of(_stats(ev), s, modules, starts)
                     op = short_op(ev.name)
                     name = f"{module}/{op}" if module else op
                     ops.append((name, s, s + ev.duration_ns * 1e-9))
