@@ -601,17 +601,20 @@ def kernel_phase_child() -> int:
                     refresh=ivf.compact, nq=4, nprobe=nlist)
         del ivf
 
-    # no exact rerank behind the ADC scan: compare the kernel's own output
-    FLAGS.set("ivfpq_rerank_factor", 1)
     pq = new_index(3, IndexParameter(
         index_type=IndexType.IVF_PQ, dimension=DIM, ncentroids=nlist,
         nsubvector=96))
     n_pq = max(n, 4096)               # PQ codebooks need >= 256 rows
     pq.upsert(np.arange(n_pq, dtype=np.int64), x[:n_pq])
     pq.train()
-    ref = search_with({ivf_on: False}, pq, nprobe=nlist)
-    compare("ivf_pq_adc_topk[m=96]", "ops.pallas.pq_adc_topk", pq,
-            {ivf_on: True}, ref, nprobe=nlist)
+    # rerank factor 1: no exact rerank behind the ADC scores, so the
+    # kernel's own output is compared; 64: the shortlist of 640 that
+    # ivfpq768's configuration reranks
+    for factor in (1, 64):
+        FLAGS.set("ivfpq_rerank_factor", factor)
+        ref = search_with({ivf_on: False}, pq, nprobe=nlist)
+        compare(f"ivf_pq_adc_scores[m=96,factor={factor}]",
+                "ops.pallas.pq_adc", pq, {ivf_on: True}, ref, nprobe=nlist)
     # the ADC scan is traced inside the request's one program
     # (index.ivfpq.search); which arm served is counter ivfpq.scan_arm,
     # as ivf.scan_arm says it for IVF_FLAT in the served phase

@@ -14,10 +14,12 @@ TPU-first design:
              [capacity, m] uint8 array updated incrementally on upsert;
              a bucketed view [nlist, cap_list, m] groups codes by coarse
              list (same scheme as ivf_flat.py).
-  search   — per probe rank r: residual LUT [b, m, ksub] for each query's
-             rank-r list (m vmapped tiny matmuls), then ADC over the gathered
-             code bucket via one take_along_axis ([b, m, cap_list]) + sum.
-             Running top-k across ranks.
+  search   — one program a request: coarse probes, the residual LUT of
+             every (query, probed list), the ADC scores of every probed
+             bucket, then ONE top-k' over all of them and the exact rerank.
+             The scores come from the Pallas kernel (ops/pallas_pq.py, each
+             table resident in VMEM) on the TPU, else from the XLA rank
+             scan's compare-select over the ksub codewords.
   fallback — untrained: exact flat-kernel scan over the SlotStore (the
              hybrid contract; NOT an error, unlike IVF_FLAT).
 """
@@ -178,11 +180,13 @@ def _codebook_sqnorms(codebooks):
     )
 
 
-def _residual_lut_tables(resid, codebooks, cb_sq):
-    """Residual targets [n, d] -> ADC tables [n, m, ksub]:
-    lut[i, j, c] = ||resid_i_subj - codeword_jc||^2. THE one copy of the
-    distance-table formula — both the XLA scan kernel and the fused
-    Quick-ADC path build tables here, so they cannot drift apart."""
+def _residual_lut_tables(resid, codebooks, cb_sq, subspace_major=False):
+    """Residual targets [n, d] -> ADC tables [n, m, ksub], or [m, n, ksub]
+    where `subspace_major` (the order the batched contraction writes, which
+    the Pallas kernel reads as it lies): lut[i, j, c] = ||resid_i_subj -
+    codeword_jc||^2. THE one copy of the distance-table formula — both the
+    XLA scan kernel and the Pallas scoring kernel build tables here, so
+    they cannot drift apart; the order only lays the same values out."""
     m = codebooks.shape[0]
     subs = split_subvectors(resid, m)                  # [m, n, dsub]
     dots = jnp.einsum(
@@ -194,12 +198,12 @@ def _residual_lut_tables(resid, codebooks, cb_sq):
         "mbd,mbd->mb", subs, subs, precision=jax.lax.Precision.HIGHEST
     )
     lut = q_sq[:, :, None] - 2.0 * dots + cb_sq[:, None, :]  # [m, n, ksub]
-    return jnp.transpose(lut, (1, 0, 2))               # [n, m, ksub]
+    return lut if subspace_major else jnp.transpose(lut, (1, 0, 2))
 
 
 def _ivfpq_adc_lut(queries, centroids, probes_coarse, codebooks):
-    """Residual ADC tables [b, nprobe, m, ksub] over the coarse probe
-    ranking — the input the fused Quick-ADC Pallas kernel
+    """Residual ADC tables [m, b, nprobe, ksub] over the coarse probe
+    ranking, subspace-major — the input the Pallas scoring kernel
     (ops/pallas_pq.py) keeps resident in VMEM per (query, rank); traced
     inside `ivfpq_search_program`."""
     b, d = queries.shape
@@ -208,8 +212,24 @@ def _ivfpq_adc_lut(queries, centroids, probes_coarse, codebooks):
     resid = (
         queries[:, None, :] - jnp.take(centroids, probes_coarse, axis=0)
     ).reshape(b * nprobe, d)
-    lut = _residual_lut_tables(resid, codebooks, _codebook_sqnorms(codebooks))
-    return lut.reshape(b, nprobe, m, ksub)
+    lut = _residual_lut_tables(resid, codebooks, _codebook_sqnorms(codebooks),
+                               subspace_major=True)
+    return lut.reshape(m, b, nprobe, ksub)
+
+
+def _adc_shortlist(scores, slots, k):
+    """ONE top-k over every probed slot's ADC score ([b, n] each, -inf where
+    nothing was scored) -> (wire distances [b, k], slots [b, k], -1 past
+    the live candidates). Both ADC arms select here, by the same code."""
+    kk = min(k, scores.shape[1])
+    vals, idx = jax.lax.top_k(scores, kk)
+    slots = jnp.where(jnp.isneginf(vals), -1,
+                      jnp.take_along_axis(slots, idx, axis=1))
+    if kk < k:
+        vals = jnp.pad(vals, ((0, 0), (0, k - kk)),
+                       constant_values=float("-inf"))
+        slots = jnp.pad(slots, ((0, 0), (0, k - kk)), constant_values=-1)
+    return -vals, slots    # wire convention: squared-L2-approx ascending
 
 
 @sentinel_jit("index.ivfpq.scan", static_argnames=("k", "precompute_lut"))
@@ -281,17 +301,8 @@ def _ivfpq_scan_kernel(
     # merge would sort k + cap entries at every rank, and k is the rerank
     # shortlist (topk x ivfpq_rerank_factor), hundreds wide
     _, (scores, slots) = jax.lax.scan(body, None, jnp.arange(probes.shape[1]))
-    scores = jnp.transpose(scores, (1, 0, 2)).reshape(b, -1)
-    slots = jnp.transpose(slots, (1, 0, 2)).reshape(b, -1)
-    kk = min(k, scores.shape[1])
-    vals, idx = jax.lax.top_k(scores, kk)
-    slots = jnp.where(jnp.isneginf(vals), -1,
-                      jnp.take_along_axis(slots, idx, axis=1))
-    if kk < k:
-        vals = jnp.pad(vals, ((0, 0), (0, k - kk)),
-                       constant_values=float("-inf"))
-        slots = jnp.pad(slots, ((0, 0), (0, k - kk)), constant_values=-1)
-    return -vals, slots    # wire convention: squared-L2-approx ascending
+    return _adc_shortlist(jnp.transpose(scores, (1, 0, 2)).reshape(b, -1),
+                          jnp.transpose(slots, (1, 0, 2)).reshape(b, -1), k)
 
 
 @sentinel_jit("index.ivfpq.search",
@@ -323,22 +334,26 @@ def ivfpq_search_program(
     code buckets, and — where `vecs` is given — the exact fp32 rerank of
     that shortlist to `k` -> (wire distances, slots, probes[b, nprobe],
     vprobes[b, budget]). Without `vecs` the distances are the ADC ones
-    (rerank factor 1, or host rows that resolve() reranks). `pallas` takes
-    the Quick-ADC kernel (ops/pallas_pq.py), else the XLA rank scan."""
+    (rerank factor 1, or host rows that resolve() reranks). `pallas` scores
+    the probed buckets with the Pallas kernel (ops/pallas_pq.py), else with
+    the XLA rank scan; both take the shortlist by `_adc_shortlist`."""
     from dingo_tpu.ops.rerank import exact_rerank_device
 
     probes = coarse_probes(qpad, centroids, c_sqnorm, nprobe)
     vprobes, coarse_pos = expand_probes_ranked(
         probes, probe_table, nprobe, probe_table.shape[1])
     if pallas:
-        from dingo_tpu.ops.pallas_pq import ivf_pq_adc_search
+        from dingo_tpu.ops.pallas_pq import ivf_pq_adc_scores
 
-        lut_all = _ivfpq_adc_lut(qpad, centroids, probes, codebooks)
-        vals, slots = ivf_pq_adc_search(
-            vprobes, coarse_pos, lut_all, code_buckets, valid, bucket_slot,
-            k=kscan, interpret=interpret,
+        b = qpad.shape[0]
+        scores = ivf_pq_adc_scores(
+            vprobes, coarse_pos,
+            _ivfpq_adc_lut(qpad, centroids, probes, codebooks),
+            code_buckets, valid, interpret=interpret,
         )
-        dists = -vals    # wire: ADC squared-L2 ascending
+        slots = jnp.take(bucket_slot, jnp.maximum(vprobes, 0), axis=0)
+        dists, slots = _adc_shortlist(scores.reshape(b, -1),
+                                      slots.reshape(b, -1), kscan)
     else:
         dists, slots = _ivfpq_scan_kernel.__wrapped__(
             code_buckets, valid, bucket_slot, bucket_coarse, probes,
@@ -685,14 +700,12 @@ class TpuIvfPq(IvfViewMaintenance, _SlotStoreIndex):
                 # the [b, nprobe, m, ksub] table fits comfortably in HBM
                 lut_bytes = qpad.shape[0] * nprobe * self.m * self.ksub * 4
                 precompute = lut_bytes <= 256 * 1024 * 1024
-                # Quick-ADC fused kernel: the IVF_FLAT list kernel's
-                # tri-state crossover; it needs the precomputed LUT (the
-                # resident VMEM operand) and k within the 64 its unrolled
-                # select rounds pay for (k' = 80 at the shipped factor:
-                # the XLA scan, PERF.md section 6 PR 37)
+                # the Pallas scoring kernel: the IVF_FLAT list kernel's
+                # tri-state crossover, where the precomputed LUT (its
+                # resident VMEM operand) fits
                 arm = "pallas" if (
                     pallas_ivf_enabled(self.dimension) and precompute
-                    and kscan <= 64) else "xla"
+                ) else "xla"
                 fprep = self._prep_filter_mask(filter_spec)
             else:
                 # Hybrid contract: exact flat scan until trained

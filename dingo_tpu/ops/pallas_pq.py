@@ -1,31 +1,32 @@
-"""Quick-ADC-style fused PQ scan: LUTs resident in VMEM, codes streamed
-per probed bucket.
+"""Quick-ADC-style PQ scoring: each (query, probed list) table resident in
+VMEM, one probed code bucket scored per grid step, scores only.
 
 Quick ADC (PAPERS.md) keeps the PQ distance tables in SIMD registers and
-scans codes through them without ever leaving the register file. The TPU
-analog: the per-(query, probed-list) residual LUT [m, ksub] stays resident
-in VMEM for the whole bucket scan while the Pallas pipeline DMAs exactly
-one probed code bucket [cap, m] per grid step (scalar-prefetched probe
-ids, same scheme as ops/pallas_ivf.py), and the ADC sum + running top-k
-merge happen in VMEM. The XLA path (`ivf_pq._ivfpq_scan_kernel`) instead
-gathers a [b, cap, m] code bucket per rank into HBM and reads it back for
-a take_along_axis — 3x the necessary HBM traffic, plus the gather itself
-lowers badly on TPU.
+scans codes through them without leaving the register file. The TPU
+analog: the residual table of a (query, coarse rank) stays in VMEM while
+the Pallas pipeline DMAs one probed code bucket [cap, m] per grid step
+(scalar-prefetched bucket ids and coarse ranks, as ops/pallas_ivf.py
+does). A list's spill buckets share their rank's table: the block index
+does not change, so the pipeline does not fetch it again. A rank with no
+bucket (-1) computes nothing and moves no DMA: its blocks are the last
+live rank's.
 
-The in-kernel table lookup is a one-hot contraction (the MXU-native
-formulation ops/pq.py:adc_scan uses at the XLA layer), chunked over
-subspace groups so the one-hot tile stays a few MB of VMEM:
+The kernel writes the ADC scores of every probed slot and selects
+nothing: the shortlist is the same single XLA top-k the XLA rank scan
+takes (`ivf_pq._adc_shortlist`), so both arms rank by the same code and k'
+does not bound the kernel.
 
-    dist[c] = sum_g  onehot(codes[c, g*MG:(g+1)*MG]) . lut[g*MG:(g+1)*MG]
-
-Output feeds the existing device-resident exact rerank (ops/rerank.py) —
-the ADC scan is the prune, the rerank absorbs the quantization noise.
+The lookup is vector work laid out for the VPU, exact in f32: a step's
+codes are [rows, m] int32 with the subspaces on lanes, and the table is
+read as [ksub, m], one codeword's m entries a row. For each of the ksub
+codewords one compare and one select over the rows' few vregs pick that
+codeword's entry wherever a code names it, so every (row, subspace) ends
+holding exactly its table entry; one lane sum per row gives the distance.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,178 +34,177 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dingo_tpu.obs.sentinel import sentinel_jit
-from dingo_tpu.ops.pallas_ivf import OUT_PAD, ROW_BLOCK
-from dingo_tpu.ops.pallas_topk import NEG_INF, _select_topk
+from dingo_tpu.ops.pallas_topk import NEG_INF
 
-#: subspaces per one-hot group and candidates per one-hot tile: together
-#: they bound the [CHUNK, MAX_GROUP * ksub] f32 tile at 2 MB (ksub=256)
-#: whatever the bucket width. One tile over a whole cap=1024 bucket needed
-#: 41.8 MB of the 16 MB scoped VMEM on v5e.
-MAX_GROUP = 8
-CHUNK = 256
+#: code rows scored at once: their [ROWS, m] codes and picked entries stay
+#: in vector registers while the ksub codewords go by, and a wider bucket
+#: is scored ROWS at a time (a lane-aligned slice of the output row)
+ROWS = 128
+#: codewords a table tile holds: one f32 vreg of sublanes
+TILE = 8
+#: ranks an output block holds: one f32 vreg of sublanes
+OUT_ROWS = 8
+#: grid steps one pallas_call takes: their three scalar-prefetched int32
+#: ids (bucket, fetched bucket, fetched table) sit in SMEM, 1 MB on v5e
+PREFETCH_STEPS = 1 << 15
+#: tables a fetched table block holds: the contraction writes a table's
+#: rows one sublane apart, so a block of TABLES consecutive (query, rank)
+#: tables is what a DMA can take; ranks go in order, so each block is
+#: fetched once
+TABLES = 8
 
 
-def _adc_kernel(vp_ref, cp_ref, lut_ref, code_ref, val_ref, slot_ref,
-                outv_ref, outi_ref, dist_ref, *, k, m, ksub):
-    qi = pl.program_id(0)
+def _adc_score_kernel(vp_ref, fb_ref, fc_ref, lut_ref, code_ref, val_ref,
+                      out_ref, tbl_ref, dist_ref, held_ref, *, width, nprobe,
+                      q0):
+    q = pl.program_id(0)
     r = pl.program_id(1)
-    row = pl.ds(jax.lax.rem(qi, ROW_BLOCK), 1)
+    at = q * width + r
+    m, _, ksub = lut_ref.shape
+    cap = code_ref.shape[1]
+    rows = min(ROWS, cap)
+    row = pl.ds(r % OUT_ROWS, 1)
+    live = vp_ref[at] >= 0
+    want = (q0 + q) * nprobe + fc_ref[at]   # the (query, rank) table
 
     @pl.when(r == 0)
-    def _init():
-        outv_ref[row, :] = jnp.full(
-            (1, outv_ref.shape[1]), NEG_INF, jnp.float32
-        )
-        outi_ref[row, :] = jnp.full((1, outi_ref.shape[1]), -1, jnp.int32)
+    def _new_query():
+        held_ref[0] = -1
 
-    @pl.when(vp_ref[qi, r] >= 0)
-    def _scan_bucket():
-        lut = lut_ref[0, 0]                              # [m, ksub]
-        cap = code_ref.shape[1]
-        ch = min(CHUNK, cap)
-        kiota = jax.lax.broadcasted_iota(jnp.int32, (1, ksub), 1)
+    @pl.when(jnp.logical_not(live))
+    def _skip():
+        out_ref[0, row, :] = jnp.full((1, cap), NEG_INF, jnp.float32)
 
-        def scan_chunk(c0):
-            codes = code_ref[0, pl.ds(c0, ch), :].astype(jnp.int32)  # [ch, m]
-            dist = jnp.zeros((1, ch), jnp.float32)
-            # static unrolled group loop: one-hot contraction per MG
-            # subspaces
-            for g in range(0, m, MAX_GROUP):
-                w = min(MAX_GROUP, m - g)
-                cg = codes[:, g:g + w]                   # [ch, w]
-                oh = (cg[:, :, None] == kiota[None, :, :]).astype(
-                    jnp.float32
-                )
-                ohf = oh.reshape(ch, w * ksub)
-                lutg = lut[g:g + w, :].reshape(1, w * ksub)
-                dist += jax.lax.dot_general(
-                    lutg, ohf, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                    precision=jax.lax.Precision.HIGHEST,
-                )                                        # [1, ch]
-            dist_ref[:, pl.ds(c0, ch)] = dist
+    @pl.when(live & (held_ref[0] != want))
+    def _codeword_rows():
+        tbl_ref[...] = lut_ref[:, want % TABLES, :].T    # [ksub, m]
+        held_ref[0] = want
 
-        if cap == ch:
-            scan_chunk(0)        # static offset: ch need not be lane-aligned
+    @pl.when(live)
+    def _score():
+        ones = jnp.ones((1, m), jnp.float32)
+
+        def score_rows(c0):
+            codes = code_ref[0, pl.ds(c0, rows), :].astype(jnp.int32)
+
+            def tile(t, picked):
+                c = pl.multiple_of(t * TILE, TILE)
+                entries = tbl_ref[pl.ds(c, TILE), :]     # [TILE, m]
+                for i in range(TILE):
+                    picked = jnp.where(codes == c + i, entries[i:i + 1, :],
+                                       picked)
+                return picked
+
+            # unrolled: 9 % faster than the rolled loop on a v5e, the
+            # same bits (PERF.md section 6)
+            picked = jax.lax.fori_loop(
+                0, ksub // TILE, tile, jnp.zeros((rows, m), jnp.float32),
+                unroll=True)
+            # the lane sum of each row as a row vector [1, rows]: a
+            # ones-row contraction at HIGHEST (f32 operands, f32
+            # accumulation)
+            dist_ref[:, pl.ds(c0, rows)] = jax.lax.dot_general(
+                ones, picked, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
+            )
+
+        if cap == rows:
+            score_rows(0)     # static offset: rows need not fill a lane tile
         else:
-            def body(c, carry):
-                scan_chunk(pl.multiple_of(c * ch, ch))
+            def body(i, carry):
+                score_rows(pl.multiple_of(i * rows, rows))
                 return carry
 
-            jax.lax.fori_loop(0, cap // ch, body, 0)
-        scores = jnp.where(val_ref[0] > 0.5, -dist_ref[:], NEG_INF)
-        slot = slot_ref[0].astype(jnp.int32)
-        blk_v, blk_i = _select_topk(scores, slot, k)
-        cur_v = outv_ref[row, :]
-        cur_i = outi_ref[row, :]
-        cat_v = jnp.concatenate([cur_v[:, :k], blk_v], axis=1)
-        cat_i = jnp.concatenate([cur_i[:, :k], blk_i], axis=1)
-        new_v, new_i = _select_topk(cat_v, cat_i, k)
-        pad = outv_ref.shape[1] - k
-        outv_ref[row, :] = jnp.concatenate(
-            [new_v, jnp.full((1, pad), NEG_INF, jnp.float32)], axis=1
-        )
-        outi_ref[row, :] = jnp.concatenate(
-            [new_i, jnp.full((1, pad), -1, jnp.int32)], axis=1
-        )
-
-    @pl.when(r == pl.num_programs(1) - 1)
-    def _finish():
-        fv = outv_ref[row, :]
-        outi_ref[row, :] = jnp.where(jnp.isneginf(fv), -1, outi_ref[row, :])
+            jax.lax.fori_loop(0, cap // rows, body, 0)
+        out_ref[0, row, :] = jnp.where(val_ref[0] > 0.5, -dist_ref[:],
+                                       NEG_INF)
 
 
-@sentinel_jit("ops.pallas.pq_adc_topk",
-              static_argnames=("k", "interpret", "nq"))
-def ivf_pq_adc_topk(
-    vprobes: jax.Array,      # [b, budget] int32 virtual bucket ids (-1 pad)
-    coarse_pos: jax.Array,   # [b, budget] int32 coarse rank of each probe
-    lut_all: jax.Array,      # [b, nprobe, m, ksub] f32 residual ADC tables
-    code_buckets: jax.Array,  # [B, cap, m] uint8
-    bucket_valid: jax.Array,  # [B, cap] bool/float
-    bucket_slot: jax.Array,   # [B, cap] int32
-    k: int,
-    interpret: bool = False,
-    nq: int = 0,
-) -> Tuple[jax.Array, jax.Array]:
-    """Fused ADC probed-bucket scan -> (scores[b, k], slots[b, k]).
+def _grid_spec(q0, nq, width, nprobe, m, ksub, cap):
+    """The (query, rank) grid of the batch's queries q0 .. q0 + nq: the
+    scalar-prefetched ids of all its steps have to fit SMEM."""
 
-    Scores are negated ADC distances ('larger is better'); a hot list's
-    spill buckets share the coarse rank's LUT via coarse_pos, so the LUT
-    block index_map re-reads the SAME VMEM-resident table instead of
-    recomputing it per bucket (the Quick ADC property)."""
-    b = vprobes.shape[0]
-    budget = vprobes.shape[1]
-    nb, cap, m = code_buckets.shape
-    ksub = lut_all.shape[3]
-    nq = nq or b
+    def step(ref, q, r):
+        return ref[q * width + r]
 
-    def bucket_map(q, r, vp, cp):
-        return (jnp.maximum(vp[q, r], 0), 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(nq, budget),
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(nq, width),
         in_specs=[
             pl.BlockSpec(
-                (1, 1, m, ksub),
-                lambda q, r, vp, cp: (q, cp[q, r], 0, 0),
-            ),                                            # resident LUT
-            pl.BlockSpec((1, cap, m), bucket_map),        # code bucket
-            pl.BlockSpec((1, 1, cap), bucket_map),        # valid
-            pl.BlockSpec((1, 1, cap), bucket_map),        # slots
-        ],
-        out_specs=[
+                (m, TABLES, ksub),
+                lambda q, r, vp, fb, fc: (
+                    0, ((q0 + q) * nprobe + step(fc, q, r)) // TABLES, 0),
+            ),                                            # resident tables
             pl.BlockSpec(
-                (ROW_BLOCK, OUT_PAD),
-                lambda q, r, vp, cp: (q // ROW_BLOCK, 0),
-            ),
-        ] * 2,
-        scratch_shapes=[pltpu.VMEM((1, cap), jnp.float32)],   # ADC sums
-    )
-    out_v, out_i = pl.pallas_call(
-        functools.partial(_adc_kernel, k=k, m=m, ksub=ksub),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, OUT_PAD), jnp.float32),
-            jax.ShapeDtypeStruct((b, OUT_PAD), jnp.int32),
+                (1, cap, m),
+                lambda q, r, vp, fb, fc: (step(fb, q, r), 0, 0),
+            ),                                            # code bucket
+            pl.BlockSpec(
+                (1, 1, cap),
+                lambda q, r, vp, fb, fc: (step(fb, q, r), 0, 0),
+            ),                                            # valid row
         ],
-        interpret=interpret,
-    )(
-        vprobes,
-        coarse_pos,
-        lut_all,
-        code_buckets,
-        bucket_valid.astype(jnp.float32)[:, None, :],
-        bucket_slot[:, None, :],
+        out_specs=pl.BlockSpec(
+            (1, OUT_ROWS, cap),
+            lambda q, r, vp, fb, fc: (q, r // OUT_ROWS, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((ksub, m), jnp.float32),       # codeword-major table
+            pltpu.VMEM((1, cap), jnp.float32),        # ADC sums
+            pltpu.SMEM((1,), jnp.int32),              # table in tbl_ref
+        ],
     )
-    return out_v[:, :k], out_i[:, :k]
 
 
-def ivf_pq_adc_search(
-    vprobes, coarse_pos, lut_all, code_buckets, bucket_valid, bucket_slot,
-    k: int, interpret: bool,
-):
-    """ROW_BLOCK-pads the per-query arrays and clamps the grid to the
-    real batch; traced inside the request's one program
-    (`ivf_pq.ivfpq_search_program`), which passes `interpret` as a static
-    of its own (config.pallas_interpret: compiled on the TPU, interpreted
-    on the CPU)."""
-    b = vprobes.shape[0]
-    pad = (-b) % ROW_BLOCK
-    if pad:
-        vprobes = jnp.concatenate(
-            [vprobes, jnp.full((pad, vprobes.shape[1]), -1, vprobes.dtype)]
-        )
-        coarse_pos = jnp.concatenate(
-            [coarse_pos,
-             jnp.zeros((pad, coarse_pos.shape[1]), coarse_pos.dtype)]
-        )
-        lut_all = jnp.concatenate(
-            [lut_all, jnp.zeros((pad,) + lut_all.shape[1:], lut_all.dtype)]
-        )
-    vals, slots = ivf_pq_adc_topk(
-        vprobes, coarse_pos, lut_all, code_buckets, bucket_valid,
-        bucket_slot, k=k, interpret=interpret, nq=b,
-    )
-    return vals[:b], slots[:b]
+@sentinel_jit("ops.pallas.pq_adc", static_argnames=("interpret",))
+def ivf_pq_adc_scores(
+    vprobes: jax.Array,       # [b, budget] int32 virtual bucket ids (-1 pad)
+    coarse_pos: jax.Array,    # [b, budget] int32 coarse rank of each probe
+    lut_all: jax.Array,       # [m, b, nprobe, ksub] f32 residual ADC tables
+    code_buckets: jax.Array,  # [B, cap, m] uint8
+    bucket_valid: jax.Array,  # [B, cap] bool/float
+    interpret: bool = False,
+) -> jax.Array:
+    """ADC scores of every probed slot -> [b, budget, cap] f32: minus the
+    ADC distance, -inf where the slot is invalid or the rank is -1.
+
+    The tables come subspace-major, as the LUT's batched contraction lays
+    them out: the kernel reads them as they lie, TABLES (query, rank)
+    tables a block, and turns a table codeword-major in VMEM once (a
+    list's spill buckets reuse it)."""
+    b, budget = vprobes.shape
+    m, _, nprobe, ksub = lut_all.shape
+    cap = code_buckets.shape[1]
+    if ksub % TILE or (cap > ROWS and cap % ROWS):
+        raise ValueError(f"ADC kernel: ksub {ksub} / cap {cap} unsupported")
+    # whole output tiles of ranks; the added ranks are -1
+    width = -(-budget // OUT_ROWS) * OUT_ROWS
+    vprobes = jnp.pad(vprobes, ((0, 0), (0, width - budget)),
+                      constant_values=-1)
+    coarse_pos = jnp.pad(coarse_pos, ((0, 0), (0, width - budget)))
+    # the blocks a step fetches: its own where the rank is live, else the
+    # last live rank's at or before it, which the pipeline already holds
+    ranks = jnp.arange(width, dtype=jnp.int32)[None, :]
+    last = jax.lax.cummax(jnp.where(vprobes >= 0, ranks, -1), axis=1)
+    last = jnp.maximum(last, 0)
+    fetch_bucket = jnp.maximum(
+        jnp.take_along_axis(vprobes, last, axis=1), 0)
+    fetch_coarse = jnp.take_along_axis(coarse_pos, last, axis=1)
+    lut = lut_all.reshape(m, b * nprobe, ksub)
+    valid = bucket_valid.astype(jnp.float32)[:, None, :]
+    per_call = max(1, PREFETCH_STEPS // width)
+    parts = []
+    for q0 in range(0, b, per_call):
+        nq = min(per_call, b - q0)
+        part = slice(q0, q0 + nq)
+        parts.append(pl.pallas_call(
+            functools.partial(_adc_score_kernel, width=width, nprobe=nprobe,
+                              q0=q0),
+            grid_spec=_grid_spec(q0, nq, width, nprobe, m, ksub, cap),
+            out_shape=jax.ShapeDtypeStruct((nq, width, cap), jnp.float32),
+            interpret=interpret,
+        )(vprobes[part].reshape(-1), fetch_bucket[part].reshape(-1),
+          fetch_coarse[part].reshape(-1), lut, code_buckets, valid))
+    scores = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+    return scores[:, :budget]

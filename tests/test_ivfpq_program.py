@@ -6,7 +6,9 @@ it were) and recomputes in float64 what faiss IndexIVFPQ with residual codes
 and an exact refine does — coarse distances, residual codes, the
 per-(query, list) lookup tables, ADC distances over the probed lists, the
 top-k' shortlist and the exact rerank. The served path is the request's
-one device program, ``index.ivfpq.search``.
+one device program, ``index.ivfpq.search``, whose ADC scores come from the
+XLA rank scan or, on the TPU, from the Pallas scoring kernel (interpreted
+here where a test forces it).
 """
 
 import jax
@@ -50,6 +52,17 @@ def _index(x, train=True, index_id=7):
 @pytest.fixture(scope="module")
 def trained(data):
     return _index(data[0])
+
+
+@pytest.fixture(params=["xla", "pallas"])
+def arm(request):
+    """The ADC arm a search takes: the XLA rank scan, or the Pallas scoring
+    kernel forced on (interpret mode off the TPU)."""
+    FLAGS.set("use_pallas_ivf_search", request.param == "pallas")
+    try:
+        yield request.param
+    finally:
+        FLAGS.set("use_pallas_ivf_search", "auto")
 
 
 # ------------------------------------------------------------ the reference
@@ -147,19 +160,22 @@ def _close(served, ref_d):
 
 @pytest.mark.parametrize("nprobe", [8, NLIST])
 @pytest.mark.parametrize("factor", [1, FACTOR])
-def test_served_matches_plain_ivfpq(data, trained, nprobe, factor):
+def test_served_matches_plain_ivfpq(data, trained, nprobe, factor, arm):
     """ids and distances: the ADC top-k (factor 1: no rerank, the wire
     distances are the ADC ones) and the ADC top-k' reranked exactly to k
-    (the shipped factor)."""
+    (the shipped factor), on either ADC arm."""
     x, q = data
     _assert_no_probe_dropped(trained, q, nprobe)
     ref = PlainIvfPq(trained, x)
     live = np.ones(N, bool)
+    key = f"ivfpq.scan_arm{{region=7,arm={arm}}}"
+    before = METRICS.dump().get(key, 0)
     FLAGS.set("ivfpq_rerank_factor", factor)
     try:
         got = trained.search(q, K, nprobe=nprobe)
     finally:
         FLAGS.set("ivfpq_rerank_factor", FACTOR)
+    assert METRICS.dump()[key] == before + 1
     want_rows, want_d = ref.search(q, K, nprobe, live,
                                    kprime=K * factor if factor > 1 else None)
     for r, rows, dist in zip(got, want_rows, want_d):
@@ -208,6 +224,66 @@ def test_filters_and_tombstones_match_plain_ivfpq(data, case):
         assert live[(np.asarray(r.ids) - 5) // 3].all()
         assert list((np.asarray(r.ids) - 5) // 3) == list(rows)
         _close(r.distances, dist)
+
+
+@pytest.mark.parametrize("calls", [1, 3])
+def test_pallas_scores_shortlist_as_the_xla_scan(data, calls, monkeypatch):
+    """The Pallas scoring kernel (interpreted) and `_ivfpq_scan_kernel`,
+    each through the shared top-k: a hot list's spill buckets sharing one
+    table, ranks of -1, tombstoned and filtered-out slots, a batch of 5 and
+    a k' past the live codes. The shortlists hold the same slots, their
+    scores within 1e-6 of each other. `calls` 3: the batch's queries split
+    over three kernel calls, as a batch whose probe ids overflow SMEM is."""
+    import jax.numpy as jnp
+
+    from dingo_tpu.index.ivf_flat import coarse_probes
+    from dingo_tpu.index.ivf_layout import expand_probes_ranked
+    from dingo_tpu.index.ivf_pq import (
+        _adc_shortlist, _ivfpq_adc_lut, _ivfpq_scan_kernel)
+    from dingo_tpu.ops import pallas_pq
+
+    x, q = data
+    rng = np.random.default_rng(38)
+    hot = (x[:1] + 0.3 * rng.standard_normal((N // 2, D))).astype(np.float32)
+    idx = _index(np.concatenate([x[:N // 2], hot]), index_id=17)
+    idx.delete(_ids()[::7])
+    spec = FilterSpec(ranges=[(0, 9000)])
+    idx._ensure_view()
+    view = idx._view
+    valid = idx._bucket_valid_for_filter(spec, idx._prep_filter_mask(spec))
+    qb = jnp.asarray(np.concatenate([q[:3], hot[:2] + 0.01]))
+    b, nprobe = 5, 8
+    probes = coarse_probes(qb, idx.centroids, idx._c_sqnorm, nprobe)
+    vp, cp = expand_probes_ranked(probes, view.probe_table, nprobe,
+                                  view.probe_table.shape[1])
+    vp_h, cp_h = np.asarray(vp), np.asarray(cp)
+    assert (vp_h < 0).any()
+    shared = (cp_h[:, 1:] == cp_h[:, :-1]) & (vp_h[:, 1:] >= 0)
+    assert shared.any()                 # spill buckets of one probed list
+    width = -(-vp_h.shape[1] // pallas_pq.OUT_ROWS) * pallas_pq.OUT_ROWS
+    monkeypatch.setattr(pallas_pq, "PREFETCH_STEPS", width * -(-b // calls))
+    scores = pallas_pq.ivf_pq_adc_scores.__wrapped__(
+        vp, cp, _ivfpq_adc_lut(qb, idx.centroids, probes, idx.codebooks),
+        idx._code_buckets, valid, interpret=True)
+    assert np.isneginf(np.asarray(scores)[vp_h < 0]).all()
+    slots = jnp.take(view.bucket_slot, jnp.maximum(vp, 0), axis=0)
+    live = int(np.isfinite(np.asarray(scores)).sum(axis=(1, 2)).max())
+    for kscan in (40, live + 64):
+        got_d, got_s = _adc_shortlist(scores.reshape(b, -1),
+                                      slots.reshape(b, -1), kscan)
+        want_d, want_s = _ivfpq_scan_kernel(
+            idx._code_buckets, valid, view.bucket_slot, view.bucket_coarse,
+            probes, vp, cp, qb, idx.centroids, idx.codebooks, kscan, True)
+        got_s, want_s = np.asarray(got_s), np.asarray(want_s)
+        for row in range(b):
+            g = dict(zip(got_s[row], np.asarray(got_d[row])))
+            w = dict(zip(want_s[row], np.asarray(want_d[row])))
+            assert set(g) == set(w)
+            assert (got_s[row] < 0).sum() == (want_s[row] < 0).sum()
+            ok = [s for s in g if s >= 0]
+            np.testing.assert_allclose([g[s] for s in ok],
+                                       [w[s] for s in ok], rtol=1e-6)
+        assert (got_s < 0).any() == (kscan > live)
 
 
 @pytest.mark.parametrize("filtered", [False, True])

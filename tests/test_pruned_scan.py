@@ -1,6 +1,6 @@
 """Early-pruning dimension-blocked scan kernels: parity vs the XLA scan
 across metrics x precision tiers (interpret mode on CPU), pruning
-observability, the fused Quick-ADC IVF_PQ path, and the steady-state
+observability, IVF_PQ's Pallas ADC scoring arm, and the steady-state
 recompile invariant."""
 
 import numpy as np
@@ -223,7 +223,7 @@ def test_flat_fused_auto_is_off_on_cpu(corpus):
 
 @pytest.mark.parametrize("host_vectors", [False, True])
 def test_ivfpq_fused_adc_parity(corpus, host_vectors):
-    """Quick-ADC fused kernel: identical post-rerank results on the
+    """The Pallas ADC scoring kernel: identical post-rerank results on the
     device-store arm; identical shortlist->rerank ids on the host arm."""
     x, ids, q = corpus
     idx = TpuIvfPq(3, IndexParameter(

@@ -82,28 +82,21 @@ def _ivf_batch_topk(xdt):
     ), dict(k=K, ascending=True, interpret=False)
 
 
-def _ivf_pq_adc_topk():
-    return pallas_pq.ivf_pq_adc_topk, (
-        _sds((B, BUDGET), i32), _sds((B, BUDGET), i32),
-        _sds((B, NPROBE, M, KSUB), f32), _sds((NB, CAP, M), u8),
-        _sds((NB, CAP), jnp.bool_), _sds((NB, CAP), i32),
-    ), dict(k=K, interpret=False, nq=B)
-
-
-def _ivf_pq_adc_topk_cell():
-    """The ADC kernel at `ivfpq768.batch64`'s buckets of 32 codes (122,880
-    rows over 4,096 lists) and k' = 64, the most the Pallas gate serves
-    (`ivf_pq.py`: k' <= 64; the cell's k' = 10 x 64 runs the XLA scan)."""
-    return pallas_pq.ivf_pq_adc_topk, (
-        _sds((B, BUDGET), i32), _sds((B, BUDGET), i32),
-        _sds((B, NPROBE, M, KSUB), f32), _sds((NB, 32, M), u8),
-        _sds((NB, 32), jnp.bool_), _sds((NB, 32), i32),
-    ), dict(k=64, interpret=False, nq=B)
+def _ivf_pq_adc_scores(cap, budget):
+    """The ADC scoring kernel over buckets of `cap` codes and `budget`
+    probed buckets a query: cap 32 is `ivfpq768.batch64`'s (122,880 rows
+    over 4,096 lists)."""
+    return pallas_pq.ivf_pq_adc_scores, (
+        _sds((B, budget), i32), _sds((B, budget), i32),
+        _sds((M, B, NPROBE, KSUB), f32), _sds((NB, cap, M), u8),
+        _sds((NB, cap), jnp.bool_),
+    ), dict(interpret=False)
 
 
 def _ivfpq_search_program():
-    """IVF_PQ's whole request program with the Quick-ADC arm, at the same
-    served shapes, the exact rerank included."""
+    """IVF_PQ's whole request program with the Pallas scoring arm, at the
+    cell's shapes and shortlist (k' = 10 x 64), the exact rerank
+    included."""
     from dingo_tpu.index.ivf_pq import ivfpq_search_program
     from dingo_tpu.ops.distance import Metric
 
@@ -112,7 +105,7 @@ def _ivfpq_search_program():
         _sds((4096, 2), i32), _sds((NB, 32), jnp.bool_), _sds((NB, 32), i32),
         _sds((NB,), i32), _sds((NB, 32, M), u8), _sds((M, KSUB, D // M), f32),
         _sds((N, D), f32), _sds((N,), f32),
-    ), dict(k=K, kscan=64, nprobe=NPROBE, metric=Metric.L2, pallas=True,
+    ), dict(k=K, kscan=640, nprobe=NPROBE, metric=Metric.L2, pallas=True,
             precompute_lut=True, interpret=False)
 
 
@@ -128,8 +121,9 @@ CASES = {
     "ivf_batch_topk[f32]": lambda: _ivf_batch_topk(f32),
     "ivf_batch_topk[bf16]": lambda: _ivf_batch_topk(bf16),
     "ivf_batch_topk[sq8]": lambda: _ivf_batch_topk(u8),
-    "ivf_pq_adc_topk[m=96]": _ivf_pq_adc_topk,
-    "ivf_pq_adc_topk[m=96,cap=32,k=64]": _ivf_pq_adc_topk_cell,
+    "ivf_pq_adc_scores[m=96,cap=32]": lambda: _ivf_pq_adc_scores(32, 56),
+    "ivf_pq_adc_scores[m=96,cap=1024]": lambda: _ivf_pq_adc_scores(
+        CAP, BUDGET),
     "ivfpq_search_program[pallas]": _ivfpq_search_program,
 }
 
@@ -198,3 +192,31 @@ def test_pq_encode_compares_in_f32_for_v5e():
     fused = hlo.split("\n}\n")          # one computation per piece
     argmins = [c for c in fused if "nearest_index/reduce" in c]
     assert argmins and not any("convolution(" in c for c in argmins)
+
+
+@pytest.mark.slow
+def test_pq_adc_carries_no_bf16_for_v5e():
+    """IVF_PQ's request program with the Pallas scoring arm, compiled for a
+    described v5e: no table entry, partial sum or score is carried in
+    bf16, neither in the XLA parts (the LUT, the top-k, the rerank) nor in
+    the kernel's own program, whose one contraction (each row's lane sum)
+    takes f32 operands at HIGHEST. A bf16 table destroyed the ranking
+    (ops/pq.py)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / no topology support
+        pytest.skip(f"no TPU topology for ahead-of-time compilation: {e}")
+    on_chip = SingleDeviceSharding(topo.devices[0])
+    fn, args = _jitted("ivfpq_search_program[pallas]")
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip)
+            for a in args]
+    hlo = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo and "bf16[" not in hlo
+    kernel, kargs = _jitted("ivf_pq_adc_scores[m=96,cap=32]")
+    jaxpr = str(jax.make_jaxpr(kernel)(*kargs))
+    assert "pallas_call" in jaxpr and "bf16" not in jaxpr
+    assert "precision=(Precision.HIGHEST, Precision.HIGHEST)" in jaxpr
