@@ -24,6 +24,7 @@ sys.path.insert(0, os.path.dirname(HERE))
 
 import numpy as np  # noqa: E402
 
+import columns  # noqa: E402
 import reference  # noqa: E402
 
 PARTITION = 1
@@ -32,6 +33,7 @@ PARTITION = 1
 class Caller:
     def __init__(self, spec: dict):
         from dingo_tpu.client import DingoClient
+        from dingo_tpu.server import pb
 
         self.spec = spec
         self.cfg = spec["config"]
@@ -40,21 +42,31 @@ class Caller:
         self.index = spec["caller"]
         # `wrap_client` (selftest.py only) names a function that breaks the
         # client underneath the timed path: [file, function]
-        wrap = load_wrapper(spec.get("wrap_client"))
+        wrap = load_wrapper(spec.get("wrap_client"), spec)
         self._plain_client = lambda: DingoClient(
             spec["coordinator"], {"s0": spec["store"]})
         self._new_client = lambda: wrap(self._plain_client())
         self._local = threading.local()
         self.data = reference.Data(self.seed, self.cfg)
         self.search_args = dict(self.mix["search_args"])
+        # a filtered search: the enums by their pb names, the predicates
+        # filled per request (`columns.fill`); a read-back is never filtered
+        self.predicates = self.search_args.pop("predicates", None)
+        self.plain_args = {a: v for a, v in self.search_args.items()
+                           if a not in columns.FILTER_ARGS}
+        for arg, enum in (("filter", pb.VectorFilter),
+                          ("filter_type", pb.VectorFilterType)):
+            if arg in self.search_args:
+                self.search_args[arg] = enum.Value(self.search_args[arg])
         self.k = self.search_args["topk"]
         self.batch = self.mix["batch"]
         self.pool = None
         self.rng = np.random.default_rng([7, self.seed, self.index])
         self.next_write = 0
+        self.next_search = 0
         # one tuple per request, appended whole (threads share the list):
         # (t_due, t_start, t_done, ok, region_map_s, offset, fresh_request,
-        #  ids, dists)
+        #  ids, dists, predicates as JSON or '')
         self.done = []
         self.errors = []
 
@@ -99,12 +111,26 @@ class Caller:
                 ids[r, c], dists[r, c] = vid, d
         return ids, dists
 
-    def search(self, t_due: float, offset: int, fresh_request: int = -1):
+    def _request_number(self) -> int:
+        """Taken where the request is made, not in the thread that sends it,
+        so each number's draws are the same in every run."""
+        self.next_search += 1
+        return self.next_search - 1
+
+    def search(self, t_due: float, offset: int, fresh_request: int = -1,
+               request: int = -1):
         q = self._queries(offset) if fresh_request < 0 \
             else self._fresh(fresh_request)[1]
+        kw, kept = self.plain_args, ""
+        if fresh_request < 0:
+            kw = self.search_args
+            if self.predicates:
+                filled = columns.fill(self.predicates, self.cfg, self.seed,
+                                      self.index, request)
+                kw, kept = dict(kw, predicates=filled), json.dumps(filled)
         t_start = time.monotonic()
         try:
-            rows = self.client.vector_search(PARTITION, q, **self.search_args)
+            rows = self.client.vector_search(PARTITION, q, **kw)
             t_done, ok = time.monotonic(), len(rows) == len(q)
         except Exception as e:  # noqa: BLE001 — a failed request is counted
             t_done, ok, rows = time.monotonic(), False, []
@@ -112,40 +138,46 @@ class Caller:
                 self.errors.append(f"{type(e).__name__}: {e}"[:300])
         self.done.append((t_due if t_due is not None else t_start, t_start,
                           t_done, ok, self._take_map_s(), offset,
-                          fresh_request, *self._arrays(rows)))
+                          fresh_request, *self._arrays(rows), kept))
 
     def insert(self):
         request = self.next_write
         self.next_write += 1
         ids, vectors = self._fresh(request)
+        kw = {"scalars": columns.as_rows(self.data.columns.fresh(
+            request, self.batch))} if self.data.columns else {}
         t_start = time.monotonic()
         try:
-            self.client.vector_add(PARTITION, ids.tolist(), vectors)
+            self.client.vector_add(PARTITION, ids.tolist(), vectors, **kw)
             t_done, ok = time.monotonic(), True
         except Exception as e:  # noqa: BLE001
             t_done, ok = time.monotonic(), False
             if len(self.errors) < 5:
                 self.errors.append(f"{type(e).__name__}: {e}"[:300])
         self.done.append((t_start, t_start, t_done, ok, self._take_map_s(),
-                          -1, request, None, None))
+                          -1, request, None, None, ""))
         return ok
 
     def one(self, t_due=None):
         if self.mix["operation"] == "vector_add":
             return self.insert()
         return self.search(
-            t_due, int(self.rng.integers(0, self.mix["query_pool"])))
+            t_due, int(self.rng.integers(0, self.mix["query_pool"])),
+            request=self._request_number())
 
     # ------------------------------------------------------------ commands
     def load(self) -> dict:
-        """Set-up: every block of the corpus through vector_add (a client
-        of its own: a test's wrapper sits under the timed path only)."""
+        """Set-up: every block of the corpus through vector_add, with its
+        scalar columns where the configuration states some (a client of its
+        own: a test's wrapper sits under the timed path only)."""
         t0 = time.monotonic()
         client = self._plain_client()
         for b in range(self.data.n_blocks):
             x = self.data.block(b)
             lo = b * self.data.block_rows
-            client.vector_add(PARTITION, range(lo, lo + len(x)), x)
+            kw = {"scalars": columns.as_rows(self.data.columns.block(b))} \
+                if self.data.columns else {}
+            client.vector_add(PARTITION, range(lo, lo + len(x)), x, **kw)
         client.close()
         return {"seconds": time.monotonic() - t0}
 
@@ -177,7 +209,8 @@ class Caller:
                 for off, d in zip(offsets, due):
                     t_due = t_open + d
                     time.sleep(max(0.0, t_due - time.monotonic()))
-                    futures.append(ex.submit(self.search, t_due, int(off)))
+                    futures.append(ex.submit(self.search, t_due, int(off),
+                                             -1, self._request_number()))
                 for f in futures:
                     f.result()
         # this process's CPU seconds (all its threads) from the open to its
@@ -198,8 +231,10 @@ class Caller:
                             f"caller{self.index}.{what}.npz")
         rec = np.asarray([r[:5] for r in self.done], np.float64).reshape(-1, 5)
         searched = [r for r in self.done if r[7] is not None]
+        filtered = {"predicates": np.asarray([r[9] for r in self.done], str)} \
+            if self.predicates else {}
         np.savez(
-            path, records=rec, batch=self.batch,
+            path, records=rec, batch=self.batch, **filtered,
             offsets=np.asarray([r[5] for r in self.done], np.int64),
             fresh_request=np.asarray([r[6] for r in self.done], np.int64),
             ids=(np.stack([r[7] for r in searched]) if searched
@@ -210,7 +245,9 @@ class Caller:
                 "writes": self.next_write}
 
 
-def load_wrapper(named):
+def load_wrapper(named, caller_spec):
+    """-> client -> the client broken by `named`'s function, which gets the
+    caller's spec beside the client (seed, configuration, traffic)."""
     if not named:
         return lambda client: client
     import importlib.util
@@ -219,7 +256,7 @@ def load_wrapper(named):
     spec = importlib.util.spec_from_file_location("client_wrapper", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return getattr(mod, function)
+    return lambda client: getattr(mod, function)(client, caller_spec)
 
 
 def main() -> int:

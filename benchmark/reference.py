@@ -8,6 +8,9 @@ re-ranked by float64 direct distances), and every distance a reply carries is
 checked against the float64 direct distance of that id's row. The metric is a
 file of its own, `distances/<metric>.py`; a configuration whose metric,
 precision or generator has no arm here is refused, never run as another.
+A filtered request (its predicates stored beside its reply) is judged
+against the exact top-k over the rows that satisfy them, by the scalar
+columns `columns.py` makes from the seed as the load did.
 
 Runs as a process of its own once the window has closed and the store is
 stopped (`python benchmark/reference.py --job job.json`), on every core, and
@@ -27,13 +30,14 @@ import sys
 import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+
+import columns  # noqa: E402
+
 CENTERS, ROWS, QUERIES, FRESH = 1, 2, 3, 4     # rng stream tags
 #: the nearest precision below the configuration's, per stated precision
 CONTROLS = {"fp32": "bf16"}
-
-
-class Unsupported(ValueError):
-    """The configuration states something no arm of the reference honours."""
+Unsupported = columns.Unsupported
 
 
 def load_distance(metric: str):
@@ -75,6 +79,7 @@ class Data:
         self.cluster_noise = float(p["cluster_noise"])
         self.query_noise = float(p["query_noise"])
         self._centers = None
+        self.columns = columns.Columns(seed, config)
 
     @property
     def centers(self) -> np.ndarray:
@@ -154,7 +159,7 @@ def exact_topk(dist, x, queries, k: int):
     """Exact top-k: float32 shortlist of 4k, re-ranked by the float64
     direct distance. -> (ids [nq, k], distances [nq, k] float64, nearest
     first)"""
-    ids, _ = shortlist(dist, x, queries, 4 * k)
+    ids, _ = shortlist(dist, x, queries, min(4 * k, len(x)))
     d = dist.served64(x, queries, ids)
     order = np.argsort(d if dist.ASCENDING else -d, axis=1)[:, :k]
     return np.take_along_axis(ids, order, 1), np.take_along_axis(d, order, 1)
@@ -171,17 +176,48 @@ def control_topk(dist, x, queries, k: int):
     return np.take_along_axis(ids, order, 1), d if dist.ASCENDING else -d
 
 
+def where_topk(fn, dist, x, queries, k: int, group, masks):
+    """`fn` (`exact_topk` or `control_topk`) over the rows each query's
+    predicates let through: `group` [nq] indexes `masks` (bool over x's
+    rows), -1 for a query with none. -> (ids [nq, k], -1 past the rows that
+    match; values [nq, k], inf past them), nearest first."""
+    ids = np.full((len(queries), k), -1, np.int64)
+    vals = np.full((len(queries), k), np.inf)
+    for g in np.unique(group):
+        rows = np.flatnonzero(group == g)
+        sub = None if g < 0 else np.flatnonzero(masks[g])
+        xs = x if sub is None else x[sub]
+        kk = min(k, len(xs))
+        if kk:
+            i, v = fn(dist, xs, queries[rows], kk)
+            ids[rows, :kk] = i if sub is None else sub[i]
+            vals[rows, :kk] = v
+    return ids, vals
+
+
 # -------------------------------------------------------------- comparison
 def compare_replies(dist, x, queries, served_ids, served_d, k: int,
-                    truth_for: np.ndarray = None):
+                    truth_for: np.ndarray = None, allowed=None):
     """`served_ids`/`served_d` [nq, k] (ids -1 where a reply was short)
     against the reference. Every reply: shape, order and each distance.
     Rows listed in `truth_for` (indices): recall against the exact top-k.
-    -> dict of numbers."""
+    `allowed` = (group [nq], masks) where requests were filtered
+    (`where_topk`): a filtered query's truth is the exact top-k over the
+    rows that match, its recall is over min(k, matches), its reply may be
+    short by what fewer than k matches leave, and every served id whose row
+    does not match counts in `unmatched_rows`. -> dict of numbers."""
     nq = len(queries)
     out = {"compared_queries": int(nq)}
-    short = int((served_ids < 0).sum())
     in_range = (served_ids >= 0) & (served_ids < len(x))
+    if allowed is None:
+        short = int((served_ids < 0).sum())
+    else:
+        group, masks = allowed
+        # group -1 (no predicates) takes the last entry: every row
+        n_match = np.asarray([int(m.sum()) for m in masks] + [len(x)])
+        need = np.minimum(k, n_match[group])
+        short = int(np.maximum(
+            0, (served_ids < 0).sum(axis=1) - (k - need)).sum())
     out["malformed_items"] = short + int(((served_ids >= 0) & ~in_range).sum())
     ids = np.where(in_range, served_ids, -1)
     srt = np.sort(ids, axis=1)
@@ -196,17 +232,35 @@ def compare_replies(dist, x, queries, served_ids, served_d, k: int,
     err = np.where(ids >= 0,
                    np.abs(d - ref_d) / dist.scale(x, queries, safe), 0.0)
     out["dist_err"] = float(err.max()) if err.size else 0.0
+    if allowed is not None:
+        matched = np.ones(ids.shape, bool)
+        for g, m in enumerate(masks):
+            rows = group == g
+            matched[rows] = m[safe[rows]]
+        out["unmatched_rows"] = int(((ids >= 0) & ~matched).sum())
     if truth_for is not None and len(truth_for):
         tq = queries[truth_for]
-        _, td = exact_topk(dist, x, tq, k)
-        kth = td[:, -1][:, None]
+        if allowed is None:
+            _, td = exact_topk(dist, x, tq, k)
+            kth = td[:, -1][:, None]
+        else:
+            _, td = where_topk(exact_topk, dist, x, tq, k, group[truth_for],
+                               masks)
+            want = need[truth_for]
+            kth = td[np.arange(len(td)), np.maximum(want, 1) - 1][:, None]
         # a served id counts where its true distance is inside the exact
         # k-th (ties at the k-th place count for either side)
         inside = (ref_d[truth_for] <= kth + 1e-9 * np.abs(kth)) \
             if dist.ASCENDING else \
             (ref_d[truth_for] >= kth - 1e-9 * np.abs(kth))
         hit = (ids[truth_for] >= 0) & inside
-        out["recall_at_10"] = float(hit.sum(axis=1).mean() / k)
+        if allowed is None:
+            out["recall_at_10"] = float(hit.sum(axis=1).mean() / k)
+        else:
+            # a query that no row matches has found all there was
+            hit &= matched[truth_for]
+            out["recall_at_10"] = float(np.where(
+                want > 0, hit.sum(axis=1) / np.maximum(want, 1), 1.0).mean())
         out["recall_queries"] = int(len(truth_for))
     return out
 
@@ -217,7 +271,9 @@ def run_job(job: dict, control: str = "") -> dict:
     [...]]}. The npz holds `offsets` (start
     of each reply's queries in the pool, or -1 for a fresh-row read-back,
     then `fresh_request` names the write request whose rows were the
-    queries), `batch`, `ids` [n, batch, k], `dists` [n, batch, k]."""
+    queries), `batch`, `ids` [n, batch, k], `dists` [n, batch, k], and
+    where requests were filtered `predicates` [n]: each request's
+    predicates with their drawn values, as JSON ('' for none)."""
     k = job["k"]
     dist = check_supported(job["config"])
     data = Data(job["seed"], job["config"])
@@ -235,11 +291,13 @@ def run_job(job: dict, control: str = "") -> dict:
     keep = job.get("reply_rows")        # a sample of the replies, or all
     offsets, fresh_of = replies["offsets"], replies["fresh_request"]
     ids, dists = replies["ids"], replies["dists"]
+    preds = replies["predicates"] if "predicates" in replies.files else None
     truth_rows = np.asarray(job["truth_rows"], np.int64)
     if keep is not None:
         keep = np.asarray(keep, np.int64)
         offsets, fresh_of = offsets[keep], fresh_of[keep]
         ids, dists = ids[keep], dists[keep]
+        preds = None if preds is None else preds[keep]
         place = {int(r): i for i, r in enumerate(keep)}
         truth_rows = np.asarray(
             [place[int(t) // batch] * batch + int(t) % batch
@@ -254,17 +312,33 @@ def run_job(job: dict, control: str = "") -> dict:
         else:
             qs.append(data.fresh_rows(int(fr), batch)[1])
     queries = np.concatenate(qs) if qs else np.zeros((0, dim), np.float32)
+    allowed = None
+    if preds is not None:
+        # one mask per distinct set of predicates, over every row a read
+        # must see: the corpus and the acknowledged writes
+        cols = columns.concat([data.columns.corpus()] + [
+            data.columns.fresh(r, fresh["batch"])
+            for r in range(fresh["acked_requests"] if fresh else 0)])
+        distinct = sorted(set(preds.tolist()) - {""})
+        masks = [columns.matches(cols, json.loads(p)) for p in distinct]
+        number = {p: g for g, p in enumerate(distinct)}
+        group = np.repeat([number.get(p, -1) for p in preds.tolist()], batch)
+        allowed = (group.astype(np.int64), masks)
     out = {}
     if control and control != CONTROLS[job["config"]["precision"]]:
         raise SystemExit(f"unknown control {control!r}")
     if control:
         # the control answers the truth rows only: the rest of the replies
         # stay the program's, and one failing number is enough
-        cid, cd = control_topk(dist, x, queries[truth_rows], k)
+        cid, cd = control_topk(dist, x, queries[truth_rows], k) \
+            if allowed is None else where_topk(
+                control_topk, dist, x, queries[truth_rows], k,
+                allowed[0][truth_rows], allowed[1])
         ids, dists = ids.copy(), dists.copy()
         ids[truth_rows], dists[truth_rows] = cid, cd
         out["control"] = control
-    out.update(compare_replies(dist, x, queries, ids, dists, k, truth_rows))
+    out.update(compare_replies(dist, x, queries, ids, dists, k, truth_rows,
+                               allowed))
     if fresh:
         # read-your-writes: each read-back row is its own nearest neighbour
         own = []

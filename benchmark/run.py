@@ -59,21 +59,29 @@ class SetupDeadline:
     `readline` on a child that never answers as well as a sleep or a gRPC
     wait), or as soon as `aligned_open` lays the window's open past it."""
 
+    REPEAT_S = 0.5
+
     def __init__(self, seconds: float, t_start: float = T_START):
         self.seconds, self.t_start = float(seconds), t_start
         self.phase = "store_up"     # then region, load, build, warm, align
+        self.fired = None
 
     def late(self, after_s: float) -> RunFailure:
         return RunFailure(f"set-up past its deadline of {self.seconds:g} s in "
                           f"phase {self.phase} after {after_s:.0f} s")
 
     def _on_alarm(self, signum, frame):
-        raise self.late(time.monotonic() - self.t_start)
+        # the alarm repeats every REPEAT_S until disarmed: an exception
+        # raised inside a finalizer or a library's catch-all is swallowed,
+        # and the next one ends the phase, as late as the first said
+        self.fired = self.fired or time.monotonic()
+        raise self.late(self.fired - self.t_start)
 
     def arm(self) -> None:
         signal.signal(signal.SIGALRM, self._on_alarm)
         signal.setitimer(signal.ITIMER_REAL, max(
-            1e-3, self.t_start + self.seconds - time.monotonic()))
+            1e-3, self.t_start + self.seconds - time.monotonic()),
+            self.REPEAT_S)
 
     def check_open(self, t_open: float) -> None:
         """The open of the window is laid: past the deadline the run ends
@@ -272,8 +280,9 @@ class Cluster:
         return c
 
     def create_region(self, config: dict) -> None:
-        """The region by the configuration's own recipe: `index_parameter`
-        holds the fields of pb.VectorIndexParameter, enums by their names."""
+        """The regions by the configuration's own recipe, one a window of
+        `region_windows`: `index_parameter` holds the fields of
+        pb.VectorIndexParameter, enums by their names."""
         from dingo_tpu.server import pb
 
         try:
@@ -282,27 +291,40 @@ class Cluster:
             raise RunFailure(f"index_parameter is no VectorIndexParameter: {e}")
         # the store registers on its first heartbeat, and placement rides
         # the next: both are waited for
-        wait_for("region creation", lambda: self.client.create_index_region(
-            PARTITION, 0, 1 << 40, param,
-            replication=config["guarantees"]["replication"]),
-            60, self.children, 1.0)
-        wait_for("the region on the store",
-                 lambda: self.client.vector_status(PARTITION),
-                 60, self.children, 1.0)
+        self.window_rows = {}
+        for lo, hi, rows in region_windows(config):
+            made = wait_for("region creation",
+                            lambda: self.client.create_index_region(
+                                PARTITION, lo, hi, param,
+                                replication=config["guarantees"]["replication"]),
+                            60, self.children, 1.0)
+            self.window_rows[made.region_id] = rows
+        wait_for("every region on the store",
+                 lambda: len(self.client.vector_status(PARTITION))
+                 >= len(self.window_rows), 60, self.children, 1.0)
 
-    def build(self, config: dict) -> dict:
-        """VectorBuild where the index trains; -> the region's status."""
+    def build(self, config: dict) -> list:
+        """VectorBuild where the index trains (once for the partition: the
+        SDK builds each region); -> every region's status."""
         if config.get("build"):
             self.client.vector_build(PARTITION)
 
         def ready():
-            st = self.client.vector_status(PARTITION)[0]
-            ok = st["ready"] and (st["trained"] or not config.get("build"))
-            return st if ok else None
+            sts = self.client.vector_status(PARTITION)
+            ok = len(sts) == len(self.window_rows) and all(
+                st["ready"] and (st["trained"] or not config.get("build"))
+                for st in sts)
+            return sts if ok else None
 
-        status = wait_for("the region to be ready", ready, 600, self.children)
-        if status["count"] != config["rows"] or status["build_error"]:
-            raise RunFailure(f"region status after build: {status}")
+        statuses = wait_for("every region to be ready", ready, 600,
+                            self.children)
+        for st in statuses:
+            if st["count"] != self.window_rows.get(st["region_id"]) \
+                    or st["build_error"]:
+                raise RunFailure(f"region status after build: {st}")
+        if sum(st["count"] for st in statuses) != config["rows"]:
+            raise RunFailure(f"regions hold {statuses}, not {config['rows']} "
+                             "rows")
         # the operator's `cluster snapshot-index` after a bulk load: the
         # first save of a fresh index takes 1.4-1.6 s where the later ones
         # take 1.1 s (chip runs, PR 26), and the store saves at every 60-s
@@ -310,11 +332,13 @@ class Cluster:
         # is a later one whatever second of the minute set-up ends at.
         from dingo_tpu.server import pb
 
-        snap = self.client._stub("s0", "RegionControlService").RegionSnapshot(
-            pb.RegionSnapshotRequest(region_id=status["region_id"]))
-        if snap.error.errcode:
-            raise RunFailure(f"index snapshot failed: {snap.error.errmsg}")
-        return status
+        for st in statuses:
+            snap = self.client._stub(
+                "s0", "RegionControlService").RegionSnapshot(
+                pb.RegionSnapshotRequest(region_id=st["region_id"]))
+            if snap.error.errcode:
+                raise RunFailure(f"index snapshot failed: {snap.error.errmsg}")
+        return statuses
 
     def stop_callers(self) -> None:
         for c in self.callers:
@@ -341,14 +365,52 @@ class Cluster:
 
 
 # ------------------------------------------------------------ configuration
-def check_config(config: dict) -> None:
+def region_windows(config: dict) -> list:
+    """The id space cut into the configuration's `regions` (default 1)
+    windows of about equal rows, by whole `block_rows` blocks where there
+    are as many blocks as regions: the first from 0, the last open to
+    1 << 40, where a write cell's fresh ids land. -> [(lo, hi, rows)]"""
+    n, rows = config.get("regions", 1), config["rows"]
+    block = int(config["assumed"]["corpus"]["block_rows"])
+    blocks = -(-rows // block)
+    cuts = [i * blocks // n * block for i in range(n)] if blocks >= n \
+        else [i * rows // n for i in range(n)]
+    his = cuts[1:] + [1 << 40]
+    return [(lo, hi, min(hi, rows) - lo) for lo, hi in zip(cuts, his)]
+
+
+def check_config(config: dict, mix: dict = None) -> None:
     """What the configuration states has to be what its region recipe asks
-    the store for and what the reference can judge: a metric, precision or
-    corpus the harness would have to ignore is an error, not a run."""
+    the store for and what the reference can judge: a metric, precision,
+    corpus, column, region count or filter the harness would have to ignore
+    is an error, not a run. `mix`: the traffic it is to be run with."""
     import reference
 
     try:
         distance = reference.check_supported(config)
+        regions = config.get("regions", 1)
+        if not isinstance(regions, int) or regions < 1:
+            raise RunFailure(f"regions {regions!r}: a whole number >= 1")
+        small = [w for w in region_windows(config) if w[2] < config["k"]]
+        if small:
+            raise RunFailure(f"region windows {small} hold fewer than k = "
+                             f"{config['k']} rows")
+        args = (mix or {}).get("search_args", {})
+        if any(a in args for a in reference.columns.FILTER_ARGS):
+            if mix["operation"] != "vector_search":
+                raise RunFailure(f"a filter on {mix['operation']}: the "
+                                 "reference judges filtered searches only")
+            if args.get("filter") != "SCALAR_FILTER" or args.get(
+                    "filter_type", "QUERY_POST") not in ("QUERY_POST",
+                                                         "QUERY_PRE"):
+                raise RunFailure(
+                    f"filter {args.get('filter')!r} / {args.get('filter_type')!r}"
+                    ": the reference judges SCALAR_FILTER, QUERY_POST or "
+                    "QUERY_PRE")
+            if config["limits"].get("unmatched_rows", {}).get("max") != 0:
+                raise RunFailure("a filtered traffic needs the configuration's "
+                                 "limits.unmatched_rows.max 0")
+            reference.columns.check_predicates(args.get("predicates"), config)
     except reference.Unsupported as e:
         raise RunFailure(str(e))
     recipe = config["index_parameter"]
@@ -508,7 +570,7 @@ def main() -> int:
               file=sys.stderr)
         return 2
     try:
-        check_config(config)
+        check_config(config, mix)
     except RunFailure as e:
         print(f"configuration {cell['config']}: {e}", file=sys.stderr)
         return 2
@@ -543,12 +605,16 @@ def main() -> int:
     deadline.arm()
     failure = None
     try:
-        result = drive(args, bench, cell, config, mix, harness, cluster,
-                       rehearsal, deadline)
+        try:
+            result = drive(args, bench, cell, config, mix, harness, cluster,
+                           rehearsal, deadline)
+        finally:
+            # before the failure is caught: a repeat of the alarm comes
+            # here at the latest, and is caught below as the first would be
+            deadline.disarm()
     except RunFailure as e:
         failure = e
     finally:
-        deadline.disarm()
         # a whole run has stopped its processes itself (`drive`); a failed
         # one has nothing to save, so its store gets 5 s and not 15
         cluster.stop(timeout=5.0)
@@ -610,6 +676,10 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal,
         c.reply()                       # {"ready": true}; the probe's too
     t0 = time.monotonic()
     setup["region"] = t0 - T_START - setup["store_up"]
+    windows = region_windows(config)
+    say(f"region: {len(windows)} over ids " + ", ".join(
+        f"[{lo}, {hi}) {rows} rows" for lo, hi, rows in windows)
+        + f" in {setup['region']:.1f}s")
     deadline.phase = "load"
     traffic_callers[0].ask(cmd="load")
     setup["load"] = time.monotonic() - t0
@@ -617,9 +687,10 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal,
         f"{setup['load']:.1f}s")
     t0 = time.monotonic()
     deadline.phase = "build"
-    status = cluster.build(config)
+    statuses = cluster.build(config)
     setup["build"] = time.monotonic() - t0
-    say(f"build: {setup['build']:.1f}s; region {status}")
+    say(f"build: {setup['build']:.1f}s; "
+        + "; ".join(f"region {st}" for st in statuses))
 
     # ---- warm the cell's own shapes ---------------------------------------
     t0 = time.monotonic()
@@ -748,9 +819,13 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal,
     else:
         ids = np.concatenate([d["ids"] for d in parts])
         offsets = np.concatenate([d["offsets"] for d in parts])
+        # each filtered request's predicates with their drawn values
+        filtered = {"predicates": np.concatenate(
+            [d["predicates"] for d in parts])} \
+            if "predicates" in parts[0].files else {}
         np.savez(job["replies"], batch=mix["batch"], offsets=offsets,
                  fresh_request=np.full(len(offsets), -1, np.int64), ids=ids,
-                 dists=np.concatenate([d["dists"] for d in parts]))
+                 dists=np.concatenate([d["dists"] for d in parts]), **filtered)
         # recall against the exact top-k on a sample of requests drawn from
         # the seed, the last one in it; every reply's distances are checked
         rng = np.random.default_rng([13, args.seed])
@@ -767,7 +842,7 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal,
             job["reply_rows"] = sorted(pick)
     with open(os.path.join(out, "job.json"), "w") as f:
         json.dump(job, f)
-    status = client.vector_status(PARTITION)[0]
+    statuses = client.vector_status(PARTITION)
 
     # ---- stop the program, then the trace reduction and the reference ------
     cluster.stop_callers()
@@ -821,6 +896,9 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal,
 
     t0 = time.monotonic()
     numbers = refer()
+    if "predicates" in mix["search_args"] and "unmatched_rows" not in numbers:
+        raise RunFailure("filtered requests judged with no unmatched_rows: "
+                         "the replies lost their predicates")
     say(f"reference and comparison: {time.monotonic() - t0:.1f}s over "
         f"{numbers.get('compared_queries')} queries")
 
@@ -832,7 +910,7 @@ def drive(args, bench, cell, config, mix, harness, cluster, rehearsal,
     compared, inside = judge(numbers)
     correct = inside and failed == 0
     compared["failed_requests"] = [failed, "<=", 0]
-    if not (status["ready"] and not status["build_error"]):
+    if not all(st["ready"] and not st["build_error"] for st in statuses):
         correct = False
         compared["region_ready"] = [0, ">=", 1]
 
