@@ -634,6 +634,7 @@ class DingoClient:
         again, so no row of a region that split or merged meanwhile is
         merged twice or missed. Bounded by the retry policy's rounds."""
         queries = np.asarray(queries, np.float32)
+        req = self._search_request(queries, topk, with_scalar_data, params)
         regions = self._regions_for_vector_ids(partition_id, refresh=False)
         rounds = self._retry.rounds
         stale = None
@@ -651,16 +652,53 @@ class DingoClient:
             if not regions:
                 raise ClientError("no index regions")
             try:
-                return self._search_regions(regions, queries, topk,
-                                            with_scalar_data, params)
+                return self._search_regions(regions, req, len(queries),
+                                            topk)
             except _StaleRoute as e:
                 stale = e
         raise ClientError(
             f"VectorSearch: route still stale after {rounds} rounds: {stale}")
 
-    def _search_regions(self, regions, queries, topk, with_scalar_data,
-                        params):
-        merged: List[List[Tuple[int, float]]] = [[] for _ in queries]
+    #: VectorSearchParameter fields `vector_search` takes as keywords of
+    #: the same name, copied as they are
+    _SEARCH_FIELDS = ("nprobe", "ef_search", "filter", "filter_type",
+                      "radius", "with_vector_data")
+
+    @classmethod
+    def _search_request(cls, queries, topk, with_scalar_data,
+                        params) -> "pb.VectorSearchRequest":
+        """The one request of a search: the queries boxed once and every
+        parameter copied once; each region gets it with its own context.
+        `predicates` is a list of {field, op, value} (op one of eq ne lt
+        le gt ge in), sent as ScalarPredicate with the value wire-encoded,
+        as the store decodes it. A keyword the request has no field for is
+        an error, not a parameter dropped on the way."""
+        req = pb.VectorSearchRequest()
+        for q in queries:
+            req.vectors.add().values.extend(q.tolist())
+        param = req.parameter
+        param.top_n = topk
+        param.with_scalar_data = with_scalar_data
+        rest = dict(params)
+        for name in cls._SEARCH_FIELDS:
+            if name in rest:
+                setattr(param, name, rest.pop(name))
+        param.vector_ids.extend(
+            int(i) for i in rest.pop("vector_ids", None) or ())
+        for pred in rest.pop("predicates", None) or ():
+            sp = param.predicates.add()
+            sp.field = pred["field"]
+            sp.op = getattr(pred["op"], "value", pred["op"])
+            sp.value = wire.encode_obj(pred["value"])
+        if "coprocessor" in rest:     # pb.Coprocessor (TABLE filter)
+            param.coprocessor.CopyFrom(rest.pop("coprocessor"))
+        if rest:
+            raise TypeError(
+                f"vector_search: no search parameter {sorted(rest)}")
+        return req
+
+    def _search_regions(self, regions, req, nq: int, topk: int):
+        merged: List[List[Tuple[int, float]]] = [[] for _ in range(nq)]
         # wire convention: L2/HAMMING distances ascend, IP/COSINE similarity
         # descends (ops/distance.py metric_ascending) — merge accordingly
         from dingo_tpu.ops.distance import Metric, metric_ascending
@@ -669,23 +707,10 @@ class DingoClient:
                   if regions[0].index_parameter else Metric.L2)
         ascending = metric_ascending(metric)
         for d in regions:
-            req = pb.VectorSearchRequest()
+            # the regions are asked one after another, and each call
+            # serializes the request as it starts: re-addressing it after
+            # a call returned changes no call in flight
             req.context.region_id = d.region_id
-            for q in queries:
-                v = req.vectors.add()
-                v.values.extend(q.tolist())
-            req.parameter.top_n = topk
-            req.parameter.with_scalar_data = with_scalar_data
-            if "nprobe" in params:
-                req.parameter.nprobe = params["nprobe"]
-            if "ef_search" in params:
-                req.parameter.ef_search = params["ef_search"]
-            if "filter" in params:
-                req.parameter.filter = params["filter"]
-            if "filter_type" in params:
-                req.parameter.filter_type = params["filter_type"]
-            if "coprocessor" in params:   # pb.Coprocessor (TABLE filter)
-                req.parameter.coprocessor.CopyFrom(params["coprocessor"])
             resp = self._call_leader(d, "IndexService", "VectorSearch", req,
                                      hedge=True, cached_route=True)
             for qi, row in enumerate(resp.batch_results):

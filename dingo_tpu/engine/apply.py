@@ -238,7 +238,7 @@ def _apply_vector_add(
         # index.upsert: assign, scatter, view maintenance
         with TRACER.start_child("index.upsert"):
             wrapper.add(data.ids, data.vectors, log_id,
-                        is_upsert=bool(data.is_update))
+                        is_upsert=bool(data.is_update), scalars=data.scalars)
 
 
 def _apply_vector_delete(

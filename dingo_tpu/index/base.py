@@ -11,8 +11,10 @@ host callback invoked per candidate inside faiss/hnswlib; under XLA that would
 be a host round-trip per candidate. Instead every filter mode is *compiled* to
 a per-slot validity bitmap on device (FilterSpec.slot_mask): id-range filters
 become vectorized compares on the resident id array, id-set filters become a
-sorted-array membership test (searchsorted). The bitmap composes with the
-tombstone/validity mask and feeds the masked top-k kernel (ops/topk.py).
+sorted-array membership test (searchsorted), and scalar predicates on the
+region's flagged fields become compares on the store's per-slot host columns
+(index/columns.py). The bitmap composes with the tombstone/validity mask and
+feeds the masked top-k kernel (ops/topk.py).
 
 The reference's *ByParallel ThreadPool sharding (vector_index.h:157-196) has no
 analog here: one batched device program already uses the whole chip.
@@ -27,6 +29,8 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from dingo_tpu.coprocessor.scalar_filter import ScalarPredicate
+from dingo_tpu.index.columns import ColumnsUnavailable, ScalarColumns
 from dingo_tpu.ops.distance import Metric
 
 
@@ -150,17 +154,22 @@ class FilterSpec:
                   SortFilterFunctor — scalar pre-filter candidates,
                   vector_reader.cc:853).
     exclude_ids — blacklist (IDSelectorNot semantics).
+    predicates  — a conjunction of ScalarPredicates on fields the store
+                  mirrors per slot (index/columns.py): the SCALAR filter of
+                  a region's scalar_speedup_keys, pre or post alike.
     """
 
     ranges: Optional[Sequence[Tuple[int, int]]] = None
     include_ids: Optional[np.ndarray] = None
     exclude_ids: Optional[np.ndarray] = None
+    predicates: Tuple[ScalarPredicate, ...] = ()
 
     def is_empty(self) -> bool:
         return (
             not self.ranges
             and self.include_ids is None
             and self.exclude_ids is None
+            and not self.predicates
         )
 
     def fingerprint(self) -> bytes:
@@ -181,14 +190,33 @@ class FilterSpec:
                 h.update(np.ascontiguousarray(
                     np.asarray(ids, np.int64)
                 ).tobytes())
+        for p in self.predicates:
+            h.update(b"p" + repr((p.field, p.op.value, p.value)).encode())
         return h.digest()
 
-    def slot_mask(self, ids_by_slot: np.ndarray) -> np.ndarray:
+    def slot_mask(self, ids_by_slot: np.ndarray,
+                  columns: Optional[ScalarColumns] = None) -> np.ndarray:
         """Compile this filter against the HOST id-by-slot array
-        [capacity] int64 (-1 = empty slot) -> bool mask [capacity].
+        [capacity] int64 (-1 = empty slot) and, for predicates, the store's
+        mirrored columns -> bool mask [capacity]. Predicates without a
+        complete column for each field raise ColumnsUnavailable.
 
         Runs in numpy: 64-bit ids stay off-device (JAX x64-off truncates
         int64), and a [capacity] bool upload per filtered search is cheap."""
+        mask = self.id_mask(ids_by_slot)
+        if self.predicates:
+            mask &= self.predicate_mask(columns, len(ids_by_slot))
+        return mask
+
+    def predicate_mask(self, columns: Optional[ScalarColumns],
+                       capacity: int) -> np.ndarray:
+        """The predicates alone, against the store's mirrored columns."""
+        if columns is None:
+            raise ColumnsUnavailable("the store mirrors no columns")
+        return columns.match(self.predicates, capacity)
+
+    def id_mask(self, ids_by_slot: np.ndarray) -> np.ndarray:
+        """The id part of the filter alone (everything but predicates)."""
         mask = ids_by_slot >= 0
         if self.ranges:
             rmask = np.zeros_like(mask)
@@ -268,6 +296,12 @@ class VectorIndex(abc.ABC):
     @abc.abstractmethod
     def delete(self, ids: np.ndarray) -> None:
         """Remove ids (missing ids are ignored, matching reference logs)."""
+
+    def set_scalars(self, ids: np.ndarray,
+                    scalars: Optional[List[Optional[dict]]]) -> None:
+        """Mirror the scalars of rows just upserted, for indexes that keep
+        the region's flagged fields per slot (index/columns.py); `scalars`
+        None when the write carried none. Default: nothing is mirrored."""
 
     # -- queries (vector_index.h:166-199) ----------------------------------
     @abc.abstractmethod

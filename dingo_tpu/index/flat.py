@@ -10,6 +10,7 @@ searches hit cached executables.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 from typing import Callable, List, Optional
@@ -80,10 +81,13 @@ def _new_tier_store(precision: str, dim: int, parameter: IndexParameter,
     float store; sq8 swaps in the quantizing store."""
     kw = {"capacity": capacity} if capacity else {}
     if precision == "sq8":
-        return SqSlotStore(dim, **kw)
-    dtype = jnp.bfloat16 if precision == "bf16" \
-        else jnp.dtype(parameter.dtype)
-    return SlotStore(dim, dtype, **kw)
+        store = SqSlotStore(dim, **kw)
+    else:
+        dtype = jnp.bfloat16 if precision == "bf16" \
+            else jnp.dtype(parameter.dtype)
+        store = SlotStore(dim, dtype, **kw)
+    store.mirror_columns(parameter.scalar_speedup_keys)
+    return store
 
 
 def integrity_mutation(fn):
@@ -353,6 +357,40 @@ class _SlotStoreIndex(VectorIndex):
         self._integrity_delete(ids)
         self.write_count_since_save += removed
 
+    def set_scalars(self, ids: np.ndarray, scalars) -> None:
+        self.store.write_scalars(np.asarray(ids, np.int64), scalars)
+
+    @staticmethod
+    def _filter_span(filter_spec: Optional[FilterSpec]):
+        """Span `index.filter` around a request's predicate -> slot mask
+        step (built, or found in a cache), taken outside the device lock;
+        nothing for a filter without predicates."""
+        if filter_spec is not None and filter_spec.predicates:
+            return TRACER.start_child("index.filter")
+        return contextlib.nullcontext()
+
+    def _filter_slot_mask(self, filter_spec: FilterSpec):
+        """(the [capacity] slot mask of a non-empty filter, the share of
+        live slots it keeps or None), on the host. A filter with predicates
+        sets gauge `filter.matched_fraction`: matching live slots over the
+        live slots of the filter's id part."""
+        store = self.store
+        if not filter_spec.predicates:
+            return filter_spec.slot_mask(store.ids_by_slot), None
+        ids = store.ids_by_slot
+        base = filter_spec.id_mask(ids)
+        mask = base & filter_spec.predicate_mask(store.columns, len(ids))
+        share = float(mask.sum()) / max(1, int(base.sum()))
+        self._note_matched(share)
+        return mask, share
+
+    def _note_matched(self, share: Optional[float]) -> None:
+        if share is not None:
+            from dingo_tpu.common.metrics import METRICS
+
+            METRICS.gauge("filter.matched_fraction",
+                          region_id=self.id).set(share)
+
     # -- search ------------------------------------------------------------
     def search(
         self,
@@ -398,6 +436,13 @@ class _SlotStoreIndex(VectorIndex):
             qpad = staged.take(queries) if staged is not None else None
             if qpad is None:
                 qpad = jnp.asarray(_pad_batch(queries))
+            # the filter's numpy mask OUTSIDE the device lock, checked
+            # against the store's version under it
+            filtered = filter_spec is not None and not filter_spec.is_empty()
+            if filtered:
+                prep_ver = store.mutation_version
+                with self._filter_span(filter_spec):
+                    np_mask, _ = self._filter_slot_mask(filter_spec)
             # lease BEFORE dispatch: kernel-produced slots must stay limbo-
             # parked (not reassigned) until resolve translates them
             lease = store.begin_search()
@@ -410,12 +455,13 @@ class _SlotStoreIndex(VectorIndex):
                     # mask capture AND dispatch under the device lock: a
                     # concurrent donated write or growth would invalidate
                     # the vecs reference / change the capacity mid-dispatch
-                    if filter_spec is None or filter_spec.is_empty():
+                    if not filtered:
                         mask = store.device_mask()
                     else:
-                        mask = jnp.asarray(
-                            filter_spec.slot_mask(store.ids_by_slot)
-                        )
+                        if prep_ver != store.mutation_version:
+                            # a write landed since: build it again
+                            np_mask, _ = self._filter_slot_mask(filter_spec)
+                        mask = jnp.asarray(np_mask)
                     kprime = self._rerank_shortlist(int(topk))
                     dists, slots, stats = self._run_search_kernel(
                         qpad, mask, kprime or int(topk)

@@ -398,15 +398,16 @@ class TpuHnsw(_SlotStoreIndex):
         None), or None for no/empty filter."""
         if filter_spec is None or filter_spec.is_empty():
             return None
-        fp = filter_spec.fingerprint()
-        ver = self.store.mutation_version
-        hit = self._filter_cache.get(fp)
-        if hit is not None and hit[0] == ver:
-            METRICS.counter(
-                "hnsw.filter_mask_hits", region_id=self.id
-            ).add(1)
-            return (fp, ver, hit[1], hit[2])
-        mask = filter_spec.slot_mask(self.store.ids_by_slot)
+        with self._filter_span(filter_spec):
+            fp = filter_spec.fingerprint()
+            ver = self.store.mutation_version
+            hit = self._filter_cache.get(fp)
+            if hit is not None and hit[0] == ver:
+                METRICS.counter(
+                    "hnsw.filter_mask_hits", region_id=self.id
+                ).add(1)
+                return (fp, ver, hit[1], hit[2])
+            mask = self._filter_slot_mask(filter_spec)[0]
         self._cache_filter(fp, (ver, mask, None))
         METRICS.counter("hnsw.filter_mask_misses", region_id=self.id).add(1)
         return (fp, ver, mask, None)
@@ -434,7 +435,7 @@ class TpuHnsw(_SlotStoreIndex):
         if dev is not None and ver == cur:
             return dev
         if ver != cur or np_mask is None:
-            np_mask = filter_spec.slot_mask(self.store.ids_by_slot)
+            np_mask = self._filter_slot_mask(filter_spec)[0]
             ver = cur
         dev = jnp.asarray(np_mask)
         self._cache_filter(fp, (ver, np_mask, dev))

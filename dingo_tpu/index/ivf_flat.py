@@ -484,29 +484,37 @@ class IvfViewMaintenance:
             self._integrity_assign(self.store.ids_by_slot[live], assign)
 
     # -- filter-mask cache -------------------------------------------------
+    def _mask_version(self, view) -> tuple:
+        """What a cached filter mask was built from: the bucketed view and
+        the store's host state (slots and mirrored columns)."""
+        return (view.version if view is not None else -1,
+                self.store.mutation_version)
+
     def _prep_filter_mask(self, filter_spec: Optional[FilterSpec]):
         """Host-side filter work done OUTSIDE the device lock: fingerprint
         hashing and the O(capacity) numpy slot-mask build can cost
         milliseconds on big include sets, and must not serialize every
         concurrent search/write behind the lock. Returns (fp, version,
-        mask_or_None); the in-lock consumer revalidates against the live
-        view version and rebuilds in the (rare) raced case."""
+        (mask, share) or None); the in-lock consumer revalidates against
+        the live version and rebuilds in the (rare) raced case."""
         if filter_spec is None or filter_spec.is_empty():
             return None
-        view = self._view
-        fp = filter_spec.fingerprint()
-        ver = view.version if view is not None else -1
-        hit = self._filter_cache.get(fp)
-        if hit is not None and hit[0] == ver:
-            return (fp, ver, None)       # expected cache hit; skip the build
-        return (fp, ver, filter_spec.slot_mask(self.store.ids_by_slot))
+        with self._filter_span(filter_spec):
+            fp = filter_spec.fingerprint()
+            ver = self._mask_version(self._view)
+            hit = self._filter_cache.get(fp)
+            if hit is not None and hit[0] == ver:
+                # expected cache hit; skip the build
+                self._note_matched(hit[2])
+                return (fp, ver, None)
+            return (fp, ver, self._filter_slot_mask(filter_spec))
 
     def _bucket_valid_for_filter(
         self, filter_spec: Optional[FilterSpec], prep=None
     ):
         """Device validity mask for the scan kernel. Unfiltered searches
         reuse the resident bucket_valid (zero per-request H2D); filtered
-        searches hit a (filter-fingerprint, view-version) cache, and a
+        searches hit a (filter-fingerprint, mask version) cache, and a
         miss uploads only the [capacity] slot mask, expanding it on
         device (_filter_bucket_mask). Callers hold store.device_lock;
         pass `prep` from _prep_filter_mask to keep the host work outside
@@ -514,26 +522,28 @@ class IvfViewMaintenance:
         view = self._view
         if filter_spec is None or filter_spec.is_empty():
             return view.bucket_valid
-        fp, ver, mask = prep if prep is not None else (
-            filter_spec.fingerprint(), view.version, None
+        cur = self._mask_version(view)
+        fp, ver, built = prep if prep is not None else (
+            filter_spec.fingerprint(), cur, None
         )
         hit = self._filter_cache.get(fp)
-        if hit is not None and hit[0] == view.version:
+        if hit is not None and hit[0] == cur:
             METRICS.counter("ivf.filter_mask_hits", region_id=self.id).add(1)
             return hit[1]
-        if mask is None or ver != view.version:
+        if built is None or ver != cur:
             # raced with a write since prep (or the expected hit was
             # evicted): rebuild against the live host state
-            mask = filter_spec.slot_mask(self.store.ids_by_slot)
+            built = self._filter_slot_mask(filter_spec)
+        mask, share = built
         bmask = _filter_bucket_mask(jnp.asarray(mask), view.bucket_slot)
         if len(self._filter_cache) >= FILTER_CACHE_SIZE:
-            stale = [k for k, (v, _) in self._filter_cache.items()
-                     if v != view.version]
+            stale = [k for k, v in self._filter_cache.items()
+                     if v[0] != cur]
             for k in stale:
                 del self._filter_cache[k]
             while len(self._filter_cache) >= FILTER_CACHE_SIZE:
                 self._filter_cache.pop(next(iter(self._filter_cache)))
-        self._filter_cache[fp] = (view.version, bmask)
+        self._filter_cache[fp] = (cur, bmask, share)
         METRICS.counter("ivf.filter_mask_misses", region_id=self.id).add(1)
         return bmask
 

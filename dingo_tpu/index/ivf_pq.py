@@ -393,6 +393,7 @@ class TpuIvfPq(IvfViewMaintenance, _SlotStoreIndex):
         )
         store_cls = HostSlotStore if p.host_vectors else SlotStore
         self.store = store_cls(p.dimension, store_dtype)
+        self.store.mirror_columns(p.scalar_speedup_keys)
         self.nlist = p.ncentroids
         self.m = p.nsubvector
         self.ksub = 1 << p.nbits_per_idx
@@ -718,12 +719,12 @@ class TpuIvfPq(IvfViewMaintenance, _SlotStoreIndex):
                 )
                 if host_rows:
                     mask_h = (
-                        filter_spec.slot_mask(store.ids_by_slot) if filtered
+                        self._filter_slot_mask(filter_spec)[0] if filtered
                         else store.valid_h
                     )
                 else:
                     mask = (
-                        jnp.asarray(filter_spec.slot_mask(store.ids_by_slot))
+                        jnp.asarray(self._filter_slot_mask(filter_spec)[0])
                         if filtered else store.device_mask()
                     )
             # lease BEFORE any kernel dispatch: slots produced by the kernel
@@ -893,6 +894,7 @@ class TpuIvfPq(IvfViewMaintenance, _SlotStoreIndex):
         )
         self.store = store_cls(self.dimension, store_dtype,
                                max(len(data["ids"]), 1))
+        self.store.mirror_columns(self.parameter.scalar_speedup_keys)
         self._assign_h = np.full((self.store.capacity,), -1, np.int32)
         self._codes = None
         self.centroids = None

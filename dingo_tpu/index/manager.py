@@ -114,6 +114,7 @@ class VectorIndexManager:
                     index.upsert(ids, vecs)
             if bulk is not None:
                 bulk.finish()
+            self.restore_columns(index, reader)
             if index.need_train() and total:
                 # TrainForBuild (:1365) — now AFTER ingest: trainable
                 # tiers buffer pre-train rows in their store and the
@@ -132,6 +133,22 @@ class VectorIndexManager:
             span.set_attr("rows", total)
             span.set_attr("device_build", bulk is not None)
         return index
+
+    @staticmethod
+    def restore_columns(index: VectorIndex, reader: VectorReader) -> None:
+        """Fill the per-slot columns of the region's flagged scalar fields
+        (index/columns.py) from the speed-up CF, for the rows the index
+        holds: build and load bring the vectors only, and an index whose
+        columns are incomplete serves its filters on the reader's host
+        path. The CF is what apply writes beside each vector, so it holds
+        exactly the mirrored fields of every row."""
+        store = getattr(index, "store", None)
+        if getattr(store, "columns", None) is None:
+            return
+        ids = np.sort(store.ids_by_slot[store.ids_by_slot >= 0])
+        for lo in range(0, len(ids), BUILD_BATCH):
+            chunk = ids[lo:lo + BUILD_BATCH]
+            index.set_scalars(chunk, reader.speedup_scalars(chunk))
 
     def _scan_chunks(self, reader: VectorReader):
         """Page the region data CF ascending in BUILD_BATCH-row chunks,
@@ -253,6 +270,7 @@ class VectorIndexManager:
                 data = wd.decode_write(payload)
                 if isinstance(data, wd.VectorAddData):
                     index.upsert(data.ids, data.vectors)
+                    index.set_scalars(data.ids, data.scalars)
                 elif isinstance(data, wd.VectorDeleteData):
                     index.delete(data.ids)
                 index.apply_log_id = log_id
@@ -298,6 +316,8 @@ class VectorIndexManager:
             index.load(path)
         except Exception:
             return False
+        # the snapshot holds no columns: the speed-up CF has them
+        self.restore_columns(index, self._reader(region))
         if raft_log is None:
             if wrapper.apply_log_id > index.apply_log_id:
                 raise StaleSnapshot(

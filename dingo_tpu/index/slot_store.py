@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from dingo_tpu.index.columns import ScalarColumns
 from dingo_tpu.obs.sentinel import sentinel_jit
 
 MIN_CAPACITY = 4096
@@ -163,6 +164,9 @@ class SlotStore:
         self.mutation_version = 0
         self.vecs, self.sqnorm = self._alloc_storage(self.capacity)
         self.ids_by_slot = np.full((self.capacity,), -1, np.int64)
+        # typed host columns of the region's scalar_speedup_keys, by slot
+        # (index/columns.py); None where the region flags no field
+        self.columns: Optional[ScalarColumns] = None
         self.valid_h = np.zeros((self.capacity,), np.bool_)
         self._dmask: Optional[jax.Array] = None   # lazy device copy of valid_h
         self._id_to_slot: dict[int, int] = {}
@@ -254,6 +258,8 @@ class SlotStore:
             size += self.capacity * (self.dim * itemsize + self.nblk * 4)
         if self.adj is not None:
             size += self.capacity * self.graph_deg * 4
+        if self.columns is not None:
+            size += self.columns.nbytes()
         return size
 
     def set_graph(self, adj: np.ndarray, deg: int) -> None:
@@ -268,6 +274,22 @@ class SlotStore:
         with self.device_lock:
             self.graph_deg = deg
             self.adj = jnp.asarray(adj, jnp.int32)
+
+    def mirror_columns(self, fields) -> None:
+        """Keep a typed host column per field (the region's
+        scalar_speedup_keys) beside ids_by_slot; call before any put."""
+        if fields:
+            self.columns = ScalarColumns(fields, self.capacity)
+
+    def write_scalars(self, ids: np.ndarray, rows) -> None:
+        """The mirrored columns of rows this store holds (`rows` None: the
+        write carried no scalar data); no-op without columns. Bumps the
+        mutation version after the write, so a mask cached before it is
+        never served after it."""
+        if self.columns is None:
+            return
+        self.columns.write(self.slots_of(ids), rows)
+        self.mutation_version += 1
 
     def reserve(self, capacity: int) -> None:
         """Pre-size device arrays (bulk ingest avoids per-growth recompiles
@@ -284,6 +306,7 @@ class SlotStore:
         if n == 0:
             return np.empty(0, np.int64)
         slots = np.empty(n, np.int64)
+        fresh = []
         for i, vid in enumerate(ids):
             vid = int(vid)
             s = self._id_to_slot.get(vid)
@@ -293,7 +316,10 @@ class SlotStore:
                 s = self._free.pop()
                 self._id_to_slot[vid] = s
                 self.ids_by_slot[s] = vid
+                fresh.append(s)
             slots[i] = s
+        if self.columns is not None:
+            self.columns.assign(np.asarray(fresh, np.int64))
         vectors = np.asarray(vectors)
         # Sort into ascending slot order, then split into contiguous runs.
         order = np.argsort(slots, kind="stable")
@@ -404,6 +430,8 @@ class SlotStore:
                     slots[i] = s
                     removed += 1
         if removed:
+            if self.columns is not None:
+                self.columns.release(slots)
             self._dmask = None
             self.mutation_version += 1
         return slots
@@ -443,6 +471,10 @@ class SlotStore:
                     [self.bsq_blk, jnp.zeros((self.nblk, pad), jnp.float32)],
                     axis=1,
                 )
+        if self.columns is not None:
+            # before ids_by_slot: a mask built meanwhile may find columns
+            # longer than the ids it was handed, never shorter
+            self.columns.grow(new_capacity)
         self.ids_by_slot = np.concatenate(
             [self.ids_by_slot, np.full((pad,), -1, np.int64)]
         )

@@ -245,7 +245,8 @@ class HostSqFlat(_SlotStoreIndex):
         with store.device_lock:
             valid = store.valid_h.copy()
             if filter_spec is not None and not filter_spec.is_empty():
-                valid &= filter_spec.slot_mask(store.ids_by_slot)
+                valid &= filter_spec.slot_mask(store.ids_by_slot,
+                                               store.columns)
             if store.sq_params is not None and valid.any():
                 q_sq = np.einsum("bd,bd->b", q, q)
                 for lo in range(0, store.capacity, SCAN_PAGE):

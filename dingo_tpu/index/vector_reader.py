@@ -2,6 +2,13 @@
 
 Reference: src/vector/vector_reader.{h,cc} (2,429 LoC) — VectorBatchSearch
 (vector_reader.cc:439) -> SearchVector (:104) dispatches on filter mode:
+  SCALAR on mirrored fields — pre and post alike: when every field of the
+                        filter is one of the region's scalar_speedup_keys,
+                        the index keeps it as a per-slot column and the
+                        filter is a slot mask inside the one search program
+                        (FilterSpec.predicates): the exact filtered top-k,
+                        where the upstream over-fetches (ARCHITECTURE.md
+                        "Scalar filters")
   SCALAR post-filter  — over-fetch topk*10, then compare scalar data (:120-215)
   VECTOR_ID pre-filter — explicit candidate ids (:216-222, impl :830)
   SCALAR pre-filter   — scan scalar CF for candidates -> id filter (:853);
@@ -35,6 +42,7 @@ from dingo_tpu.engine.raw_engine import (
     RawEngine,
 )
 from dingo_tpu.index import codec as vcodec
+from dingo_tpu.index.columns import ColumnsUnavailable
 from dingo_tpu.index.base import (
     FilterSpec,
     IndexParameter,
@@ -289,7 +297,13 @@ class VectorReader:
         base = FilterSpec(ranges=[self.ctx.id_window()])
 
         radius = search_kw.pop("radius", 0.0)
-        if filter_mode is VectorFilterMode.VECTOR_ID:
+        masked = None
+        if filter_mode is VectorFilterMode.SCALAR:
+            masked = self._masked_scalar_search(
+                queries, topk, scalar_filter, base, **search_kw)
+        if masked is not None:
+            results = masked
+        elif filter_mode is VectorFilterMode.VECTOR_ID:
             # pre-filter on explicit ids (vector_reader.cc:216-222, :830)
             t0 = _time.perf_counter_ns()
             ids = np.asarray(sorted(set(map(int, vector_ids or []))), np.int64)
@@ -364,6 +378,36 @@ class VectorReader:
                 total_ns - prefilter_ns - postfilter_ns - backfill_ns
             ) // 1000
         return out
+
+    def _masked_scalar_search(self, queries, topk, scalar_filter, base,
+                              **search_kw) -> Optional[List[SearchResult]]:
+        """A SCALAR filter whose fields the region mirrors per slot (its
+        scalar_speedup_keys): one FilterSpec with the predicates, the mask
+        inside the index's one search program, for QUERY_PRE and
+        QUERY_POST alike. None where the filter cannot be a mask (a field
+        not flagged, an expression filter, an index whose columns are not
+        complete): the host paths below serve it."""
+        keys = getattr(self.ctx.parameter, "scalar_speedup_keys", ()) or ()
+        if not (keys and isinstance(scalar_filter, ScalarFilter)
+                and not scalar_filter.is_empty()
+                and scalar_filter.fields() <= set(keys)):
+            return None
+        spec = FilterSpec(ranges=base.ranges,
+                          predicates=tuple(scalar_filter.predicates))
+        try:
+            return self._search_with_fallback(queries, topk, spec,
+                                              **search_kw)
+        except ColumnsUnavailable:
+            return None
+
+    def speedup_scalars(self, ids: Sequence[int]) -> List[dict]:
+        """The flagged scalar fields of each id, from the speed-up CF ({}
+        where the row has none): one multi-get."""
+        keys = [vcodec.encode_vector_key(self.ctx.partition_id, int(i))
+                for i in ids]
+        got = self._speedup.kv_batch_get(keys, self.ctx.read_ts)
+        return [deserialize_scalar(got[k]) if got.get(k) else {}
+                for k in keys]
 
     def _radius_cut(self, r: SearchResult, radius: float) -> SearchResult:
         from dingo_tpu.ops.distance import Metric, metric_ascending
@@ -470,13 +514,20 @@ class VectorReader:
         from dingo_tpu.index.recovery import RECOVERY, DeviceDegraded
 
         wrapper = self.ctx.index_wrapper
+        if spec.predicates and (
+                wrapper is None or not wrapper.is_ready()
+                or RECOVERY.is_degraded(self.ctx.region_id)):
+            # only a serving index mirrors columns
+            raise ColumnsUnavailable("no serving index")
         if wrapper is not None and RECOVERY.is_degraded(self.ctx.region_id):
             return self._host_exact_search(queries, topk, spec)
         if wrapper is not None and wrapper.is_ready():
             try:
                 return wrapper.search(queries, topk, spec, **kw)
-            except (NotSupported, NotTrained):
-                pass  # EVECTOR_NOT_SUPPORT contract -> brute force
+            except (NotSupported, NotTrained) as e:
+                if spec.predicates:
+                    raise ColumnsUnavailable("index cannot serve") from e
+                # EVECTOR_NOT_SUPPORT contract -> brute force
             except Exception as e:  # noqa: BLE001 — OOM-classified below
                 from dingo_tpu.obs.hbm import looks_like_oom
 
@@ -488,6 +539,8 @@ class VectorReader:
                         lambda: wrapper.search(queries, topk, spec, **kw),
                         kind="search", cause=e)
                 except DeviceDegraded:
+                    if spec.predicates:
+                        raise ColumnsUnavailable("region degraded") from e
                     return self._host_exact_search(queries, topk, spec)
         return self._brute_force_search(queries, topk, spec)
 
@@ -570,6 +623,8 @@ class VectorReader:
         mask = np.ones(len(ids), np.bool_)
         if spec is None or spec.is_empty():
             return mask
+        if spec.predicates:
+            raise ColumnsUnavailable("the host path mirrors no columns")
         if spec.ranges:
             rm = np.zeros(len(ids), np.bool_)
             for lo, hi in spec.ranges:
@@ -666,10 +721,16 @@ class VectorReader:
     def _post_filter(
         self, result: SearchResult, topk: int, src: MvccReader, match
     ) -> SearchResult:
+        """Keep over-fetched hits whose CF row matches, in order, up to
+        topk: the row's candidates are read in one multi-get (counter
+        `filter.post_rows`)."""
+        keys = [vcodec.encode_vector_key(self.ctx.partition_id, int(vid))
+                for vid in result.ids]
+        blobs = src.kv_batch_get(keys, self.ctx.read_ts) if keys else {}
+        METRICS.counter("filter.post_rows").add(len(keys))
         keep_ids, keep_d = [], []
-        for vid, dist in zip(result.ids, result.distances):
-            key = vcodec.encode_vector_key(self.ctx.partition_id, int(vid))
-            blob = src.kv_get(key, self.ctx.read_ts)
+        for key, vid, dist in zip(keys, result.ids, result.distances):
+            blob = blobs.get(key)
             if match(blob):
                 keep_ids.append(vid)
                 keep_d.append(dist)

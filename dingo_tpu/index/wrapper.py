@@ -103,10 +103,12 @@ class VectorIndexWrapper:
 
     # -- writes (apply-log contract, §3.2) ------------------------------------
     def add(self, ids: np.ndarray, vectors: np.ndarray, log_id: int,
-            is_upsert: bool = True) -> None:
+            is_upsert: bool = True, scalars=None) -> None:
         """Apply a raft-committed VECTOR_ADD iff log_id advances
         (VectorAddHandler guard: 'if log_id > ApplyLogId',
-        raft_apply_handler.cc:1115)."""
+        raft_apply_handler.cc:1115). `scalars` are the write's scalar rows
+        (None: it carried none), which an index that mirrors the region's
+        flagged fields takes in the same mutation."""
         with self._lock:
             idx = self.own_index if self.ready else None
             if idx is None:
@@ -134,6 +136,7 @@ class VectorIndexWrapper:
                         idx.upsert(ids, vectors)
                     else:
                         idx.add(ids, vectors)
+                    idx.set_scalars(ids, scalars)
                     # post-merge: purge absorbed-range versions from the
                     # sibling so search's sibling merge can't resurrect
                     # stale vectors

@@ -320,8 +320,8 @@ class ShadowOracle:
         # filter mask compiles in numpy OUTSIDE the device lock (the
         # serving paths' discipline); same [capacity] bool shape as the
         # plain validity mask, so no extra programs
-        np_mask = filter_spec.slot_mask(store.ids_by_slot) if filtered \
-            else None
+        np_mask = filter_spec.slot_mask(
+            store.ids_by_slot, store.columns) if filtered else None
         lease = store.begin_search()
         try:
             with store.device_lock:
@@ -342,7 +342,8 @@ class ShadowOracle:
         vecs = np.asarray(store.vecs, np.float32)
         valid = store.valid_h
         if filter_spec is not None and not filter_spec.is_empty():
-            valid = valid & filter_spec.slot_mask(store.ids_by_slot)
+            valid = valid & filter_spec.slot_mask(store.ids_by_slot,
+                                                  store.columns)
         if self.metric is Metric.L2:
             scores = -(
                 (queries ** 2).sum(1)[:, None]

@@ -149,6 +149,8 @@ class IndexService:
         for name in ("decode_wire_rows", "decode_boxed_rows",
                      "epoch_stamped", "epoch_refusals"):
             METRICS.counter("service." + name).add(0)
+        # rows the reader's host post-filter looked up (index/vector_reader)
+        METRICS.counter("filter.post_rows").add(0)
 
     def _get_coalescer(self):
         from dingo_tpu.common.coalescer import SearchCoalescer

@@ -291,8 +291,10 @@ def test_scalar_speedup_cf_pre_filter():
     """Scalar speed-up CF end-to-end (raft_apply_handler.cc:1115 via
     SplitVectorScalarData + constant.h kVectorScalarKeySpeedUpCF): with
     scalar_speedup_keys flagged, apply writes the flagged subset to the
-    narrow CF, a covered pre-filter search reads ONLY the narrow CF, and
-    results are identical to the wide-CF path."""
+    narrow CF; a covered pre-filter search reads no scalar CF at all
+    while the index mirrors the flagged fields per slot, and ONLY the
+    narrow CF on the host path (no serving index); results are identical
+    to the wide-CF path."""
     from dingo_tpu.engine.raw_engine import (
         CF_VECTOR_SCALAR,
         CF_VECTOR_SCALAR_SPEEDUP,
@@ -351,9 +353,25 @@ def test_scalar_speedup_cf_pre_filter():
         filter_type=VectorFilterType.QUERY_PRE,
         scalar_filter=ScalarFilter.equals({"color": "red"}),
     )
+    assert CF_VECTOR_SCALAR_SPEEDUP not in spy.read_cfs
+    assert CF_VECTOR_SCALAR not in spy.read_cfs, (
+        "covered pre-filter touched the wide scalar CF")
+    # no serving index, no columns: the host path reads the narrow CF
+    host = engine.new_vector_reader(region)
+    host.ctx = dataclasses_replace_engine(host.ctx, spy)
+    host.ctx.index_wrapper = None
+    for cf_reader in (host._scalar, host._speedup, host._data):
+        cf_reader.engine = spy
+    res_host = host.vector_batch_search(
+        x[:8], 10, filter_mode=VectorFilterMode.SCALAR,
+        filter_type=VectorFilterType.QUERY_PRE,
+        scalar_filter=ScalarFilter.equals({"color": "red"}),
+    )
     assert CF_VECTOR_SCALAR_SPEEDUP in spy.read_cfs
     assert CF_VECTOR_SCALAR not in spy.read_cfs, (
         "covered pre-filter touched the wide scalar CF")
+    for a, b in zip(res_narrow, res_host):
+        assert [v.id for v in a] == [v.id for v in b]
 
     # identical results to a region WITHOUT the speed-up CF
     raw2, engine2, storage2, region2, x2 = build(())

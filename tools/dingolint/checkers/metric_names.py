@@ -130,6 +130,13 @@ FAMILY_NAMES = {
         "ivf.batch_scan_buckets",   # buckets the last batch-major scan
                                     # read, each once (gauge)
     },
+    "filter": {
+        # scalar filters (index/columns.py, index/vector_reader.py)
+        "filter.matched_fraction",  # matching live slots over live slots
+                                    # of the last predicate mask (gauge)
+        "filter.post_rows",         # rows the host post-filter looked up
+                                    # (fields the region does not mirror)
+    },
     "ivfpq": {
         "ivfpq.probed_codes_per_query",  # live codes in the probed
                                     # buckets, mean per query of a sampled
